@@ -506,7 +506,16 @@ def bench_shed_vs_degrade(model_path: str, workdir: str,
     return legs
 
 
-def bench_lifecycle_swap(workdir: str, features: int, bst) -> dict:
+def _lifecycle_window(features: int):
+    """The data the lifecycle section's v2 continues training on."""
+    rng = np.random.default_rng(3)
+    Xw = rng.normal(size=(4000, features)).astype(np.float32)
+    yw = (Xw[:, 0] + 0.5 * Xw[:, 1] > 0).astype(np.float32)
+    return Xw, yw
+
+
+def bench_lifecycle_swap(workdir: str, features: int, v1_path: str,
+                         v2_path: str) -> dict:
     """p99 during a hot swap vs steady state, with requests in flight.
 
     A 2-replica fleet serves v1 from a model store that already holds a
@@ -517,21 +526,15 @@ def bench_lifecycle_swap(workdir: str, features: int, bst) -> dict:
     steady-state p99 from the same run's between-swap windows (a
     within-run pair, per the host-noise convention).
     """
-    import xgboost_tpu as xtb
     from xgboost_tpu.lifecycle import LifecycleConfig, LifecycleManager
     from xgboost_tpu.serving import ModelStore, ServingFleet
 
     store = ModelStore(os.path.join(workdir, "lifecycle_store"))
-    store.publish("m", bst)
+    store.publish("m", v1_path)
     store.set_active("m", 1)
-    rng = np.random.default_rng(3)
-    Xw = rng.normal(size=(4000, features)).astype(np.float32)
-    yw = (Xw[:, 0] + 0.5 * Xw[:, 1] > 0).astype(np.float32)
-    cont = xtb.train(dict(bst.params), xtb.DMatrix(Xw, label=yw), 2,
-                     verbose_eval=False, xgb_model=bst)
-    store.publish("m", cont)
+    store.publish("m", v2_path)
 
-    Xq = Xw[:FLEET_BATCH]
+    Xq = _lifecycle_window(features)[0][:FLEET_BATCH]
     n_clients = 4
     lats, lock, errors = [], threading.Lock(), []
     stop = threading.Event()
@@ -596,14 +599,28 @@ def bench_lifecycle_swap(workdir: str, features: int, bst) -> dict:
     }
 
 
-def main(out_path: str) -> int:
+def _bench_shape() -> tuple:
+    return (int(os.environ.get("BENCH_SERVE_ROUNDS", "20")),
+            int(os.environ.get("BENCH_SERVE_DEPTH", "6")),
+            int(os.environ.get("BENCH_SERVE_FEATURES", "28")))
+
+
+def _fleet_sections() -> bool:
+    return os.environ.get("BENCH_SERVE_FLEET", "1") != "0"
+
+
+def prepare(workdir: str) -> int:
+    """The half that computes in-process, run as a child of :func:`main`:
+    trains every model the run needs, benches the in-process engine, and
+    leaves the model files and ``engine.json`` in ``workdir``.  It exits
+    before any fleet starts: a process that has trained holds the device,
+    and a replica that needs it would then fail or hang."""
     import jax
 
+    import xgboost_tpu as xtb
     from xgboost_tpu.serving import ServingEngine
 
-    rounds = int(os.environ.get("BENCH_SERVE_ROUNDS", "20"))
-    depth = int(os.environ.get("BENCH_SERVE_DEPTH", "6"))
-    features = int(os.environ.get("BENCH_SERVE_FEATURES", "28"))
+    rounds, depth, features = _bench_shape()
     scale = float(os.environ.get("BENCH_SERVE_ITERS", "1"))
 
     bst, X = train_model(rounds, depth, features)
@@ -635,23 +652,41 @@ def main(out_path: str) -> int:
               f"req/s over {report['concurrent']['threads']} threads, "
               f"steady-state compiles={steady}")
 
+    if _fleet_sections():
+        # mixed-architecture set: the binary model above + a
+        # multiclass + a regression one (distinct serve programs per
+        # bucket each — a multi-tenant replica's real warm load), and the
+        # lifecycle section's continuation-trained v2 of the first
+        bst_b, _ = train_model(max(2, rounds // 2), max(3, depth - 2),
+                               features, "multi:softprob", num_class=5)
+        bst_c, _ = train_model(max(2, rounds // 2), max(3, depth - 1),
+                               features, "reg:squarederror")
+        Xw, yw = _lifecycle_window(features)
+        cont = xtb.train(dict(bst.params), xtb.DMatrix(Xw, label=yw), 2,
+                         verbose_eval=False, xgb_model=bst)
+        for name, model in (("a", bst), ("b", bst_b), ("c", bst_c),
+                            ("a2", cont)):
+            model.save_model(os.path.join(workdir, name + ".json"))
+    with open(os.path.join(workdir, "engine.json"), "w") as fh:
+        json.dump(report, fh)
+    return 0
+
+
+def main(out_path: str) -> int:
+    import subprocess
+
+    _, _, features = _bench_shape()
     rc = 0
-    if os.environ.get("BENCH_SERVE_FLEET", "1") != "0":
-        workdir = tempfile.mkdtemp(prefix="xtb_bench_fleet_")
-        try:
-            # mixed-architecture set: the binary model above + a
-            # multiclass + a regression one (distinct serve programs per
-            # bucket each — a multi-tenant replica's real warm load)
-            bst_b, _ = train_model(max(2, rounds // 2), max(3, depth - 2),
-                                   features, "multi:softprob", num_class=5)
-            bst_c, _ = train_model(max(2, rounds // 2), max(3, depth - 1),
-                                   features, "reg:squarederror")
-            pa = os.path.join(workdir, "a.json")
-            pb = os.path.join(workdir, "b.json")
-            pc = os.path.join(workdir, "c.json")
-            bst.save_model(pa)
-            bst_b.save_model(pb)
-            bst_c.save_model(pc)
+    workdir = tempfile.mkdtemp(prefix="xtb_bench_fleet_")
+    try:
+        subprocess.run([sys.executable, os.path.abspath(__file__),
+                        "--prepare", workdir], check=True)
+        with open(os.path.join(workdir, "engine.json")) as fh:
+            report = json.load(fh)
+        steady = report["concurrent"]["engine_metrics"]["compiles_steady"]
+        if _fleet_sections():
+            pa, pb, pc, pa2 = (os.path.join(workdir, n + ".json")
+                               for n in ("a", "b", "c", "a2"))
             cs = bench_fleet_coldstart({"a": pa, "b": pb, "c": pc}, workdir)
             report["fleet_coldstart"] = _stamp(cs)
             print(f"fleet coldstart ({cs['programs']} programs): "
@@ -694,7 +729,7 @@ def main(out_path: str) -> int:
                   f"({report.get('fleet_scaling_note', 'replica-limited')})")
             svd = bench_shed_vs_degrade(pa, workdir, features)
             report["shed_vs_degrade"] = _stamp(svd)
-            ls = bench_lifecycle_swap(workdir, features, bst)
+            ls = bench_lifecycle_swap(workdir, features, pa, pa2)
             report["lifecycle_swap"] = _stamp(ls)
             print(f"lifecycle swap: wall={ls['swap_wall_s'] * 1e3:.0f}ms  "
                   f"{ls['requests_during_swap']} requests in flight  "
@@ -712,8 +747,8 @@ def main(out_path: str) -> int:
                 print(f"FAIL: warm-cache cold-start speedup "
                       f"{cs['speedup']}x < {min_x}x", file=sys.stderr)
                 rc = 1
-        finally:
-            shutil.rmtree(workdir, ignore_errors=True)
+    finally:
+        shutil.rmtree(workdir, ignore_errors=True)
 
     with open(out_path, "w") as fh:
         json.dump(report, fh, indent=2)
@@ -773,6 +808,8 @@ def diff_main(old_path: str, new_path: str) -> int:
 
 
 if __name__ == "__main__":
+    if "--prepare" in sys.argv:
+        sys.exit(prepare(sys.argv[sys.argv.index("--prepare") + 1]))
     if "--diff" in sys.argv:
         i = sys.argv.index("--diff")
         sys.exit(diff_main(sys.argv[i + 1], sys.argv[i + 2]))

@@ -231,4 +231,6 @@ JAX_PLATFORMS=cpu python scripts/lifecycle_smoke.py 2 60
 # replayed from the same seed must retrain the bitwise-identical model
 JAX_PLATFORMS=cpu python scripts/online_smoke.py 2
 
-BENCH_FORCE_CPU=1 BENCH_ROWS=100000 BENCH_ROUNDS=5 python bench.py
+# the chip smoke, rehearsed on the CPU (bench.py and the smoke proper need
+# the chip and fail without it; the rehearsal's last line says "ok": false)
+JAX_PLATFORMS=cpu python chip_smoke.py --rows 100000 --allow-cpu

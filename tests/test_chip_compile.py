@@ -1,0 +1,196 @@
+"""The chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is described
+and not attached (section 2 of the on-chip-measurement guide).  These tests
+hand it the programs of the main path at the real width — 28 columns, 256
+bins, depth 6 — so that what Mosaic or XLA:TPU would refuse on the chip is
+refused here, at no chip time.  Rows are cut to ROWS (the shape of record
+has 2,000,000; compile time, not the verdict, depends on them); the width
+never is.  A compile that passes is a compile: nothing runs, and nothing
+here says anything about results or speed.
+
+Interpret-mode CPU tests pinned every result these kernels give
+(test_hist_kernels.py, test_quantised_hist.py) and still saw none of what
+the first compile refused: a (2048, 16) block of a (R, 28) array.
+
+The topology is described inside the module's fixture and nowhere else:
+only one process at a time may load the TPU's library, every xdist worker
+imports every test file, and so nothing here may touch ``topologies`` while
+a module is imported.  The compiles are made in the test's own process, and
+all of them live in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+ROWS = 65_536  # of the 2,000,000 of the shape of record
+F, B, DEPTH = 28, 256, 6
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def device_paths(monkeypatch):
+    """The branches a chip takes: here jax.default_backend() is the CPU, so
+    the backend sniffs of ops/ would trace the host's FFI kernels."""
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    monkeypatch.setenv("XTB_NO_NATIVE_SPLIT", "1")
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# root; and the widest level a depth-6 tree builds: the 16 left children of
+# depth 5 (heap ids 31, 33, ...), the right ones coming by subtraction
+LEVELS = {"root": dict(node0=0, n_nodes=1, stride=1),
+          "depth5": dict(node0=31, n_nodes=16, stride=2)}
+
+
+@pytest.mark.parametrize("level", sorted(LEVELS))
+@pytest.mark.parametrize("bins_dtype", [jnp.uint8, jnp.int16],
+                         ids=["uint8", "int16"])
+@pytest.mark.parametrize("form", ["float32", "int8limb"])
+def test_fused_hist_kernel_compiles_for_v5e(one_chip, form, bins_dtype,
+                                            level):
+    """Both fused kernels, compiled and not interpreted, at the tiles
+    choose_tiles picks.  uint8 is the page of max_bin <= 254; int16 is what
+    the grower really holds at max_bin=256 (257 symbols with the sentinel)."""
+    from xgboost_tpu.ops.hist_pallas import (build_histogram_pallas,
+                                             build_histogram_pallas_q)
+
+    kernel, vals = {
+        "float32": (build_histogram_pallas,
+                    _shape((ROWS, 2), jnp.float32, one_chip)),
+        "int8limb": (build_histogram_pallas_q,
+                     _shape((ROWS, 2, 3), jnp.int8, one_chip)),
+    }[form]
+    compiled = kernel.lower(
+        _shape((ROWS, F), bins_dtype, one_chip), vals,
+        _shape((ROWS,), jnp.int32, one_chip), n_bin=B, interpret=False,
+        **LEVELS[level]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _level_args(sharding_rows, sharding_rep):
+    """Shapes of one level step's operands at ROWS x 28 x 256, depth 6."""
+    from xgboost_tpu.tree.grow import init_tree_state, max_nodes_for_depth
+
+    state = jax.eval_shape(
+        lambda g, v: init_tree_state(
+            g, v, max_nodes=max_nodes_for_depth(DEPTH), n_bin=B),
+        jax.ShapeDtypeStruct((ROWS, 2), jnp.float32),
+        jax.ShapeDtypeStruct((ROWS,), bool))
+    state = type(state)(*(
+        _shape(s.shape, s.dtype,
+               sharding_rows if name == "pos" else sharding_rep)
+        for name, s in zip(state._fields, state)))
+    return (state,
+            _shape((ROWS, F), jnp.int16, sharding_rows),  # bins
+            _shape((ROWS, 2), jnp.float32, sharding_rows),  # gpair
+            _shape((F, B), jnp.float32, sharding_rep),    # cuts_pad
+            _shape((F,), jnp.int32, sharding_rep),        # n_bins
+            _shape((1, F), bool, sharding_rep),           # feature_mask
+            _shape((1, F), bool, sharding_rep),           # set_matrix
+            _shape((F,), bool, sharding_rep))             # cat_mask
+
+
+def _split_params():
+    from xgboost_tpu.ops.split import SplitParams
+
+    return SplitParams(eta=0.1, gamma=0.0, min_child_weight=1.0,
+                       lambda_=1.0, alpha=0.0, max_delta_step=0.0)
+
+
+def test_root_level_program_compiles_for_v5e(one_chip, device_paths):
+    """``level_step`` at depth 0, as HistTreeGrower.grow calls it by
+    default on a chip: XLA one-hot matmul histogram, XLA split scan."""
+    from xgboost_tpu.tree.grow import level_step
+
+    def root(*args):  # a fresh function, so a fresh trace under device_paths
+        return level_step.__wrapped__(
+            *args, None, None, depth=0, params=_split_params(),
+            last_level=False, hist_impl="xla", subtract=False)
+
+    compiled = jax.jit(root).lower(*_level_args(one_chip, one_chip)).compile()
+    text = compiled.as_text()
+    assert "custom_call_target=\"xtb_" not in text  # no host FFI kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
+
+
+def test_padded_level_program_compiles_for_v5e(one_chip, device_paths):
+    """``level_step_padded``: the one program every interior depth shares,
+    32 node slots wide at depth 6, the 16 left ones built and the rest
+    subtracted."""
+    from xgboost_tpu.tree.grow import level_step_padded
+
+    W = 1 << (DEPTH - 1)
+
+    def interior(*args):
+        return level_step_padded.__wrapped__(
+            *args, width=W, params=_split_params(), hist_impl="xla",
+            subtract=True)
+
+    args = _level_args(one_chip, one_chip) + (
+        _shape((W, F, B, 2), jnp.float32, one_chip),  # hist_prev
+        _shape((), jnp.int32, one_chip))              # node0, traced
+    compiled = jax.jit(interior).lower(*args).compile()
+    assert "custom_call_target=\"xtb_" not in compiled.as_text()
+
+
+def test_sharded_level_program_allreduces_on_four_chips(topo, device_paths,
+                                                        monkeypatch):
+    """What ``n_devices=4`` runs: the padded level step under shard_map on a
+    mesh of the described chip's four devices.  The histogram has to cross
+    the chips, so the compiled text holds an all-reduce."""
+    from xgboost_tpu.parallel.grower import ShardedHistTreeGrower
+    from xgboost_tpu.parallel.mesh import DATA_AXIS
+
+    # the platform rule for sharing one padded program asks the default
+    # backend, which is the CPU here: hold it to what a chip gets
+    monkeypatch.setattr("xgboost_tpu.tree.grow.default_padded_levels",
+                        lambda max_depth: True)
+    mesh = Mesh(np.asarray(topo.devices[:4]), (DATA_AXIS,))
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+    rep = NamedSharding(mesh, P())
+    grower = ShardedHistTreeGrower(DEPTH, _split_params(), mesh)
+    grower._build(F, B)
+    W = 1 << (DEPTH - 1)
+    args = _level_args(rows, rep) + (
+        _shape((W, F, B, 2), jnp.float32, rep), _shape((), jnp.int32, rep))
+    compiled = grower._interior_fn.lower(*args).compile()
+    assert "all-reduce" in compiled.as_text()

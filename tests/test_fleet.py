@@ -323,6 +323,63 @@ def test_program_key_is_architecture_not_weights(tmp_path):
     assert program_key(sa, 64) != program_key(sc, 64)
 
 
+def test_program_key_holds_the_device_set(tmp_path, monkeypatch):
+    # a serialized executable carries the device set it was compiled
+    # against: the same model and bucket in a process with another number
+    # of devices, or another kind of device, is another program
+    import jax
+
+    from xgboost_tpu.serving import warmcache
+
+    bst, _ = _train(seed=7, rounds=3, depth=3)
+    store = ModelStore(str(tmp_path))
+    store.publish("a", bst)
+    snap = store.snapshot("a", device=False)
+    here = program_key(snap, 64)
+    monkeypatch.setattr(jax, "device_count", lambda: jax.local_device_count() + 3)
+    fewer = program_key(snap, 64)
+    assert fewer != here
+    monkeypatch.undo()
+    assert program_key(snap, 64) == here
+
+    class OtherKind:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(warmcache, "_program_device", lambda: OtherKind())
+    assert program_key(snap, 64) != here
+
+
+def test_configure_persistent_cache_leaves_the_environment_in_charge(
+        tmp_path):
+    # where JAX_COMPILATION_CACHE_DIR is set, jax's own reading of it stands:
+    # the helper names no directory, whatever its caller passes.  Unset, the
+    # cache goes to the caller's directory or to .jax_cache/ in the checkout.
+    # (In a child: the cache's place is process-wide state.)
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys; from xgboost_tpu.serving.warmcache import "
+            "configure_persistent_cache as c; print(c(*sys.argv[1:]))")
+
+    def place(*args, **env):
+        base = {k: v for k, v in os.environ.items()
+                if k != "JAX_COMPILATION_CACHE_DIR"}
+        r = subprocess.run([sys.executable, "-c", code, *args], cwd=root,
+                           env={**base, **env}, capture_output=True,
+                           text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-1000:]
+        return r.stdout.strip().splitlines()[-1]
+
+    assert place(str(tmp_path / "other"),
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / "env")) == str(
+        tmp_path / "env")
+    assert not (tmp_path / "other").exists()
+    assert place(str(tmp_path / "mine")) == str(tmp_path / "mine")
+    assert (tmp_path / "mine").is_dir()
+    assert place() == os.path.join(root, ".jax_cache")
+
+
 def test_warmcache_attach_and_reload(tmp_path):
     bst, X = _train(seed=9)
     store = ModelStore(str(tmp_path / "store"))
@@ -363,6 +420,32 @@ def test_fleet_config_validation():
     assert cfg.resolve_slo(None).name == "default"
     with pytest.raises(ValueError):
         ServingFleet({}, n_replicas=1).start()  # no models
+
+
+def test_fleet_refuses_more_chip_replicas_than_can_start(fleet_models,
+                                                         monkeypatch):
+    """A chip belongs to one process.  The driver learns the replicas'
+    platform from the configuration (or the JAX_PLATFORMS they inherit),
+    never from JAX, and a fleet that asks for several chip-holding
+    replicas fails with the reason before it spawns one."""
+    spawned = []
+    monkeypatch.setattr(ServingFleet, "_spawn",
+                        lambda self, label: spawned.append(label))
+    with pytest.raises(ValueError, match="a chip belongs to one process"):
+        ServingFleet({"a": fleet_models["a"]}, n_replicas=2,
+                     platform="tpu").start()
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with pytest.raises(ValueError, match="platform 'tpu'"):
+        ServingFleet({"a": fleet_models["a"]}, n_replicas=4,
+                     n_shards=2).start()
+    assert spawned == []
+    fleet = ServingFleet({"a": fleet_models["a"]}, n_replicas=1)
+    assert fleet._replica_platform() == "tpu"
+    fleet._check_chip_replicas()  # one chip-holding replica may start
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert fleet._replica_platform() is None  # left to the replica
+    assert ServingFleet({"a": fleet_models["a"]}, n_replicas=8,
+                        platform="cpu")._replica_platform() == "cpu"
 
 
 # =========================================================================

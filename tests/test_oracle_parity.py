@@ -1,8 +1,8 @@
 """Parity against the real dmlc/xgboost (the oracle).
 
 Round-1 verdict: the repo's numpy mirror shares this package's reading of
-xgboost semantics, so agreement between them proves nothing (VERDICT.md
-"parity tests are circular").  These tests compare against the actual
+xgboost semantics, so agreement between them proves nothing (the parity
+tests were circular).  These tests compare against the actual
 reference implementation, built CPU-only from /root/reference by
 oracle/build_oracle.sh (see the dmlc shim there).  They skip when the oracle
 has not been built.

@@ -189,9 +189,10 @@ class Booster:
         if unknown and str(p.get("validate_parameters", "")).lower() in ("1", "true"):
             raise ValueError(f"Unknown parameters: {unknown}")
         self.tparam = TrainParam.from_dict(p)
-        self.context = Context.create(str(p.get("device", "cpu")),
+        self.context = Context.create(p.get("device"),
                                       nthread=int(p.get("nthread", 0) or 0),
                                       seed=int(p.get("seed", 0)))
+        self.context.check_device()
         # nthread reaches the native ParallelFor pool here (params dict /
         # XGBoosterSetParam("nthread") both land in p); results are bitwise
         # independent of the value (docs/native_threading.md)
@@ -2320,7 +2321,7 @@ class Booster:
              getattr(self, "multi_strategy", "one_output_per_tree"))
 
         generic = {}
-        take(generic, "device", "tpu")
+        take(generic, "device")  # recorded only where the user asserted one
         take(generic, "seed", 0)
         take(generic, "seed_per_iteration", 0)
         take(generic, "nthread", 0)

@@ -151,7 +151,7 @@ def _lambda_gradients_topk_native(pred, y, gptr, *, k: int,
     R = pred.shape[0]
     shapes = (jax.ShapeDtypeStruct((R,), jnp.float32),
               jax.ShapeDtypeStruct((R,), jnp.float32))
-    call = native.jax_ffi().ffi_call("xtb_lambdarank", shapes)
+    call = jax.ffi.ffi_call("xtb_lambdarank", shapes)
     return call(pred.astype(jnp.float32), y.astype(jnp.float32),
                 gptr.astype(jnp.int32), k=np.int32(k),
                 ndcg_weight=np.int32(ndcg_weight),

@@ -1,23 +1,32 @@
-"""Pallas TPU histogram kernel — the production hot path.
+"""Pallas TPU histogram kernel — the fused form of ops/histogram.py.
 
 The CUDA reference builds histograms with shared-memory atomics
 (src/tree/gpu_hist/histogram.cu:37-120).  TPU has no atomics; the masked
 one-hot matmul formulation (ops/histogram.py) is MXU-shaped, but the plain XLA
 lowering materializes the (rows, F*B) one-hot operand in HBM — hundreds of GB
 of traffic per level at HIGGS scale.  This kernel fuses one-hot construction
-into VMEM so HBM sees only: bins read once (R*F bytes), gpair read once per
-feature group, histogram written once.
+into VMEM so HBM sees only: bins read once (R*F*itemsize bytes), the gradient
+operand read once per feature group, histogram written once.
 
-Layout:
+Layout — rows ride the 128-lane axis everywhere, so every block is
+lane-dense and no value is ever sliced off the lane axis:
+  inputs (transposed once per call by the wrapper):
+      bins_t (F, R) int, vals_t (C, R) f32|int8, pos (1, R) int32
   grid = (F/FG feature groups, R/T row tiles)   [both arbitrary/sequential]
-  per step: bins tile (T, FG) + gpair tile (T, 2) + pos tile (T, 1) in VMEM
-  out block (FG, B, 2N) stays VMEM-resident across the row-tile loop of one
-  feature group (index_map ignores the row index) and accumulates f32 matmuls:
-      hist[f] += onehot(bins[:, f]).T @ (nodemask * gpair)    # (B,T)@(T,2N)
-  MXU shapes: M=B (256), K=T (512), N=2N -> full utilization at depth >= 6.
+  per step: bins tile (FG, T) + operand tile (C, T) + pos tile (1, T) in VMEM
+  out block (FG, C*N, B) stays VMEM-resident across the row-tile loop of one
+  feature group (index_map ignores the row index) and accumulates
+      hist[f] += gm @ onehot(bins_t[f]).T            # (C*N, T) x (B, T)^T
+  where gm[c*N + n] = vals_t[c] masked to the rows sitting in node n.
 
-Determinism: sequential grid, f32 accumulation, no atomics — the property the
-reference buys with int64 fixed-point quantisation (quantiser.cuh:52).
+One kernel body serves the float32 form (C=2 channels g,h; f32 accumulate
+pinned to ``Precision.HIGHEST``: the one-hot operand is exact in bf16 but
+the gradient operand is not, and a default-precision f32 MXU matmul rounds
+it to 8 mantissa bits) and the quantised form (C=6 int8 limbs; int32
+accumulate, exact and order-free — the reference's GradientQuantiser
+contract, quantiser.cuh:52).
+
+Determinism: sequential grid, fixed accumulation order, no atomics.
 """
 from __future__ import annotations
 
@@ -28,21 +37,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4 -> 0.5+
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+# Mosaic's default scoped-VMEM limit is 16 MiB; a v5e core has 128 MiB.  The
+# kernel asks for _VMEM_LIMIT explicitly and choose_tiles plans the working
+# set under _VMEM_BUDGET, the rest being the compiler's own temporaries (the
+# role of the reference's CacheManager L1/L2 detection for CPU hist
+# blocking, src/common/cache_manager.h).
+_VMEM_LIMIT = 64 * 2**20
+_VMEM_BUDGET = 40 * 2**20
 
-# sweep overrides (scripts/pallas_hw_sweep.py); None = VMEM-budget autotune
-_ROW_TILE = None
-_FEAT_GROUP = None
+_LANES = 128
+# widest sublane packing among the bin dtypes (int8: 32 rows per tile); a
+# feature group of this height is a legal block for uint8, int16 and int32
+_FEAT_GROUP = 32
 
-# Per-core VMEM working budget.  v5e/v5p expose ~128 MiB of VMEM; leaving
-# headroom for the compiler's own temporaries and double-buffering slack,
-# 64 MiB is the planning number (the role of the reference's CacheManager
-# L1/L2 detection for CPU hist blocking, src/common/cache_manager.h — there
-# the cache sizes block the CPU hist loop, here the VMEM budget blocks the
-# MXU hist kernel).
-_VMEM_BUDGET = 64 * 2**20
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def choose_tiles(n_features: int, n_bin: int, n_nodes: int,
@@ -50,55 +60,149 @@ def choose_tiles(n_features: int, n_bin: int, n_nodes: int,
                  vmem_budget: int = _VMEM_BUDGET, out_ch: int = 2) -> tuple:
     """Pick (row_tile, feat_group) that fits the VMEM budget.
 
-    Working set per grid step:
-      - persistent out block: FG * B * out_ch*N * 4 bytes (lives across row
-        tiles; out_ch = 2 for the f32 (g,h) kernel, 6 for the quantised
-        (g,h) x 3-limb kernel)
-      - double-buffered inputs: 2 * T * (FG*itemsize + 8 + 4)
-      - scratch (one feature at a time in the unrolled loop):
-        onehot T*B*4 + node-masked gpair T*out_ch*N*4 + nodemask T*N*4
-    Preference order: biggest row tile first (deeper MXU K dim), then the
-    widest feature group that still fits — the shapes the hardware sweep
-    showed to matter most.  Always returns something runnable (1, 256).
+    The feature group is not free: Mosaic takes a (FG, T) block of the
+    (F, R) bins only when FG is the whole feature axis or a multiple of the
+    dtype's sublane tile, so FG = F for narrow data and 32 otherwise.  The
+    row tile is the largest of 1024..256 whose working set fits (on a v5e
+    at 2M x 28 x 256 bins a 2048-row tile took 2.5 to 4 times as long to
+    compile as a 1024-row one and ran the float32 form 15% slower; 512 and
+    1024 ran alike — smoke timings of PR 21, see PERF.md):
+      - out block, double-buffered: 2 * FG * roundup(out_ch*N, 8) * B_pad * 4
+        (out_ch = 2 for the f32 (g,h) kernel, 6 for the (g,h) x 3-limb one)
+      - double-buffered inputs: 2 * T * (FG*itemsize + 8*4 + 8*4)
+      - scratch: widened bins FG*T*4, one-hot B_pad*T*4 (one feature at a
+        time), masked operand and its iota temporaries 3 * out_ch*N*T*4
+    Always returns something; the compiler refuses what does not fit.
     """
-    for t in (2048, 1024, 512, 256):
-        for fg in (16, 8, 4, 2, 1):
-            if fg > max(n_features, 1):
-                continue
-            out_b = fg * n_bin * out_ch * n_nodes * 4
-            in_b = 2 * t * (fg * bin_itemsize + 8 + 4)
-            scratch = (t * n_bin * 4 + t * out_ch * n_nodes * 4
-                       + t * n_nodes * 4)
-            if out_b + in_b + scratch <= vmem_budget:
-                return t, fg
-    return 256, 1
+    fg = n_features if n_features <= _FEAT_GROUP else _FEAT_GROUP
+    fg = max(fg, 1)
+    m = _round_up(out_ch * n_nodes, 8)
+    b_pad = _round_up(n_bin, _LANES)
+    out_b = 2 * fg * m * b_pad * 4
+    for t in (1024, 512, 256):
+        in_b = 2 * t * (fg * bin_itemsize + 64)
+        scratch = fg * t * 4 + b_pad * t * 4 + 3 * m * t * 4
+        if out_b + in_b + scratch <= vmem_budget:
+            return t, fg
+    return 256, fg
 
 
-def _hist_kernel(bins_ref, gpair_ref, pos_ref, out_ref, *, node0: int,
-                 n_nodes: int, n_bin: int, feat_group: int, stride: int):
-    i = pl.program_id(1)  # row-tile index (innermost)
+def _resolve_interpret(interpret):
+    """``None`` = compile for the chip on TPU, interpret on CPU (so the
+    hist_impl="pallas" grower path works, slowly, in CPU tests).  Never
+    interpret mode on a chip, never a guess on any other platform."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        "the fused histogram kernel compiles for TPU and interprets on CPU; "
+        f"JAX's default backend here is {backend!r}")
 
-    @pl.when(i == 0)
+
+def _masked_operand(pos_row, vals, *, node0: int, n_nodes: int, stride: int):
+    """(C*N, T) matmul operand, channel-major: row c*N + n holds channel c of
+    ``vals`` (C, T) for the rows whose ``pos`` is node n, zero elsewhere.
+    Built from a 2-D iota and selects only — no reshape, no concatenate, no
+    integer division (none of which Mosaic takes at arbitrary C, N)."""
+    C, T = vals.shape
+    r = jax.lax.broadcasted_iota(jnp.int32, (C * n_nodes, T), 0)
+    ch = jnp.zeros_like(r)
+    for c in range(1, C):
+        ch = ch + (r >= c * n_nodes).astype(jnp.int32)
+    node = node0 + stride * (r - ch * n_nodes)
+    val = jnp.broadcast_to(vals[0:1, :], r.shape)
+    for c in range(1, C):
+        val = jnp.where(ch == c, vals[c:c + 1, :], val)
+    return jnp.where(pos_row == node, val, jnp.zeros_like(val))
+
+
+def _hist_kernel(bins_ref, vals_ref, pos_ref, out_ref, *, node0: int,
+                 n_nodes: int, stride: int):
+    @pl.when(pl.program_id(1) == 0)  # first row tile of this feature group
     def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    pos = pos_ref[:, 0]  # (T,)
-    gpair = gpair_ref[:, :2]  # (T, 2)
-    nodes = node0 + stride * jax.lax.iota(jnp.int32, n_nodes)
-    nodemask = (pos[:, None] == nodes[None, :]).astype(jnp.float32)  # (T, N)
-    T = gpair.shape[0]
-    gm = (nodemask[:, :, None] * gpair[:, None, :]).reshape(T, n_nodes * 2)
-
-    bin_ids = jax.lax.iota(jnp.int32, n_bin)
-    for f in range(feat_group):  # static unroll
-        b = bins_ref[:, f].astype(jnp.int32)  # (T,)
-        onehot = (b[:, None] == bin_ids[None, :]).astype(jnp.float32)  # (T, B)
-        acc = jax.lax.dot_general(
-            onehot, gm,
-            dimension_numbers=(((0,), (0,)), ((), ())),  # contract rows: (B, 2N)
-            preferred_element_type=jnp.float32,
+    op_dtype = vals_ref.dtype  # float32 | int8
+    quantised = jnp.issubdtype(op_dtype, jnp.integer)
+    wide = jnp.int32 if quantised else jnp.float32
+    # 0/1 mask times a limb is the limb: the masked operand stays int8-safe
+    gm = _masked_operand(pos_ref[...], vals_ref[...].astype(wide),
+                         node0=node0, n_nodes=n_nodes,
+                         stride=stride).astype(op_dtype)
+    bins = bins_ref[...].astype(jnp.int32)  # (FG, T)
+    FG, T = bins.shape
+    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[2], T), 0)
+    for f in range(FG):  # static unroll
+        # (B, T); the missing sentinel (== n_bin) lands in a pad column or
+        # nowhere, never in a real bin
+        onehot = (bins[f:f + 1, :] == bin_ids).astype(wide).astype(op_dtype)
+        out_ref[f] += jax.lax.dot_general(
+            gm, onehot,
+            dimension_numbers=(((1,), (1,)), ((), ())),  # contract rows
+            preferred_element_type=out_ref.dtype,
+            precision=None if quantised else jax.lax.Precision.HIGHEST,
         )
-        out_ref[f] = out_ref[f] + acc
+
+
+def _fused_hist(bins, vals_t, pos, *, node0: int, n_nodes: int, n_bin: int,
+                stride: int, interpret, row_tile: int, feat_group: int,
+                acc_dtype):
+    """Shared wrapper: (N, F, B, C) from bins (R, F), vals_t (C, R), pos (R,).
+    Rows are padded up to the row tile (pad rows carry pos = -1, matching no
+    node), features up to the feature group, bins up to the lane width."""
+    interpret = _resolve_interpret(interpret)
+    R, F = bins.shape
+    C = vals_t.shape[0]
+    M = C * n_nodes
+    T, FG = row_tile, feat_group
+    if not (T and FG):
+        at, afg = choose_tiles(F, n_bin, n_nodes, bins.dtype.itemsize,
+                               out_ch=C)
+        T, FG = T or at, FG or afg
+    R_pad, F_pad = _round_up(R, T), _round_up(F, FG)
+    B_pad = _round_up(n_bin, _LANES)
+    bins_t = jnp.pad(bins.T, ((0, F_pad - F), (0, R_pad - R)),
+                     constant_values=n_bin)
+    vals_t = jnp.pad(vals_t, ((0, 0), (0, R_pad - R)))
+    pos_row = jnp.pad(pos.astype(jnp.int32), (0, R_pad - R),
+                      constant_values=-1)[None, :]
+    n_fg = F_pad // FG
+
+    out = pl.pallas_call(
+        functools.partial(_hist_kernel, node0=node0, n_nodes=n_nodes,
+                          stride=stride),
+        grid=(n_fg, R_pad // T),
+        in_specs=[
+            pl.BlockSpec((FG, T), lambda fg, i: (fg, i),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((C, T), lambda fg, i: (0, i),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, T), lambda fg, i: (0, i),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((FG, M, B_pad), lambda fg, i: (fg, 0, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((F_pad, M, B_pad), acc_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * R_pad * F_pad * B_pad * M,
+            bytes_accessed=R_pad * F_pad * bins.dtype.itemsize
+            + R_pad * (C * vals_t.dtype.itemsize + 4) * n_fg
+            + F_pad * M * B_pad * 4,
+            transcendentals=0,
+        ),
+        interpret=interpret,
+    )(bins_t, vals_t, pos_row)
+    # (F_pad, C*N, B_pad) -> (N, F, B, C)
+    return out[:F, :, :n_bin].reshape(F, C, n_nodes, n_bin).transpose(
+        2, 0, 3, 1)
 
 
 @functools.partial(
@@ -111,97 +215,14 @@ def build_histogram_pallas(bins, gpair, pos, *, node0: int, n_nodes: int,
     """hist (n_nodes, F, B, 2) — drop-in for ops/histogram.build_histogram.
 
     bins (R_pad, F) int (sentinel == n_bin for missing), gpair (R_pad, 2) f32,
-    pos (R_pad,) int32.  Rows are padded up to the row tile internally
-    (pad rows carry pos = -1, matching no node).  ``row_tile``/``feat_group``
-    of 0 select the VMEM-budget autotune (choose_tiles); the module globals
-    remain overridable for sweeps.
+    pos (R_pad,) int32.  ``row_tile``/``feat_group`` of 0 select the
+    VMEM-budget plan (choose_tiles); an explicit feature group compiles only
+    where choose_tiles' rule holds, and runs anywhere in interpret mode.
     """
-    if interpret is None:
-        # auto: lower to Mosaic on TPU, run the Pallas interpreter elsewhere
-        # so the hist_impl="pallas" grower path works (slowly) off-TPU
-        interpret = jax.default_backend() != "tpu"
-    R, F = bins.shape
-    # explicit kwargs > module-global sweep override > autotune; a partial
-    # override (one of the two) autotunes only the missing dimension
-    T = row_tile or _ROW_TILE
-    FG = feat_group or _FEAT_GROUP
-    if not (T and FG):
-        at, afg = choose_tiles(F, n_bin, n_nodes, bins.dtype.itemsize)
-        T, FG = T or at, FG or afg
-    if R % T:
-        pad = T - R % T
-        bins = jnp.pad(bins, ((0, pad), (0, 0)), constant_values=n_bin)
-        gpair = jnp.pad(gpair, ((0, pad), (0, 0)))
-        pos = jnp.pad(pos, (0, pad), constant_values=-1)
-        R += pad
-    n_fg = (F + FG - 1) // FG
-    F_pad = n_fg * FG
-
-    kernel = functools.partial(
-        _hist_kernel, node0=node0, n_nodes=n_nodes, n_bin=n_bin, feat_group=FG,
-        stride=stride,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_fg, R // T),
-        in_specs=[
-            pl.BlockSpec((T, FG), lambda fg, i: (i, fg), memory_space=pltpu.VMEM),
-            pl.BlockSpec((T, 2), lambda fg, i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((T, 1), lambda fg, i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (FG, n_bin, 2 * n_nodes), lambda fg, i: (fg, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((F_pad, n_bin, 2 * n_nodes), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * R * F_pad * n_bin * 2 * n_nodes,
-            bytes_accessed=R * F_pad * bins.dtype.itemsize + R * 8 * n_fg
-            + F_pad * n_bin * 2 * n_nodes * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(bins, gpair, pos[:, None].astype(jnp.int32))
-    # (F_pad, B, 2N) -> (N, F, B, 2)
-    hist = out[:F].reshape(F, n_bin, n_nodes, 2).transpose(2, 0, 1, 3)
-    return hist
-
-
-def _hist_kernel_q(bins_ref, gq_ref, pos_ref, out_ref, *, node0: int,
-                   n_nodes: int, n_bin: int, feat_group: int, stride: int,
-                   n_ch: int):
-    """Quantised variant: int8 one-hot x int8 limb operand -> int32 MXU
-    accumulation.  Integer partial sums are exact and associative, so the
-    kernel output is bitwise identical for ANY grid order or topology — the
-    reference's GradientQuantiser contract (quantiser.cuh:52) inside the
-    production kernel."""
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    pos = pos_ref[:, 0]  # (T,)
-    gq = gq_ref[:, :n_ch]  # (T, C*3) int8 limbs
-    nodes = node0 + stride * jax.lax.iota(jnp.int32, n_nodes)
-    nodemask = (pos[:, None] == nodes[None, :]).astype(jnp.int8)  # (T, N)
-    T = gq.shape[0]
-    # 0/1 mask times a limb is the limb: product stays int8-safe
-    gm = (nodemask[:, :, None] * gq[:, None, :]).reshape(T, n_nodes * n_ch)
-
-    bin_ids = jax.lax.iota(jnp.int32, n_bin)
-    for f in range(feat_group):  # static unroll
-        b = bins_ref[:, f].astype(jnp.int32)
-        onehot = (b[:, None] == bin_ids[None, :]).astype(jnp.int8)  # (T, B)
-        acc = jax.lax.dot_general(
-            onehot, gm,
-            dimension_numbers=(((0,), (0,)), ((), ())),  # (B, N*n_ch)
-            preferred_element_type=jnp.int32,
-        )
-        out_ref[f] = out_ref[f] + acc
+    return _fused_hist(bins, gpair[:, :2].astype(jnp.float32).T, pos,
+                       node0=node0, n_nodes=n_nodes, n_bin=n_bin,
+                       stride=stride, interpret=interpret, row_tile=row_tile,
+                       feat_group=feat_group, acc_dtype=jnp.float32)
 
 
 @functools.partial(
@@ -213,61 +234,16 @@ def build_histogram_pallas_q(bins, gq, pos, *, node0: int, n_nodes: int,
                              stride: int = 1, row_tile: int = 0,
                              feat_group: int = 0):
     """Quantised Pallas histogram: (n_nodes, F, B, C, 3) int32 — drop-in for
-    ops/quantise.hist_accumulate_q on TPU, keeping the bitwise
-    topology-free determinism contract inside the fused VMEM kernel.
+    ops/quantise.hist_accumulate_q on TPU.  int8 one-hot x int8 limb operand
+    -> int32 MXU accumulation: integer partial sums are exact and
+    associative, so the output is bitwise identical for ANY grid order or
+    topology.
 
     gq (R_pad, C, 3) int8 signed base-256 limbs (ops/quantise.quantise_gpair).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    R, F = bins.shape
-    C, L = gq.shape[1], gq.shape[2]
-    n_ch = C * L
-    gq = gq.reshape(R, n_ch)
-    T = row_tile or _ROW_TILE
-    FG = feat_group or _FEAT_GROUP
-    if not (T and FG):
-        at, afg = choose_tiles(F, n_bin, n_nodes, bins.dtype.itemsize,
-                               out_ch=n_ch)
-        T, FG = T or at, FG or afg
-    if R % T:
-        pad = T - R % T
-        bins = jnp.pad(bins, ((0, pad), (0, 0)), constant_values=n_bin)
-        gq = jnp.pad(gq, ((0, pad), (0, 0)))
-        pos = jnp.pad(pos, (0, pad), constant_values=-1)
-        R += pad
-    n_fg = (F + FG - 1) // FG
-    F_pad = n_fg * FG
-
-    kernel = functools.partial(
-        _hist_kernel_q, node0=node0, n_nodes=n_nodes, n_bin=n_bin,
-        feat_group=FG, stride=stride, n_ch=n_ch,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_fg, R // T),
-        in_specs=[
-            pl.BlockSpec((T, FG), lambda fg, i: (i, fg), memory_space=pltpu.VMEM),
-            pl.BlockSpec((T, n_ch), lambda fg, i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((T, 1), lambda fg, i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (FG, n_bin, n_ch * n_nodes), lambda fg, i: (fg, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((F_pad, n_bin, n_ch * n_nodes),
-                                       jnp.int32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * R * F_pad * n_bin * n_ch * n_nodes,
-            bytes_accessed=R * F_pad * bins.dtype.itemsize + R * n_ch * n_fg
-            + F_pad * n_bin * n_ch * n_nodes * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(bins, gq, pos[:, None].astype(jnp.int32))
-    # (F_pad, B, N*C*L) -> (N, F, B, C, L)
-    hist = out[:F].reshape(F, n_bin, n_nodes, C, L).transpose(2, 0, 1, 3, 4)
-    return hist
+    R, C, L = gq.shape
+    hist = _fused_hist(bins, gq.reshape(R, C * L).T, pos, node0=node0,
+                       n_nodes=n_nodes, n_bin=n_bin, stride=stride,
+                       interpret=interpret, row_tile=row_tile,
+                       feat_group=feat_group, acc_dtype=jnp.int32)
+    return hist.reshape(hist.shape[:3] + (C, L))

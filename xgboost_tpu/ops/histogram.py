@@ -36,6 +36,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# A float32 matmul at default precision runs on the MXU as one bfloat16 pass:
+# the 0/1 one-hot operand survives that exactly, the gradient operand is
+# rounded to 8 mantissa bits before it is summed.  Every histogram matmul is
+# pinned, so that "float32 histogram" means float32 on the chip too.
+_EXACT_F32 = lax.Precision.HIGHEST
+
 
 def _hist_chunk(bins_c, gpair_c, pos_c, node0: int, n_nodes: int, n_bin: int,
                 stride: int = 1):
@@ -50,7 +56,8 @@ def _hist_chunk(bins_c, gpair_c, pos_c, node0: int, n_nodes: int, n_bin: int,
     ).astype(jnp.float32)  # (T, N)
     gm = (nodemask[:, :, None] * gpair_c[:, None, :]).reshape(T, n_nodes * C)
     out = jnp.dot(
-        onehot.reshape(T, F * n_bin).T, gm, preferred_element_type=jnp.float32
+        onehot.reshape(T, F * n_bin).T, gm, preferred_element_type=jnp.float32,
+        precision=_EXACT_F32,
     )  # (F*B, N*C)
     return out.reshape(F, n_bin, n_nodes, C).transpose(2, 0, 1, 3)
 
@@ -136,7 +143,7 @@ def _native_hist(bins, gpair, pos, node0, n_nodes, n_bin, stride):
     C = gpair.shape[1]
     if bins.dtype not in (jnp.uint8, jnp.uint16, jnp.int16, jnp.int32):
         bins = bins.astype(jnp.int32)
-    call = native.jax_ffi().ffi_call(
+    call = jax.ffi.ffi_call(
         "xtb_hist",
         jax.ShapeDtypeStruct((n_nodes, F, n_bin, C), jnp.float32))
     return call(bins, gpair.astype(jnp.float32), pos.astype(jnp.int32),
@@ -304,12 +311,17 @@ def combine_sibling_hists(left, hist_prev, alive_lvl):
 
 @functools.partial(jax.jit, static_argnames=("node0", "n_nodes"))
 def node_sums(gpair, pos, *, node0: int, n_nodes: int):
-    """Per-node gradient totals: (N, C) — masked segment sum, MXU-friendly.
+    """Per-node gradient totals: (N, C) — masked segment sum.
 
     Used for the root sum (reference: updater_gpu_hist.cu:581 InitRoot device
     reduce followed by collective::GlobalSum).
+
+    A reduction, not a matmul: a dot whose contraction runs over every row
+    adds them to one float32 accumulator in one chain, and a long run of
+    like-signed values (hessians) is then rounded the same way at every step.
+    On the CPU backend that left the root hessian 0.17% high at 200k rows
+    and 2.7% high at 2M; XLA's reduce sums in a tree and stays at 1e-6.
     """
-    nodemask = (pos[:, None] == (node0 + jnp.arange(n_nodes, dtype=pos.dtype))).astype(
-        jnp.float32
-    )
-    return jnp.dot(nodemask.T, gpair, preferred_element_type=jnp.float32)
+    nodemask = pos[:, None] == (node0 + jnp.arange(n_nodes, dtype=pos.dtype))
+    return jnp.sum(jnp.where(nodemask[:, :, None], gpair[:, None, :], 0.0),
+                   axis=0)

@@ -169,7 +169,7 @@ def _predict_native(X, feat, thr, dleft, left, right, value, groups,
           else jnp.zeros((T, M, 1), jnp.uint8))
     init_arr = (jnp.zeros((R, n_groups), jnp.float32) if init is None
                 else init.astype(jnp.float32))
-    call = native.jax_ffi().ffi_call(
+    call = jax.ffi.ffi_call(
         "xtb_predict", jax.ShapeDtypeStruct((R, n_groups), jnp.float32))
     return call(X.astype(jnp.float32), feat.astype(jnp.int32),
                 thr.astype(jnp.float32), dleft.astype(jnp.uint8),
@@ -289,7 +289,7 @@ def predict_margin_delta_binned(bins, feat, sbin, dleft, left, right, value,
         b = bins
         if b.dtype not in (jnp.uint8, jnp.uint16, jnp.int16, jnp.int32):
             b = b.astype(jnp.int32)
-        call = native.jax_ffi().ffi_call(
+        call = jax.ffi.ffi_call(
             "xtb_predict_binned",
             jax.ShapeDtypeStruct((R, n_groups), jnp.float32))
         return call(b, feat.astype(jnp.int32), sbin.astype(jnp.int32),

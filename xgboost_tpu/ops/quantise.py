@@ -118,7 +118,7 @@ def hist_accumulate_q(bins, gq, pos, node0, n_nodes: int, n_bin: int,
         b = bins
         if b.dtype not in (jnp.uint8, jnp.uint16, jnp.int16, jnp.int32):
             b = b.astype(jnp.int32)
-        call = native.jax_ffi().ffi_call(
+        call = jax.ffi.ffi_call(
             "xtb_hist_q",
             jax.ShapeDtypeStruct((n_nodes, F, n_bin, C * L), jnp.int32))
         flat = call(b, gq.reshape(R, C * L), pos.astype(jnp.int32),

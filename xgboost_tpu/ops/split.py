@@ -226,7 +226,7 @@ def _evaluate_splits_native(hist, totals, n_bins, params: SplitParams,
     from ..utils import native as _native
 
     _native.ensure_pool()
-    call = _native.jax_ffi().ffi_call("xtb_split", shapes)
+    call = jax.ffi.ffi_call("xtb_split", shapes)
     gain, feat, bin_, dleft, GL, HL = call(
         hist.astype(jnp.float32), totals.astype(jnp.float32),
         n_bins.astype(jnp.int32), fm.astype(jnp.uint8),

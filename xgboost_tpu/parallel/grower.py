@@ -17,11 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 graduated shard_map to the top-level namespace
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x: pre-graduation home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..ops.split import SplitParams
 from ..telemetry import span
 from ..tree.grow import (TreeState, init_tree_state, level_step,
@@ -70,7 +65,7 @@ class ShardedHistTreeGrower:
         n_sets = make_set_matrix(self.interaction_sets, n_features).shape[0]
 
         self._init_fn = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 functools.partial(
                     init_tree_state, max_nodes=self.max_nodes, axis_name=ax,
                     n_sets=n_sets, n_bin=n_bin,
@@ -106,7 +101,7 @@ class ShardedHistTreeGrower:
                 has_cat=has_cat, subtract=True, quantised=q,
             )
             self._interior_fn = jax.jit(
-                _shard_map(pad_base, mesh=self.mesh,
+                jax.shard_map(pad_base, mesh=self.mesh,
                               in_specs=row_specs + (P(), P()) + rho_specs,
                               out_specs=(sspec, P()))
             )
@@ -150,7 +145,7 @@ class ShardedHistTreeGrower:
                 in_specs = row_specs + rho_specs
                 out_specs = (sspec, P())
             self._level_fns[d] = jax.jit(
-                _shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                               out_specs=out_specs)
             )
         self._built_for = (n_features, n_bin, has_cat)
@@ -263,7 +258,7 @@ class ShardedMultiTargetGrower:
         ax = DATA_AXIS
         sspec = self._state_specs(ax)
         self._init_fn = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 functools.partial(
                     init_multi_state, max_nodes=self.max_nodes,
                     n_targets=self.n_targets, axis_name=ax,
@@ -295,7 +290,7 @@ class ShardedMultiTargetGrower:
             else:
                 fn, in_specs, out_specs = base, row_specs, (sspec, P())
             self._level_fns[d] = jax.jit(
-                _shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                               out_specs=out_specs)
             )
         self._built_for = (n_features, n_bin)

@@ -799,6 +799,7 @@ class ServingFleet:
 
         from .modelstore import ModelStore
 
+        self._check_chip_replicas()
         if self.config.n_shards > 1:
             return self._start_sharded()
         with self._cv:
@@ -973,6 +974,29 @@ class ServingFleet:
             self._bringup_done = True
         return self
 
+    def _replica_platform(self) -> Optional[str]:
+        """The platform the replicas will run on, as far as the
+        configuration says: ``FleetConfig.platform``, else the
+        ``JAX_PLATFORMS`` they inherit, else None (the replica finds out).
+        Never by asking JAX: a process that has initialised JAX on a chip
+        holds it, and a replica that needs it would then fail or hang."""
+        plat = self.config.platform or os.environ.get("JAX_PLATFORMS", "")
+        return plat.split(",")[0].strip().lower() or None
+
+    def _check_chip_replicas(self) -> None:
+        """A chip belongs to one process, and a replica process takes every
+        chip its JAX can see (per-replica chip assignment is not built), so
+        more than one chip-holding replica cannot start: refuse it here,
+        with the reason, before anything is spawned."""
+        plat = self._replica_platform()
+        if plat not in (None, "cpu") and self.config.n_replicas > 1:
+            raise ValueError(
+                f"fleet of {self.config.n_replicas} replicas on platform "
+                f"{plat!r}: each replica is a process that claims every "
+                f"chip it can see, and a chip belongs to one process, so "
+                f"only one chip-holding replica can start.  Ask for "
+                f"n_replicas=1, or platform='cpu' for host replicas.")
+
     def _spawn(self, label: str) -> None:
         port = self._listener.getsockname()[1]
         repo_root = os.path.dirname(os.path.dirname(
@@ -980,14 +1004,7 @@ class ServingFleet:
         env = dict(os.environ)
         env["PYTHONPATH"] = repo_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        plat = self.config.platform
-        if plat is None:
-            try:
-                import jax
-
-                plat = jax.default_backend()
-            except Exception:
-                plat = None
+        plat = self._replica_platform()
         if plat == "cpu" and self.config.nthread_per_replica > 0:
             # N replicas each spawning an ncores-wide spinning XLA intra-op
             # pool convoy each other off the host (4 replicas on 2 cores
@@ -996,7 +1013,8 @@ class ServingFleet:
             # at the configured per-replica width.  This REPLACES any
             # inherited XLA_FLAGS for CPU replicas (set
             # nthread_per_replica=0 to pass the parent's flags through);
-            # on other backends replicas inherit the environment as-is.
+            # on other platforms, and where the platform is not stated,
+            # replicas inherit the environment as-is.
             env["XLA_FLAGS"] = (
                 "--xla_cpu_multi_thread_eigen=false "
                 f"intra_op_parallelism_threads="

@@ -3,9 +3,11 @@
 Replica cold-start has two compile layers, attacked separately:
 
 1. **XLA persistent compilation cache** (:func:`configure_persistent_cache`)
-   — ``jax_compilation_cache_dir`` pointed at the fleet cache directory, so
-   every backend compile (peripheral eager ops, transforms, anything not
-   AOT-covered) is a disk hit after the first replica ever ran.  This layer
+   — JAX's own cache, so every backend compile (peripheral eager ops,
+   transforms, anything not AOT-covered) is a disk hit after the first
+   process that ran it.  Where ``JAX_COMPILATION_CACHE_DIR`` is set that is
+   the directory and nothing here names another; otherwise it is the
+   caller's directory or one fixed directory in the checkout.  This layer
    skips *compilation* but still pays trace + lowering per program.
 
 2. **AOT program warm file** (``programs.pkl``) — the serving margin
@@ -20,9 +22,10 @@ The serialized program is a *fused serve step*: bucket-padded rows in,
 executable serves both ``output_margin`` polarities, and the warm path
 never traces the peripheral add/transform ops either.  Programs are keyed
 by everything that shapes the executable (stacked tensor shapes/dtypes,
-depth, group count, objective, bucket, jax/backend version), NOT by the
-weights: two same-architecture model versions share one program, so a
-hot-swapped retrain warms instantly.
+depth, group count, objective, bucket, jax/backend version, device kind
+and count), NOT by the weights: two same-architecture model versions share
+one program, so a hot-swapped retrain warms instantly.  A program is built
+for one device and reloaded onto that device only.
 
 Executables embed the ``xtb_predict`` FFI custom call; deserialization
 requires the native library's targets registered first —
@@ -46,20 +49,34 @@ _WARM_FILE = "programs.pkl"
 _FORMAT = 1
 
 
-def configure_persistent_cache(cache_dir: str) -> None:
-    """Point jax's persistent compilation cache at ``cache_dir`` (idempotent;
-    call before the first jit of the process for full effect)."""
+# the persistent cache's home when neither the environment nor the caller
+# names one: a fixed path (the path is part of the cache key, so a directory
+# that moves never hits), inside the checkout and git-ignored
+_DEFAULT_JAX_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache and return its directory
+    (idempotent; call before the first jit of the process for full effect).
+
+    The one place in the repository that may place that cache — the smoke,
+    the bench and the replica all come through here.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own reading of it stands and
+    no directory is set in code; otherwise the cache goes to ``cache_dir``
+    or, without one, to ``.jax_cache/`` at the root of the checkout."""
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", os.fspath(cache_dir))
-    # serving programs are small and fast to compile individually — cache
-    # all of them, not just the slow ones
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir = os.fspath(cache_dir or _DEFAULT_JAX_CACHE)
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # the programs here are many and individually quick to compile — cache
+    # all of them, not just the slow or the large ones
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # knob added in jax 0.4.30; older = size 0 floor
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
 
 
 def program_key(snap, bucket: int) -> str:
@@ -67,13 +84,15 @@ def program_key(snap, bucket: int) -> str:
 
     Hashes program *shape*, never weights — see module docstring.  The jax
     and backend versions are folded in because serialized executables are
-    not portable across them.
+    not portable across them, the device kind and count because an
+    executable carries the device set it was compiled against.
     """
     import jax
 
     h = hashlib.sha256()
     h.update(f"fmt{_FORMAT}|jax{jax.__version__}|"
-             f"{jax.default_backend()}|".encode())
+             f"{jax.default_backend()}|{_program_device().device_kind}|"
+             f"n{jax.device_count()}|".encode())
     h.update(f"b{int(bucket)}|d{snap.depth}|g{snap.n_groups}|"
              f"f{snap.num_features}|{type(snap.objective).__name__}|"
              f"{getattr(snap, 'store_meta', {}).get('objective', '')}|"
@@ -89,6 +108,23 @@ def program_key(snap, bucket: int) -> str:
                 h.update(f"{k}:{tuple(v.shape)}:{np.dtype(v.dtype).str}|"
                          .encode())
     return h.hexdigest()
+
+
+def _program_device():
+    """The one device a serve program is compiled for and reloaded onto."""
+    import jax
+
+    return jax.devices()[0]
+
+
+def _load_program(payload):
+    """Deserialize one warm-file payload onto the program device.  Left to
+    its default, ``deserialize_and_load`` reads the executable as spanning
+    every local device."""
+    from jax.experimental import serialize_executable
+
+    return serialize_executable.deserialize_and_load(
+        *payload, execution_devices=[_program_device()])
 
 
 def _fused_serve_fn(snap):
@@ -112,14 +148,19 @@ def build_program(snap, bucket: int):
     """Trace + lower + compile the fused serve program for one bucket."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
 
     fn = _fused_serve_fn(snap)
-    Xp = jax.ShapeDtypeStruct((int(bucket), max(snap.num_features, 1)),
-                              jnp.float32)
-    base = jax.ShapeDtypeStruct((snap.n_groups,), jnp.float32)
+    one = SingleDeviceSharding(_program_device())
+
+    def shape(shp, dtype):
+        return jax.ShapeDtypeStruct(shp, dtype, sharding=one)
+
+    Xp = shape((int(bucket), max(snap.num_features, 1)), jnp.float32)
+    base = shape((snap.n_groups,), jnp.float32)
     shaped = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), dict(snap.stacked))
-    groups = (jax.ShapeDtypeStruct(snap.groups.shape, snap.groups.dtype)
+        lambda a: shape(a.shape, a.dtype), dict(snap.stacked))
+    groups = (shape(snap.groups.shape, snap.groups.dtype)
               if snap.groups is not None else None)
     return jax.jit(fn).lower(Xp, shaped, groups, base).compile()
 
@@ -185,8 +226,7 @@ class WarmProgramCache:
             compiled = None
             if payload is not None:
                 try:
-                    compiled = serialize_executable.deserialize_and_load(
-                        *payload)
+                    compiled = _load_program(payload)
                     stats["hits"] += 1
                 except Exception:
                     compiled = None  # stale/foreign entry: recompile below
@@ -204,7 +244,7 @@ class WarmProgramCache:
                     # file.  Whoever actually COMPILED the program
                     # persists a good entry, so the fleet still converges.
                     try:
-                        serialize_executable.deserialize_and_load(*ser)
+                        _load_program(ser)
                     except Exception:
                         ser = None
                     if ser is not None:

@@ -352,7 +352,7 @@ def recv_frame(stream, *, budget_s: Optional[float] = None,
 
 
 def _native_raise(rc: int, what: str) -> None:
-    """Map a libxtb_wire return code onto the same WireError taxonomy the
+    """Map a libxtb_wire return code onto the same WireError classes the
     Python reader raises (CRC handled at the call site — it also bumps
     the integrity counter)."""
     if rc in (1, -1):

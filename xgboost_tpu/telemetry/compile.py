@@ -1,8 +1,10 @@
 """Retrace accounting: count XLA backend compiles process-wide.
 
 JAX emits a ``/jax/core/compile/backend_compile_duration`` monitoring event
-for every program that actually reaches the backend compiler — cache hits
-(in-memory jit cache or the persistent compilation cache) do not fire it.
+for every program that leaves the in-memory jit cache — a hit there does
+not fire it; with jax 0.9.0 a hit in the persistent compilation cache does
+(seen on the chip in PR 21: the same count cold and warm), so the count says
+"traced and asked for", not "compiled from nothing".
 Counting those events gives the exact signal "Out-of-Core GPU Gradient
 Boosting" (2005.09148) calls out: the difference between a tuned pipeline
 and an accidentally-retracing one is knowing when a step compiled.

@@ -50,8 +50,9 @@ def init_multi_state(gpair, valid, *, max_nodes: int, n_targets: int,
     R = gpair.shape[0]
     K = n_targets
     pos = jnp.where(valid, 0, -1).astype(jnp.int32)
-    mask = (pos == 0).astype(jnp.float32)
-    root = jnp.einsum("r,rkc->kc", mask, gpair)  # (K, 2)
+    # a reduction, not a dot over every row (ops/histogram.node_sums says why)
+    root = jnp.sum(jnp.where((pos == 0)[:, None, None], gpair, 0.0),
+                   axis=0)  # (K, 2)
     if axis_name is not None:
         root = lax.psum(root, axis_name)
     mn = max_nodes

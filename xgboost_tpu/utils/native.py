@@ -20,22 +20,6 @@ _LIB = None
 _TRIED = False
 
 
-def jax_ffi():
-    """The jax FFI module: ``jax.ffi`` (jax >= 0.5) or ``jax.extend.ffi``
-    (0.4.x) — identical surface for everything this package uses
-    (``ffi_call``, ``register_ffi_target``, ``pycapsule``, ``include_dir``).
-    Every FFI call site routes through this shim so the native kernels stay
-    live across the jax version seam."""
-    import jax
-
-    mod = getattr(jax, "ffi", None)
-    if mod is not None and hasattr(mod, "ffi_call"):
-        return mod
-    import jax.extend.ffi as ffi  # jax 0.4.x
-
-    return ffi
-
-
 def _native_dir() -> str:
     return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "native")
@@ -420,7 +404,8 @@ def load_ffi() -> bool:
                     fcntl.flock(lk, fcntl.LOCK_UN)
         import ctypes as c
 
-        ffi = jax_ffi()
+        from jax import ffi
+
         lib = c.CDLL(so)
         for name, sym in (("xtb_hist", lib.XtbHist),
                           ("xtb_hist_q", lib.XtbHistQ),
