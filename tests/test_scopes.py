@@ -1,0 +1,181 @@
+"""Device scopes (docs/observability.md, "Span and scope vocabulary"): every
+operation of the level programs, the margin update and the binning program
+that touches a row-sized array carries exactly one ``jax.named_scope`` of the
+table in its ``op_name``, so that any profile can be summed by them
+(telemetry/xplane.py).  Read from the compiled HLO text at toy shapes, with
+the histogram forced to the XLA matmul formulation the chip runs."""
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import xgboost_tpu as xtb
+from xgboost_tpu.telemetry import xplane
+from xgboost_tpu.tree import grow
+
+R, F, B, DEPTH = 4096, 5, 8, 3  # no node table reaches R elements
+
+# instructions the compiler makes, which carry no op_name of the program
+STRUCTURAL = {"parameter", "tuple", "get-tuple-element", "while", "call",
+              "conditional", "constant", "broadcast", "bitcast", "copy"}
+
+
+def row_sized(line: str) -> bool:
+    return any(np.prod([int(x) for x in dims.split(",")]) >= R
+               for dims in re.findall(r"\[([0-9,]+)\]", line))
+
+
+def scopes_of_row_sized(hlo: str) -> dict:
+    """{scope: count} over the row-sized instructions of ``hlo``; fails on
+    one that names no scope, or more than one."""
+    seen = {}
+    for line in hlo.splitlines():
+        opcode = re.search(r" = \S+ ([a-z\-]+)\(", line)
+        if " = " not in line or not opcode or not row_sized(line):
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        if opcode.group(1) == "parameter" or (
+                name is None and opcode.group(1) in STRUCTURAL):
+            continue
+        assert name is not None, f"row-sized and unnamed: {line[:200]}"
+        inside = [p for p in name.group(1).split("/") if p in xplane.SCOPES]
+        assert len(inside) == 1, (
+            f"op_name {name.group(1)!r} is under {inside or 'no scope'}: "
+            f"{line[:200]}")
+        seen[inside[0]] = seen.get(inside[0], 0) + 1
+    return seen
+
+
+@pytest.fixture(scope="module")
+def toy(tmp_path_factory):
+    mp = pytest.MonkeyPatch()
+    mp.setenv("XTB_HIST_IMPL", "matmul")
+    rng = np.random.default_rng(0)
+    bst = xtb.Booster({"max_depth": DEPTH})
+    bst._configure()
+    gpair = jnp.asarray(rng.normal(size=(R, 2)).astype(np.float32))
+    args = dict(
+        bins=jnp.asarray(rng.integers(0, B, size=(R, F)).astype(np.uint8)),
+        gpair=gpair,
+        cuts=jnp.asarray(np.sort(rng.normal(size=(F, B)), axis=1)
+                         .astype(np.float32)),
+        nb=jnp.full(F, B, jnp.int32), ones=jnp.ones((1, F), bool),
+        setm=jnp.ones((1, F), bool), cm=jnp.zeros(F, bool),
+        params=bst._split_params,
+        state=grow.init_tree_state(gpair, jnp.ones(R, bool),
+                                   max_nodes=grow.max_nodes_for_depth(DEPTH),
+                                   n_bin=B))
+    yield args
+    mp.undo()
+
+
+def level_hlo(toy, depth, last, subtract, padded) -> str:
+    a = toy
+    head = (a["state"], a["bins"], a["gpair"], a["cuts"], a["nb"], a["ones"],
+            a["setm"], a["cm"])
+    if padded:
+        width = 1 << (DEPTH - 1)
+        prev = jnp.zeros((width, F, B, 2), jnp.float32)
+        low = grow.level_step_padded.lower(
+            *head, prev, (1 << depth) - 1, None, width=width,
+            params=a["params"], subtract=subtract)
+    else:
+        prev = (jnp.zeros((1 << (depth - 1), F, B, 2), jnp.float32)
+                if subtract else None)
+        low = grow.level_step.lower(
+            *head, prev, None, depth=depth, params=a["params"],
+            last_level=last, subtract=subtract)
+    return low.compile().as_text()
+
+
+@pytest.mark.parametrize("depth,last,subtract,padded", [
+    (0, False, False, False),      # the root
+    (1, False, False, False),      # an interior level, every node built
+    (2, False, True, False),       # an interior level, siblings subtracted
+    (1, False, True, True),        # the shared padded program
+    (2, False, False, True),       # the same with every node built
+    (DEPTH, True, False, False),   # leaf finalize: no row-sized work at all
+])
+def test_level_programs_name_every_row_sized_operation(toy, depth, last,
+                                                       subtract, padded):
+    seen = scopes_of_row_sized(level_hlo(toy, depth, last, subtract, padded))
+    if last:
+        assert seen == {}
+    else:
+        assert set(seen) == {"hist", "route"}, seen
+
+
+def test_level_program_names_its_node_work_too(toy):
+    """Node-sized work is under ``split`` and ``record``: nothing of a level
+    program is left to the unscoped row but what the compiler made."""
+    hlo = level_hlo(toy, 1, False, True, True)
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    by_scope = {}
+    for n in names:
+        by_scope[xplane.scope_of(n)] = by_scope.get(xplane.scope_of(n), 0) + 1
+    assert {"hist", "split", "record", "route"} <= set(by_scope)
+    loose = {n for n in names if xplane.scope_of(n) == xplane.UNSCOPED
+             and n.startswith("jit(")}
+    assert not loose, f"traced operations outside every scope: {sorted(loose)}"
+
+
+def test_margin_update_is_scoped(toy):
+    st = toy["state"]
+    hlo = grow.leaf_margin_delta.lower(st.pos, st.leaf_val).compile().as_text()
+    assert set(scopes_of_row_sized(hlo)) == {"margin"}
+
+
+def test_binning_program_is_scoped(monkeypatch):
+    """``_bin`` is a closure of build_ellpack, jitted there: take it as it is
+    handed to jax.jit (the CPU's native binning kernel switched off)."""
+    from xgboost_tpu.data import ellpack
+    from xgboost_tpu.data.quantile import sketch_dense
+    from xgboost_tpu.utils import native
+
+    X = np.random.default_rng(1).normal(size=(R, F)).astype(np.float32)
+    jitted = []
+    real_jit = jax.jit
+
+    def spy(fn, *a, **kw):
+        out = real_jit(fn, *a, **kw)
+        if getattr(fn, "__name__", "") == "_bin":
+            jitted.append(out)
+        return out
+
+    monkeypatch.setattr(native, "ellpack_bin_native", lambda *a, **kw: None)
+    monkeypatch.setattr(jax, "jit", spy)
+    ellpack.build_ellpack(X, sketch_dense(X, B, use_device=False))
+    monkeypatch.setattr(jax, "jit", real_jit)
+    assert len(jitted) == 1
+    hlo = jitted[0].lower(jnp.asarray(X)).compile().as_text()
+    assert set(scopes_of_row_sized(hlo)) == {"bin"}
+
+
+def test_predict_walkers_are_scoped(monkeypatch):
+    """The two entry points eval.predict reaches during training (traced
+    with the CPU's native predictor switched off, as on the chip)."""
+    from xgboost_tpu.ops import predict
+
+    monkeypatch.setattr(predict, "_native_predict_ok", lambda: False)
+
+    T, M = 2, 7
+    tree = dict(feat=jnp.zeros((T, M), jnp.int32), dleft=jnp.ones((T, M), bool),
+                left=jnp.zeros((T, M), jnp.int32),
+                right=jnp.zeros((T, M), jnp.int32),
+                value=jnp.zeros((T, M), jnp.float32),
+                groups=jnp.zeros(T, jnp.int32))
+    raw = predict.predict_margin_delta.lower(
+        jnp.zeros((R, F), jnp.float32), tree["feat"],
+        jnp.zeros((T, M), jnp.float32), tree["dleft"], tree["left"],
+        tree["right"], tree["value"], tree["groups"], n_groups=1,
+        depth=2).compile().as_text()
+    binned = predict.predict_margin_delta_binned.lower(
+        jnp.zeros((R, F), jnp.uint8), tree["feat"],
+        jnp.zeros((T, M), jnp.int32), tree["dleft"], tree["left"],
+        tree["right"], tree["value"], tree["groups"], n_groups=1, depth=2,
+        n_bin=B).compile().as_text()
+    for hlo in (raw, binned):
+        assert set(scopes_of_row_sized(hlo)) == {"predict"}

@@ -1601,10 +1601,13 @@ class Booster:
                         tree.split_conditions[lm] = lv[lm]
                     else:
                         state = state._replace(leaf_val=leaf_val)
-                delta = leaf_margin_delta(pos, leaf_val)
-                new_margin = new_margin.at[:, k].add(delta)
+                with span("grow.margin"):
+                    delta = leaf_margin_delta(pos, leaf_val)
+                    new_margin = new_margin.at[:, k].add(delta)
                 if tree is None:
-                    tree = RegTree.from_grown(HistTreeGrower.to_host(state))
+                    grown = HistTreeGrower.to_host(state)
+                    with span("tree.from_grown"):
+                        tree = RegTree.from_grown(grown)
                 tree.cuts_token = cuts_token_use
                 self.trees.append(tree)
                 self.tree_info.append(k)

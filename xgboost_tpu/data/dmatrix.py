@@ -16,6 +16,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
 
 import numpy as np
 
+from ..telemetry import span
 from .ellpack import EllpackPage, build_ellpack, build_ellpack_csr
 from .quantile import HistogramCuts, sketch_csr, sketch_dense
 
@@ -432,7 +433,10 @@ class DMatrix:
         if self._jax_X is None:
             import jax.numpy as jnp
 
-            self._jax_X = jnp.asarray(self._dense, dtype=jnp.float32)
+            # dispatch and host staging only: the copy itself ends inside
+            # whoever first waits on the array (the sketch)
+            with span("dmatrix.upload"):
+                self._jax_X = jnp.asarray(self._dense, dtype=jnp.float32)
         return self._jax_X
 
     def cat_mask(self) -> Optional[np.ndarray]:
@@ -450,6 +454,15 @@ class DMatrix:
         if (self._ellpack is not None and self._max_bin_built == max_bin
                 and self._ellpack.n_padded % row_align == 0):
             return self._ellpack
+        with span("dmatrix.build"):
+            self._ellpack = self._build_ellpack(
+                max_bin, sketch_weights, ref, distributed, row_align)
+        self._max_bin_built = max_bin
+        return self._ellpack
+
+    def _build_ellpack(self, max_bin: int, sketch_weights, ref, distributed,
+                       row_align: int) -> EllpackPage:
+        """Cuts (reused, borrowed or sketched), then the binned page."""
         if self._ellpack is not None and self._max_bin_built == max_bin:
             # alignment-only rebuild (n_devices changed): reuse the built
             # cuts — re-sketching would waste the work and, distributed, a
@@ -464,9 +477,10 @@ class DMatrix:
             # summaries into shared cuts (quantile.cc:397 AllreduceV analogue)
             from .quantile import sketch_distributed
 
-            cuts = sketch_distributed(self.host_dense(), max_bin,
-                                      weights=sketch_weights,
-                                      cat_mask=self.cat_mask())
+            with span("dmatrix.sketch"):
+                cuts = sketch_distributed(self.host_dense(), max_bin,
+                                          weights=sketch_weights,
+                                          cat_mask=self.cat_mask())
         elif self._kind == "dense":
             # weighted / categorical sketches run on host — feed them the
             # host array when we already have one rather than bouncing the
@@ -477,24 +491,31 @@ class DMatrix:
             # host sketches get the cached host copy (one D2H transfer, reused
             # by later host paths) instead of bouncing the device array down
             sk_X = self.host_dense() if host_sketch else self._device_dense()
-            cuts = sketch_dense(sk_X, max_bin, weights=sketch_weights,
-                                cat_mask=cm)
+            # ends at the sketch's own np.asarray of the grid, so it is
+            # drained, and holds what is left of the upload
+            with span("dmatrix.sketch"):
+                cuts = sketch_dense(sk_X, max_bin, weights=sketch_weights,
+                                    cat_mask=cm)
         else:
             indptr, indices, values, (R, F) = self._csr
-            cuts = sketch_csr(indptr, indices, values, F, max_bin,
-                              weights=sketch_weights, cat_mask=self.cat_mask(),
-                              distributed=distributed)
-        if self._kind == "dense":
-            self._ellpack = build_ellpack(self._device_dense(), cuts,
-                                          row_align=row_align)
-            if self._dense is not None:
-                self._jax_X = None  # binned; drop the duplicate device copy
-        else:
-            indptr, indices, values, (R, F) = self._csr
-            self._ellpack = build_ellpack_csr(indptr, indices, values, F, cuts,
-                                              row_align=row_align)
-        self._max_bin_built = max_bin
-        return self._ellpack
+            with span("dmatrix.sketch"):
+                cuts = sketch_csr(indptr, indices, values, F, max_bin,
+                                  weights=sketch_weights,
+                                  cat_mask=self.cat_mask(),
+                                  distributed=distributed)
+        # trace, compile-or-load and dispatch of the binning program plus the
+        # pad; NOT drained: the page is ready when its first reader waits
+        with span("dmatrix.bin"):
+            if self._kind == "dense":
+                ellpack = build_ellpack(self._device_dense(), cuts,
+                                        row_align=row_align)
+                if self._dense is not None:
+                    self._jax_X = None  # binned; drop the duplicate device copy
+            else:
+                indptr, indices, values, (R, F) = self._csr
+                ellpack = build_ellpack_csr(indptr, indices, values, F, cuts,
+                                            row_align=row_align)
+        return ellpack
 
     def slice(self, rindex: Sequence[int]) -> "DMatrix":
         """Row slice (reference: XGDMatrixSliceDMatrix) — used by cv()."""

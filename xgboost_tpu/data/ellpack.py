@@ -121,8 +121,10 @@ def build_ellpack(
             b = jnp.where(jnp.isnan(col), B, b)
             return b
 
-        bins = jax.vmap(one_feature, in_axes=(1, 0, 0), out_axes=1)(Xd, cuts_pad, n_bins)
-        return bins.astype(dtype)
+        with jax.named_scope("bin"):
+            bins = jax.vmap(one_feature, in_axes=(1, 0, 0), out_axes=1)(
+                Xd, cuts_pad, n_bins)
+            return bins.astype(dtype)
 
     bins = _bin(Xd)
     if R_pad != R:

@@ -210,11 +210,12 @@ def predict_margin_delta(X, feat, thr, dleft, left, right, value, groups,
         margin = lax.dynamic_update_slice_in_dim(margin, col + delta[:, None], grp, axis=1)
         return margin, None
 
-    margin0 = (jnp.zeros((R, n_groups), jnp.float32) if init is None
-               else init.astype(jnp.float32))
     xs = ((feat, thr, dleft, left, right, value, groups) if is_cat is None
           else (feat, thr, dleft, left, right, value, groups, is_cat, catm))
-    margin, _ = lax.scan(body, margin0, xs)
+    with jax.named_scope("predict"):
+        margin0 = (jnp.zeros((R, n_groups), jnp.float32) if init is None
+                   else init.astype(jnp.float32))
+        margin, _ = lax.scan(body, margin0, xs)
     return margin
 
 
@@ -334,9 +335,10 @@ def predict_margin_delta_binned(bins, feat, sbin, dleft, left, right, value,
         margin = lax.dynamic_update_slice_in_dim(margin, col + delta[:, None], grp, axis=1)
         return margin, None
 
-    margin0 = (jnp.zeros((R, n_groups), jnp.float32) if init is None
-               else init.astype(jnp.float32))
     xs = ((feat, sbin, dleft, left, right, value, groups) if is_cat is None
           else (feat, sbin, dleft, left, right, value, groups, is_cat, catm))
-    margin, _ = lax.scan(body, margin0, xs)
+    with jax.named_scope("predict"):
+        margin0 = (jnp.zeros((R, n_groups), jnp.float32) if init is None
+                   else init.astype(jnp.float32))
+        margin, _ = lax.scan(body, margin0, xs)
     return margin

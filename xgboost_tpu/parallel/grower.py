@@ -176,7 +176,7 @@ class ShardedHistTreeGrower:
             md = self.max_depth
             W = 1 << (md - 1)
             fm = ones if feature_masks is None else feature_masks(0, 1)
-            with span(_LEVEL):
+            with span(_LEVEL, depth=0):
                 state, hist = self._level_fns[0](state, bins, gpair, cuts_pad,
                                                  n_bins, fm, setmat, cm,
                                                  *rho_args)
@@ -185,19 +185,19 @@ class ShardedHistTreeGrower:
             for d in range(1, md):
                 fm = (ones if feature_masks is None
                       else HistTreeGrower._pad_mask(feature_masks(d, 1 << d), W))
-                with span(_LEVEL):
+                with span(_LEVEL, depth=d):
                     state, hist_pad = self._interior_fn(
                         state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm,
                         hist_pad, jnp.int32((1 << d) - 1), *rho_args)
             fm = ones if feature_masks is None else feature_masks(md, 1 << md)
-            with span(_LEVEL):
+            with span(_LEVEL, depth=md):
                 state = self._level_fns[md](state, bins, gpair, cuts_pad,
                                             n_bins, fm, setmat, cm, *rho_args)
             return state
         hist_prev = None
         for d in range(self.max_depth + 1):
             fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-            with span(_LEVEL):
+            with span(_LEVEL, depth=d):
                 if d == self.max_depth:
                     state = self._level_fns[d](state, bins, gpair, cuts_pad,
                                                n_bins, fm, setmat, cm,

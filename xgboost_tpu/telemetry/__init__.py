@@ -7,13 +7,21 @@ serving/metrics counters with no export format):
 - **Registry** (registry.py): lock-cheap Counter/Gauge/Histogram families
   with labels; ``serving/metrics.ServingMetrics`` feeds it, the span tracer
   records into it, ``render_prometheus()`` exposes it.
-- **Spans** (spans.py): ``span("grow.build_hist")`` brackets the training
-  and serving hot paths — perf_counter histogram + JSONL trace event +
-  jax.profiler.TraceAnnotation, all behind one enabled flag
-  (``enable()`` / env ``XGBOOST_TPU_TRACE``), no-op by default.
-- **Retrace accounting** (compile.py): every XLA backend compile is counted
-  process-wide (``compiles_total()``, ``xtb_compiles_total``); a second
-  identical train() records zero — the guard tests/test_telemetry.py keeps.
+- **Spans** (spans.py): ``span("grow.to_host")`` brackets the training
+  and serving hot paths.  Every span opens a jax.profiler.TraceAnnotation
+  (so it lands in any live profiler session, on the device trace's clock)
+  and leaves a record in the flight ring (``spans.recent()``); the flag
+  (``enable()`` / env ``XGBOOST_TPU_TRACE``) adds the perf_counter
+  histogram and the JSONL trace event.
+- **Device scopes**: every jitted program of the training path names its
+  parts with ``jax.named_scope`` (``hist``, ``split``, ``record``,
+  ``route``, ...); ``python -m xgboost_tpu.telemetry.xplane <dir>``
+  (xplane.py) sums any profile by them and puts idle gaps down to spans.
+- **Retrace accounting** (compile.py): programs compiled, programs loaded
+  from the persistent cache and functions traced are counted process-wide
+  (``compiles_total()``, ``xtb_compiles_total{kind}``,
+  ``xtb_traces_total``); a second identical train() records zero — the
+  guard tests/test_telemetry.py keeps.
 - **Exporters**: ``render_prometheus()`` text exposition and the
   chrome://tracing JSONL writer gated by ``XGBOOST_TPU_TRACE=path``
   (trace.py).
@@ -50,9 +58,10 @@ from __future__ import annotations
 from .registry import (Counter, Gauge, Histogram, Registry, get_registry,
                        render_prometheus)
 from .spans import (PHASE_HISTOGRAM, Span, disable, enable, enabled,
-                    phase_totals, record_phase, span)
-from .compile import COMPILE_EVENT, compile_delta, compiles_total
-from . import distributed, flight, native_pool, profiler, trace
+                    phase_totals, record_phase, span, step_span)
+from .compile import (COMPILE_EVENT, compile_delta, compiles_total,
+                      loads_total, traces_total)
+from . import distributed, flight, native_pool, profiler, trace, xplane
 from .distributed import (MergedRegistry, get_merged, snapshot_payload,
                           start_metrics_server, stop_metrics_server)
 from .callback import TelemetryCallback
@@ -60,10 +69,11 @@ from .callback import TelemetryCallback
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "get_registry",
     "render_prometheus",
-    "span", "Span", "enable", "disable", "enabled", "record_phase",
-    "phase_totals", "PHASE_HISTOGRAM",
-    "compiles_total", "compile_delta", "COMPILE_EVENT",
-    "trace", "native_pool", "distributed", "flight", "profiler",
+    "span", "step_span", "Span", "enable", "disable", "enabled",
+    "record_phase", "phase_totals", "PHASE_HISTOGRAM",
+    "compiles_total", "loads_total", "traces_total", "compile_delta",
+    "COMPILE_EVENT",
+    "trace", "native_pool", "distributed", "flight", "profiler", "xplane",
     "MergedRegistry", "get_merged", "snapshot_payload",
     "start_metrics_server", "stop_metrics_server",
     "TelemetryCallback",

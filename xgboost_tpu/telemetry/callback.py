@@ -1,22 +1,25 @@
 """TelemetryCallback: per-round phase timings, tree stats, and compile
 accounting as an inspectable history.
 
-A TrainingCallback (callback.py contract) that diffs the span histogram
-(spans.py PHASE_HISTOGRAM) and the compile counter around every boosting
-round, and reads the committed model for structural stats — so a training
-run leaves a round-by-round record of where the time went and whether any
-round retraced, without touching the training loop itself::
+A TrainingCallback (callback.py contract) that reads the round's span
+records (``spans.recent``) and the compile counter at every boosting round,
+and the committed model for structural stats — so a training run leaves a
+round-by-round record of where the time went and whether any round
+retraced, without touching the training loop itself::
 
     cb = TelemetryCallback()
     xtb.train(params, d, 10, callbacks=[cb])
-    cb.history[3]["phases"]["grow.update_tree"]   # seconds in round 3
+    cb.history[3]["phases"]["update.update_tree"]  # seconds in round 3
+    cb.history[3]["dispatches"]                    # device programs launched
+    cb.history[3]["host_syncs"]                    # waits and copies to host
     cb.history[3]["trees"][0]["leaves"]
-    cb.compiles_steady                            # SLO: 0 after round 0
+    cb.compiles_steady                             # SLO: 0 after round 0
 
 Round 0 is the warm-up round (every level program traces there); compiles
 in later rounds are steady-state retraces and feed the registry counter
 ``xtb_compiles_steady{scope="train"}`` — the same no-retrace SLO gauge the
-serving engine keeps (serving/metrics.py), scoped per subsystem.
+serving engine keeps (serving/metrics.py), scoped per subsystem.  A load
+from the persistent compilation cache is not a compile (compile.py).
 """
 from __future__ import annotations
 
@@ -25,10 +28,15 @@ from typing import Any, Dict, List, Optional
 
 from ..callback import TrainingCallback
 from . import compile as _compile
-from . import spans
+from . import flight, spans
 from .registry import get_registry
 
 __all__ = ["TelemetryCallback"]
+
+# the spans that launch device work, and those that wait for it or copy from it
+_DISPATCH_SPANS = ("grow.build_hist+eval_split", "update.gradient",
+                   "grow.margin")
+_SYNC_SPANS = ("grow.wait_device", "grow.to_host")
 
 
 class TelemetryCallback(TrainingCallback):
@@ -37,11 +45,11 @@ class TelemetryCallback(TrainingCallback):
     Parameters
     ----------
     enable_spans : bool
-        Turn the span tracer on in before_training (default True) so the
-        phase attribution is populated even when the caller never called
-        ``telemetry.enable()``.  The flag is left as-is on after_training
-        (process-wide state; flipping it back could disable a concurrent
-        consumer's spans).
+        Turn the span flag on in before_training (default True), so that
+        the registry histogram ``xtb_phase_seconds`` and a configured JSONL
+        trace are fed too.  ``history`` does not need it: it reads the ring.
+        The flag is left as-is on after_training (process-wide state;
+        flipping it back could disable a concurrent consumer's spans).
     straggler : bool
         Distributed only: allgather every rank's round wall + collective
         wait at each round boundary and record a straggler report
@@ -59,7 +67,7 @@ class TelemetryCallback(TrainingCallback):
         self.history: List[Dict[str, Any]] = []
         self.compiles_warmup = 0
         self.compiles_steady = 0
-        self._phase0: Dict[str, Dict[str, float]] = {}
+        self._seq0 = 0
         self._coll0: Dict[Any, Any] = {}
         self._compiles0 = 0
         self._t0 = 0.0
@@ -82,7 +90,7 @@ class TelemetryCallback(TrainingCallback):
         return model
 
     def before_iteration(self, model, epoch: int, evals_log) -> bool:
-        self._phase0 = spans.phase_totals()
+        self._seq0 = flight.seq()
         self._coll0 = self._coll_sums()
         self._compiles0 = _compile.compiles_total()
         self._t0 = time.perf_counter()
@@ -90,20 +98,27 @@ class TelemetryCallback(TrainingCallback):
 
     def after_iteration(self, model, epoch: int, evals_log) -> bool:
         seconds = time.perf_counter() - self._t0
-        cur = spans.phase_totals()
-        phases = {}
-        for name, tot in cur.items():
-            prev = self._phase0.get(name)
-            ds = tot["seconds"] - (prev["seconds"] if prev else 0.0)
-            dc = tot["count"] - (prev["count"] if prev else 0)
-            if dc:
-                phases[name] = {"seconds": ds, "count": int(dc)}
+        # this round's spans: a round the ring has begun to overwrite (more
+        # spans than the ring holds) reads as no phases, never as some
+        phases: Dict[str, Dict[str, Any]] = {}
+        dispatches = host_syncs = 0
+        for r in spans.recent(round_from=epoch):
+            if r["round"] != epoch or r["seq"] < self._seq0:
+                continue
+            p = phases.setdefault(r["name"], {"seconds": 0.0, "count": 0})
+            p["seconds"] += r["dur_ns"] / 1e9
+            p["count"] += 1
+            dispatches += r["name"] in _DISPATCH_SPANS
+            if r["name"] in _SYNC_SPANS:
+                host_syncs += r.get("copies", 1)
         compiles = _compile.compiles_total() - self._compiles0
         trees = self._tree_stats(model)
         rec: Dict[str, Any] = {
             "round": int(epoch),
             "seconds": seconds,
             "phases": phases,
+            "dispatches": dispatches,
+            "host_syncs": host_syncs,
             "compiles": int(compiles),
             "trees": trees,
         }

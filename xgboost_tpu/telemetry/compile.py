@@ -1,82 +1,160 @@
-"""Retrace accounting: count XLA backend compiles process-wide.
+"""Retrace accounting: what jax traced, compiled and loaded, process-wide.
 
-JAX emits a ``/jax/core/compile/backend_compile_duration`` monitoring event
-for every program that leaves the in-memory jit cache — a hit there does
-not fire it; with jax 0.9.0 a hit in the persistent compilation cache does
-(seen on the chip in PR 21: the same count cold and warm), so the count says
-"traced and asked for", not "compiled from nothing".
-Counting those events gives the exact signal "Out-of-Core GPU Gradient
-Boosting" (2005.09148) calls out: the difference between a tuned pipeline
-and an accidentally-retracing one is knowing when a step compiled.
+JAX (0.9.0) tells a monitoring listener three things about a program that
+leaves the in-memory jit cache (a hit there fires nothing):
 
-The listener registers once at import, costs nothing between compiles, and
-feeds three sinks:
+- ``/jax/core/compile/jaxpr_trace_duration``: a function was traced to a
+  jaxpr (once for each jitted function, inner ones such as ``jnp.sort``
+  included);
+- ``/jax/core/compile/backend_compile_duration``: a program was asked of the
+  backend.  It brackets the persistent compilation cache too, so it fires
+  the same whether XLA compiled the program or the cache held it (seen on
+  the chip in PR 21: 55 "compiles" cold and warm);
+- ``/jax/compilation_cache/cache_hits``: fired inside that bracket, on the
+  same thread, when the persistent cache held the program.
 
-- ``compiles_total()`` — the process-global int both training
+So a backend event with a cache hit inside it is a *load*, and one without
+is a *compile*: the difference between a tuned pipeline and an accidentally
+retracing one ("Out-of-Core GPU Gradient Boosting", 2005.09148) is knowing
+when a step compiled, and on a machine with a warm cache, when it only
+loaded.
+
+The listeners register once at import, cost nothing between events, and
+feed:
+
+- ``compiles_total()`` (real compiles only), ``loads_total()`` and
+  ``traces_total()``: the process-global ints that training
   (``TelemetryCallback`` per-round deltas, steady-state SLO: 0 after the
   warm-up round) and serving (``ServingEngine`` windows) read;
-- the registry counters ``xtb_compiles_total`` / ``xtb_compiles_steady``
-  (the steady counter is fed by whoever owns the warm/steady boundary —
-  the TelemetryCallback after round 0, ServingMetrics outside warmup());
-- a JSONL trace event per compile when ``XGBOOST_TPU_TRACE`` is set, so
-  retraces are visible inline with the phase spans they stall.
+- the registry counters ``xtb_compiles_total{kind="compiled"|"loaded"}`` and
+  ``xtb_traces_total`` (``xtb_compiles_steady`` is fed by whoever owns the
+  warm/steady boundary: the TelemetryCallback after round 0, ServingMetrics
+  outside warmup());
+- one flight-ring record for each program compiled or loaded (kind
+  ``compile``; name ``xla.compiled`` or ``xla.loaded``) with the function's
+  name, the seconds and the training round it fell in.  Traces are too many
+  for the ring (435 in a three-round toy train, against a ring of 512), so
+  they are counted only;
+- :func:`counting`, which puts the three counts of a span's lifetime into
+  its ring record: training wraps the round's two spans in it, so that a
+  window's rounds can be shown to hold no compile, no load and no trace.
 
 ``jax.monitoring`` listeners cannot be unregistered individually, so this
 must never be registered twice (the module guard) and must stay cheap
-forever (it is: one string compare per monitoring event).
+forever (it is: a few string compares per monitoring event).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
-import time
-from typing import Optional
+from typing import Dict, Iterator, Optional
 
-from . import trace
+from . import flight, spans
 from .registry import get_registry
 
-__all__ = ["compiles_total", "compile_delta", "install", "COMPILE_EVENT"]
+__all__ = ["compiles_total", "loads_total", "traces_total", "compile_delta",
+           "counting", "install", "COMPILE_EVENT", "TRACE_EVENT",
+           "CACHE_HIT_EVENT"]
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+KINDS = ("compiled", "loaded", "traced")
 
 _lock = threading.Lock()
-_total = 0
+_totals: Dict[str, int] = dict.fromkeys(KINDS, 0)
+_hit = threading.local()  # .seen: a cache hit since this thread's last backend event
 _installed = False
-_counter = None  # xtb_compiles_total registry child (lazy)
+_counters: Dict[str, object] = {}  # kind -> registry child (lazy)
 
 
-def _on_event(name: str, duration_secs: float, **kw) -> None:
-    global _total, _counter
-    if name != COMPILE_EVENT:
-        return
+def _counter(kind: str):
+    child = _counters.get(kind)
+    if child is None:
+        reg = get_registry()
+        if kind == "traced":
+            child = reg.counter(
+                "xtb_traces_total",
+                "functions jax traced to a jaxpr in this process").labels()
+        else:
+            child = reg.counter(
+                "xtb_compiles_total",
+                "programs asked of the XLA backend in this process: "
+                "compiled, or loaded from the persistent cache",
+                ("kind",)).labels(kind)
+        child = _counters.setdefault(kind, child)
+    return child
+
+
+def _count(kind: str, duration_secs: float, fun_name: Optional[str]) -> None:
     with _lock:
-        _total += 1
-    if _counter is None:
-        _counter = get_registry().counter(
-            "xtb_compiles_total",
-            "XLA backend compiles in this process (cache misses)").labels()
-    _counter.inc()
-    if trace.active():
-        dur_ns = int(duration_secs * 1e9)
-        trace.emit("xla.compile", time.perf_counter_ns() - dur_ns, dur_ns)
+        _totals[kind] += 1
+    _counter(kind).inc()
+    if kind == "traced":
+        return
+    detail = {"s": duration_secs, "fun": fun_name}
+    round_ = spans.current_round()
+    if round_ is not None:
+        detail["round"] = round_
+    flight.record("compile", "xla." + kind, **detail)
+
+
+def _on_event(name: str, **kw) -> None:
+    if name == CACHE_HIT_EVENT:
+        _hit.seen = True
+
+
+def _on_duration(name: str, duration_secs: float, **kw) -> None:
+    if name == TRACE_EVENT:
+        _count("traced", duration_secs, kw.get("fun_name"))
+    elif name == COMPILE_EVENT:
+        loaded = getattr(_hit, "seen", False)
+        _hit.seen = False
+        _count("loaded" if loaded else "compiled", duration_secs,
+               kw.get("fun_name"))
 
 
 def install() -> None:
-    """Register the monitoring listener (idempotent; called at telemetry
-    import so compile counts exist before the first train())."""
+    """Register the monitoring listeners (idempotent; called at telemetry
+    import so the counts exist before the first train())."""
     global _installed
     if _installed:
         return
-    try:
-        import jax.monitoring
-    except Exception:  # pragma: no cover - no jax in the process
-        return
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     _installed = True
 
 
 def compiles_total() -> int:
-    """Backend compiles since process start (monotonic)."""
-    return _total
+    """Programs XLA compiled since process start (monotonic); a load from
+    the persistent compilation cache is not one."""
+    return _totals["compiled"]
+
+
+def loads_total() -> int:
+    """Programs loaded from the persistent compilation cache."""
+    return _totals["loaded"]
+
+
+def traces_total() -> int:
+    """Functions traced to a jaxpr."""
+    return _totals["traced"]
+
+
+@contextlib.contextmanager
+def counting(sp: spans.Span) -> Iterator[spans.Span]:
+    """``with counting(span(...)):`` is ``with span(...):`` whose ring record
+    also says how many programs were ``compiled`` and ``loaded`` and how many
+    functions ``traced`` while it was open."""
+    before = dict(_totals)
+    with sp:
+        try:
+            yield sp
+        finally:
+            sp.args.update((k, _totals[k] - before[k]) for k in KINDS)
 
 
 class compile_delta:

@@ -45,7 +45,7 @@ from collections import deque
 from datetime import datetime, timezone
 from typing import Any, Dict, List, Optional
 
-__all__ = ["record", "events", "dump", "dump_stacks", "install",
+__all__ = ["record", "events", "seq", "dump", "dump_stacks", "install",
            "dump_dir", "default_path", "stacks_path", "set_label", "clear",
            "ENV_DIR", "ENV_LABEL", "ENV_SIZE", "ENV_SPILL"]
 
@@ -64,6 +64,7 @@ def _ring_size() -> int:
 
 _lock = threading.Lock()
 _ring: "deque[Dict[str, Any]]" = deque(maxlen=_ring_size())
+_seq = 0  # records appended since process start; a record's "seq" is its own
 _label: Optional[str] = os.environ.get(ENV_LABEL) or None
 _spill_path: Optional[str] = None
 _spill_interval: float = 5.0
@@ -96,12 +97,15 @@ def record(kind: str, name: str, **detail: Any) -> None:
     """Append one event to the ring; never raises (observability must not
     take the process down).  ``kind`` is one of ``span``/``event``/
     ``fault`` by convention; ``detail`` must be JSON-serializable."""
+    global _seq
     try:
         rec: Dict[str, Any] = {"t_mono": time.monotonic(), "kind": kind,
                                "name": name}
         if detail:
             rec["detail"] = detail
         with _lock:
+            rec["seq"] = _seq
+            _seq += 1
             _ring.append(rec)
         if _spill_path is not None:
             _maybe_spill()
@@ -112,6 +116,13 @@ def record(kind: str, name: str, **detail: Any) -> None:
 def events() -> List[Dict[str, Any]]:
     with _lock:
         return list(_ring)
+
+
+def seq() -> int:
+    """The sequence number the next record will get.  Records are numbered
+    in the order they were appended, so ``events()[0]["seq"]`` tells how much
+    the ring has dropped (spans.recent reads whole rounds by it)."""
+    return _seq
 
 
 def _payload(evs: List[Dict[str, Any]]) -> Dict[str, Any]:

@@ -1,42 +1,60 @@
 """Span tracer: named wall-clock brackets over the training/serving hot path.
 
-``span("grow.build_hist")`` is a context manager that, when telemetry is
-enabled, records ``time.perf_counter_ns`` duration into the registry
-histogram ``xtb_phase_seconds{phase=...}``, appends a JSONL trace event
-(trace.py) when ``XGBOOST_TPU_TRACE`` is set, and opens a
-``jax.profiler.TraceAnnotation`` so the same label shows up in TPU/perfetto
-profiler captures — one bracket, three sinks.
+``span("grow.to_host")`` is a context manager.  Every span, with no switch,
 
-Disabled-by-default overhead is the design constraint (the hot path calls
-``span()`` per tree level): everything hangs off ONE module-level flag, and
-the disabled path is a flag test plus returning a shared no-op context
-manager — no allocation, no clock read, no dict lookup
-(tests/test_telemetry.py has the guard test).
+- opens a ``jax.profiler.TraceAnnotation`` — a no-op unless a profiler
+  session is live, and when one is live the span lands on the host plane of
+  the same ``.xplane.pb`` as the device operations, on one clock
+  (``telemetry/xplane.py`` reads it back);
+- on exit appends one record to the flight ring (``flight.py``, bounded)
+  with ``t0_ns``, ``dur_ns``, the enclosing span's name (``parent``), the
+  training round (``round``: set by the round span, absent outside a round)
+  and the span's own arguments.  :func:`recent` reads them back: it is what
+  the benchmark's ``program_span`` metrics and ``TelemetryCallback`` read.
 
-``utils/timer.Monitor`` is a thin shim over ``record_phase`` (same sinks,
-stack-based start/stop bracketing); use ``span`` directly in new code.
+The flag (``enable()`` / ``XGBOOST_TPU_TRACE``) gates the two sinks that
+cost more: the registry histogram ``xtb_phase_seconds{phase=...}`` and the
+JSONL trace event (trace.py).  With it off a span touches no registry
+family and no file (tests/test_telemetry.py has the guard test).
+
+``utils/timer.Monitor`` is a thin shim over ``record_phase`` (the flag's
+sinks, stack-based start/stop bracketing); use ``span`` directly in new code.
 """
 from __future__ import annotations
 
 import os
+import threading
 import time
-from typing import Dict, Optional
+from typing import Any, Dict, List, Optional
+
+import jax.profiler as _profiler
 
 from . import flight, trace
 from .registry import get_registry
 
-__all__ = ["span", "enable", "disable", "enabled", "record_phase", "Span",
-           "phase_totals", "PHASE_HISTOGRAM"]
+__all__ = ["span", "step_span", "recent", "current_round", "enable",
+           "disable", "enabled", "record_phase", "Span", "phase_totals",
+           "PHASE_HISTOGRAM"]
 
 PHASE_HISTOGRAM = "xtb_phase_seconds"
 
-# the ONE flag every span checks; a configured trace destination implies
-# spans are wanted (capturing an empty trace would be the only alternative)
+# gates the histogram and the JSONL writer; a configured trace destination
+# implies both are wanted (an empty trace would be the only alternative)
 _ENABLED: bool = bool(os.environ.get(trace.ENV_VAR))
 
 _phase_hist = None  # created lazily so importing telemetry stays cheap
 _children: Dict[str, object] = {}  # phase name -> histogram child (cached)
-_profiler = 0  # 0 = unprobed, module when available, None when not
+
+
+class _Open(threading.local):
+    """The spans open on this thread, outermost first, and its round."""
+
+    def __init__(self) -> None:
+        self.names: List[str] = []
+        self.round: Optional[int] = None
+
+
+_open = _Open()
 
 
 def enabled() -> bool:
@@ -44,13 +62,18 @@ def enabled() -> bool:
 
 
 def enable(on: bool = True) -> None:
-    """Turn span bookkeeping on (idempotent; process-wide)."""
+    """Turn the histogram and JSONL sinks on (idempotent; process-wide)."""
     global _ENABLED
     _ENABLED = bool(on)
 
 
 def disable() -> None:
     enable(False)
+
+
+def current_round() -> Optional[int]:
+    """The training round this thread is in, None outside one."""
+    return _open.round
 
 
 def _hist():
@@ -69,63 +92,60 @@ def _child(name: str):
     return child
 
 
-def _annotation(name: str):
-    """jax.profiler.TraceAnnotation(name).__enter__() or None — guarded so
-    telemetry works before/without jax initialization."""
-    global _profiler
-    if _profiler == 0:
-        try:
-            import jax.profiler as _p
-            _profiler = _p
-        except Exception:  # pragma: no cover - no jax in the process
-            _profiler = None
-    if _profiler is None:  # pragma: no cover - no jax in the process
-        return None
-    try:
-        ann = _profiler.TraceAnnotation(name)
-        ann.__enter__()
-        return ann
-    except Exception:  # pragma: no cover - profiler backend quirk
-        return None
-
-
-def record_phase(name: str, t0_ns: int, dur_ns: int) -> None:
-    """Feed one finished bracket into the sinks (histogram + flight ring
-    + JSONL trace).  Shared by Span and the Monitor shim so they agree on
-    format.  The flight append keeps the crash ring carrying the last few
-    hundred spans even when no trace file is configured."""
+def _flag_sinks(name: str, t0_ns: int, dur_ns: int) -> None:
+    """The two sinks the flag gates: registry histogram and JSONL event."""
     _child(name).observe(dur_ns / 1e9)
-    flight.record("span", name, s=dur_ns / 1e9)
     if trace.active():
         trace.emit(name, t0_ns, dur_ns)
 
 
+def record_phase(name: str, t0_ns: int, dur_ns: int) -> None:
+    """Feed one finished bracket into every sink (ring, histogram, JSONL).
+    For callers that time a bracket themselves behind :func:`enabled` (the
+    Monitor shim, the batcher's admission wait)."""
+    flight.record("span", name, t0_ns=t0_ns, dur_ns=dur_ns)
+    _flag_sinks(name, t0_ns, dur_ns)
+
+
 class Span:
-    """One enabled bracket.  Usable as a context manager or via explicit
-    begin()/end() (the Monitor shim drives it manually)."""
+    """One bracket.  Usable as a context manager or via explicit
+    begin()/end()."""
 
-    __slots__ = ("name", "t0", "_ann")
+    __slots__ = ("name", "args", "t0", "_ann", "_round_before")
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, args: Optional[Dict[str, Any]] = None) -> None:
         self.name = name
+        self.args = args or {}
         self.t0 = 0
         self._ann = None
 
+    def _annotation(self):
+        return _profiler.TraceAnnotation(self.name, **self.args)
+
     def begin(self) -> "Span":
-        self._ann = _annotation(self.name)
+        self._round_before = _open.round
+        if "round" in self.args:
+            _open.round = self.args["round"]
+        _open.names.append(self.name)
+        self._ann = self._annotation()
+        self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def end(self) -> int:
         dur = time.perf_counter_ns() - self.t0
-        ann = self._ann
-        if ann is not None:
-            self._ann = None
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:  # pragma: no cover - profiler backend quirk
-                pass
-        record_phase(self.name, self.t0, dur)
+        self._ann.__exit__(None, None, None)
+        names = _open.names
+        names.pop()
+        detail = dict(self.args)
+        if names:
+            detail["parent"] = names[-1]
+        if _open.round is not None:
+            detail["round"] = _open.round
+        _open.round = self._round_before
+        flight.record("span", self.name, t0_ns=self.t0, dur_ns=dur, **detail)
+        if _ENABLED:
+            _flag_sinks(self.name, self.t0, dur)
         return dur
 
     def __enter__(self) -> "Span":
@@ -135,36 +155,69 @@ class Span:
         self.end()
 
 
-class _NullSpan:
-    """Shared no-op: the disabled path allocates nothing."""
+class _StepSpan(Span):
+    """The round span: a ``StepTraceAnnotation``, so that XProf groups device
+    time by round; its record carries ``seq0``, the ring's sequence number
+    as the round began, by which :func:`recent` tells a whole round from one
+    the ring has begun to overwrite."""
 
     __slots__ = ()
 
-    def __enter__(self) -> "_NullSpan":
-        return self
+    def _annotation(self):
+        return _profiler.StepTraceAnnotation(self.name,
+                                             step_num=self.args["round"])
 
-    def __exit__(self, *exc) -> None:
-        pass
-
-    def begin(self) -> "_NullSpan":
-        return self
-
-    def end(self) -> int:
-        return 0
+    def begin(self) -> "Span":
+        self.args["seq0"] = flight.seq()
+        return super().begin()
 
 
-_NULL = _NullSpan()
+def span(name: str, **args: Any) -> Span:
+    """The instrumentation entry point.  ``args`` go to the annotation and
+    the ring record; ``round=`` also makes this span and everything inside it
+    belong to that training round."""
+    return Span(name, args)
 
 
-def span(name: str):
-    """The instrumentation entry point: a live Span when telemetry is
-    enabled, the shared no-op otherwise."""
-    return Span(name) if _ENABLED else _NULL
+def step_span(name: str, round: int) -> Span:
+    """The span of one whole training round (see :class:`_StepSpan`)."""
+    return _StepSpan(name, {"round": int(round)})
+
+
+def recent(name: Optional[str] = None,
+           round_from: Optional[int] = None) -> List[Dict[str, Any]]:
+    """The span records the ring still holds, oldest first, each a flat dict
+    (``name``, ``seq``, ``t0_ns``, ``dur_ns``, and where they apply
+    ``parent``, ``round`` and the span's arguments).
+
+    ``name`` keeps the spans of that name.  ``round_from`` keeps the spans of
+    rounds ``>= round_from`` and of whole rounds only: a round counts as
+    whole when its round span is in the ring and nothing recorded since that
+    span began has been overwritten.  A ring that has wrapped therefore
+    returns fewer rounds, never part of one."""
+    events = flight.events()
+    if not events:
+        return []
+    oldest = events[0]["seq"]
+    out = [dict(e.get("detail", ()), name=e["name"], seq=e["seq"])
+           for e in events if e["kind"] == "span"]
+    if round_from is not None:
+        # round spans with nothing lost since they began, and what lies
+        # before the first of them is the tail of a round that is not whole
+        whole = [r for r in out if r.get("seq0", -1) >= oldest]
+        rounds = {r["round"] for r in whole}
+        first = min((r["seq0"] for r in whole), default=0)
+        out = [r for r in out
+               if r.get("round") in rounds and r["seq"] >= first
+               and r["round"] >= round_from]
+    if name is not None:
+        out = [r for r in out if r["name"] == name]
+    return out
 
 
 def phase_totals() -> Dict[str, Dict[str, float]]:
-    """{phase: {"count": n, "seconds": s}} accumulated so far — the
-    inspectable read side (render_prometheus() has the full histogram)."""
+    """{phase: {"count": n, "seconds": s}} accumulated so far while the flag
+    was on (render_prometheus() has the full histogram)."""
     hist = get_registry().get(PHASE_HISTOGRAM)
     if hist is None:
         return {}

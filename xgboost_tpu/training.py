@@ -315,6 +315,8 @@ def train(
     end = num_boost_round if total else start + num_boost_round
     from .reliability import watchdog as _wd
     from .reliability.faults import maybe_inject
+    from .telemetry import span, step_span
+    from .telemetry.compile import counting
     from .telemetry.distributed import ship_to_tracker
 
     i = start
@@ -338,10 +340,17 @@ def train(
             # round boundary is where a worker death is injected for the
             # kill->resume parity tests
             maybe_inject("train.round", round=i, rank=collective.get_rank)
-            if cbs.before_iteration(bst, i, dtrain, evals):
+            # the round span closes before after_iteration on purpose:
+            # callbacks start and stop profiler sessions there, and an
+            # annotation that straddles a session's edge is lost
+            with counting(step_span("train.round", i)):
+                stop = cbs.before_iteration(bst, i, dtrain, evals)
+                if not stop:
+                    bst.update(dtrain, i, fobj=obj)
+            if stop:
                 break
-            bst.update(dtrain, i, fobj=obj)
-            stop = cbs.after_iteration(bst, i, dtrain, evals)
+            with counting(span("train.after_iteration", round=i)):
+                stop = cbs.after_iteration(bst, i, dtrain, evals)
         except RegroupRequired:
             if elastic is None:
                 raise
@@ -441,6 +450,7 @@ def cv(
             period=1 if verbose_eval is True else int(verbose_eval),
             show_stdv=show_stdv))
     cbs = CallbackContainer(callbacks, is_cv=True)
+    from .telemetry import step_span
 
     class _Agg:
         """Aggregate booster stand-in handed to callbacks (reference _PackedBooster)."""
@@ -469,12 +479,13 @@ def cv(
         if cbs.before_iteration(agg, i, dtrain, []):
             break
         fold_metrics: Dict[str, List[float]] = {}
-        for p in packs:
-            p.update(i, obj)
-            msg = p.eval(i, custom_metric)
-            for part in msg.strip().split("\t")[1:]:
-                key, v = part.rsplit(":", 1)
-                fold_metrics.setdefault(key, []).append(float(v))
+        with step_span("train.round", i):
+            for p in packs:
+                p.update(i, obj)
+                msg = p.eval(i, custom_metric)
+                for part in msg.strip().split("\t")[1:]:
+                    key, v = part.rsplit(":", 1)
+                    fold_metrics.setdefault(key, []).append(float(v))
         for key, vals in fold_metrics.items():
             mean, std = float(np.mean(vals)), float(np.std(vals))
             results.setdefault(f"{key}-mean", []).append(mean)

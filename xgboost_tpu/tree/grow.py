@@ -255,23 +255,26 @@ def level_step(
     N = 1 << depth
     B = cuts_pad.shape[1]
 
-    idx = node0 + jnp.arange(N, dtype=jnp.int32)
-    totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, N, axis=0)
-    alive_lvl = lax.dynamic_slice_in_dim(state.alive, node0, N, axis=0)
-    lower_lvl = lax.dynamic_slice_in_dim(state.lower, node0, N, axis=0)
-    upper_lvl = lax.dynamic_slice_in_dim(state.upper, node0, N, axis=0)
-    w = calc_weight(totals_lvl[:, 0], totals_lvl[:, 1], params, lower_lvl, upper_lvl)
+    with jax.named_scope("split"):
+        idx = node0 + jnp.arange(N, dtype=jnp.int32)
+        totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, N, axis=0)
+        alive_lvl = lax.dynamic_slice_in_dim(state.alive, node0, N, axis=0)
+        lower_lvl = lax.dynamic_slice_in_dim(state.lower, node0, N, axis=0)
+        upper_lvl = lax.dynamic_slice_in_dim(state.upper, node0, N, axis=0)
+        w = calc_weight(totals_lvl[:, 0], totals_lvl[:, 1], params,
+                        lower_lvl, upper_lvl)
 
     if last_level:
         # no hist needed: every surviving node becomes a leaf
-        return state._replace(
-            is_leaf=state.is_leaf.at[idx].set(alive_lvl),
-            leaf_val=state.leaf_val.at[idx].set(
-                jnp.where(alive_lvl, params.eta * w, 0.0)
-            ),
-            base_weight=state.base_weight.at[idx].set(w),
-            sum_hess=state.sum_hess.at[idx].set(totals_lvl[:, 1]),
-        ), None
+        with jax.named_scope("record"):
+            return state._replace(
+                is_leaf=state.is_leaf.at[idx].set(alive_lvl),
+                leaf_val=state.leaf_val.at[idx].set(
+                    jnp.where(alive_lvl, params.eta * w, 0.0)
+                ),
+                base_weight=state.base_weight.at[idx].set(w),
+                sum_hess=state.sum_hess.at[idx].set(totals_lvl[:, 1]),
+            ), None
 
     if quantised:
         # gpair here is the (R, C, 3) int8 limb array: integer builds and
@@ -296,61 +299,68 @@ def level_step(
         from ..ops.hist_pallas import build_histogram_pallas as _build
     else:
         _build = build_histogram
-    if subtract:
-        half = N // 2
-        # left children sit at even offsets 2j (heap id node0 + 2j); parent j
-        # of the previous level maps to offsets (2j, 2j+1)
-        left = _build(bins, gpair, state.pos, node0=node0, n_nodes=half,
-                      n_bin=B, stride=2)
-        if axis_name is not None:
-            left = lax.psum(left, axis_name)
-        hist = combine_sibling_hists(left, hist_prev, alive_lvl)
-    else:
-        hist = _build(bins, gpair, state.pos, node0=node0, n_nodes=N, n_bin=B)
-        if axis_name is not None:
-            hist = lax.psum(hist, axis_name)  # the distributed cost (SURVEY §3.1)
-    if quantised:
-        hist_eval = dequantise(hist, rho)  # the ONE rounding step
-    else:
-        hist_eval = hist
+    with jax.named_scope("hist"):
+        if subtract:
+            half = N // 2
+            # left children sit at even offsets 2j (heap id node0 + 2j);
+            # parent j of the previous level maps to offsets (2j, 2j+1)
+            left = _build(bins, gpair, state.pos, node0=node0, n_nodes=half,
+                          n_bin=B, stride=2)
+            if axis_name is not None:
+                left = lax.psum(left, axis_name)
+            hist = combine_sibling_hists(left, hist_prev, alive_lvl)
+        else:
+            hist = _build(bins, gpair, state.pos, node0=node0, n_nodes=N,
+                          n_bin=B)
+            if axis_name is not None:
+                # the distributed cost (SURVEY §3.1)
+                hist = lax.psum(hist, axis_name)
+        if quantised:
+            hist_eval = dequantise(hist, rho)  # the ONE rounding step
+        else:
+            hist_eval = hist
 
-    # interaction constraints: allowed feature set per node = union of the
-    # constraint sets still compatible with the node's path
-    # (reference: src/tree/constraints.cc FeatureInteractionConstraint)
-    compat_lvl = lax.dynamic_slice_in_dim(state.setcompat, node0, N, axis=0)
-    allowed = jnp.einsum("ns,sf->nf", compat_lvl.astype(jnp.float32),
-                         set_matrix.astype(jnp.float32)) > 0.0  # (N, F)
-    fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
-    fmask = allowed & fm
+    with jax.named_scope("split"):
+        # interaction constraints: allowed feature set per node = union of
+        # the constraint sets still compatible with the node's path
+        # (reference: src/tree/constraints.cc FeatureInteractionConstraint)
+        compat_lvl = lax.dynamic_slice_in_dim(state.setcompat, node0, N, axis=0)
+        allowed = jnp.einsum("ns,sf->nf", compat_lvl.astype(jnp.float32),
+                             set_matrix.astype(jnp.float32)) > 0.0  # (N, F)
+        fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
+        fmask = allowed & fm
 
-    node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
-    best = evaluate_splits(hist_eval, totals_lvl, n_bins, params, fmask,
-                           node_bounds,
-                           cat_mask=cat_mask if has_cat else None)
+        node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
+        best = evaluate_splits(hist_eval, totals_lvl, n_bins, params, fmask,
+                               node_bounds,
+                               cat_mask=cat_mask if has_cat else None)
 
-    gamma_eps = max(params.gamma, _EPS)
-    can_split = alive_lvl & (best.gain > gamma_eps)
+        gamma_eps = max(params.gamma, _EPS)
+        can_split = alive_lvl & (best.gain > gamma_eps)
 
-    # split budget (max_leaves): expand best-first under lossguide, node-order
-    # under depthwise (reference: src/tree/driver.h grow-policy queue)
-    budget = state.splits_left[0]
-    prio = best.gain if lossguide else -idx.astype(jnp.float32)
-    prio = jnp.where(can_split, prio, -jnp.inf)
-    order = jnp.argsort(-prio)
-    ranks = jnp.argsort(order).astype(jnp.int32)
-    can_split = can_split & (ranks < budget)
-    new_budget = budget - jnp.sum(can_split).astype(jnp.int32)
+        # split budget (max_leaves): expand best-first under lossguide,
+        # node-order under depthwise (reference: src/tree/driver.h
+        # grow-policy queue)
+        budget = state.splits_left[0]
+        prio = best.gain if lossguide else -idx.astype(jnp.float32)
+        prio = jnp.where(can_split, prio, -jnp.inf)
+        order = jnp.argsort(-prio)
+        ranks = jnp.argsort(order).astype(jnp.int32)
+        can_split = can_split & (ranks < budget)
+        new_budget = budget - jnp.sum(can_split).astype(jnp.int32)
 
-    new_leaf = alive_lvl & ~can_split
+        new_leaf = alive_lvl & ~can_split
 
-    thr_lvl = cuts_pad[best.feature, jnp.minimum(best.bin, B - 1)]
-    member = set_matrix.T[jnp.clip(best.feature, 0, set_matrix.shape[1] - 1)]  # (N, n_sets)
-    st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
-                       totals_lvl, compat_lvl, member, new_budget, lower_lvl,
-                       upper_lvl, params)
-    st = st._replace(
-        pos=_update_positions(bins, st.pos, best, can_split, node0, N, B, has_cat)
-    )
+        thr_lvl = cuts_pad[best.feature, jnp.minimum(best.bin, B - 1)]
+        member = set_matrix.T[jnp.clip(best.feature, 0, set_matrix.shape[1] - 1)]  # (N, n_sets)
+    with jax.named_scope("record"):
+        st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
+                           totals_lvl, compat_lvl, member, new_budget,
+                           lower_lvl, upper_lvl, params)
+    with jax.named_scope("route"):
+        st = st._replace(
+            pos=_update_positions(bins, st.pos, best, can_split, node0, N, B,
+                                  has_cat))
     return st, hist
 
 
@@ -410,13 +420,14 @@ def level_step_padded(
     B = cuts_pad.shape[1]
     node0 = jnp.asarray(node0, jnp.int32)
 
-    idx = node0 + jnp.arange(W, dtype=jnp.int32)
-    totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, W, axis=0)
-    alive_lvl = lax.dynamic_slice_in_dim(state.alive, node0, W, axis=0)
-    lower_lvl = lax.dynamic_slice_in_dim(state.lower, node0, W, axis=0)
-    upper_lvl = lax.dynamic_slice_in_dim(state.upper, node0, W, axis=0)
-    w = calc_weight(totals_lvl[:, 0], totals_lvl[:, 1], params, lower_lvl,
-                    upper_lvl)
+    with jax.named_scope("split"):
+        idx = node0 + jnp.arange(W, dtype=jnp.int32)
+        totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, W, axis=0)
+        alive_lvl = lax.dynamic_slice_in_dim(state.alive, node0, W, axis=0)
+        lower_lvl = lax.dynamic_slice_in_dim(state.lower, node0, W, axis=0)
+        upper_lvl = lax.dynamic_slice_in_dim(state.upper, node0, W, axis=0)
+        w = calc_weight(totals_lvl[:, 0], totals_lvl[:, 1], params, lower_lvl,
+                        upper_lvl)
 
     if hist_impl == "pallas":
         raise NotImplementedError(
@@ -428,53 +439,56 @@ def level_step_padded(
         _build_at = build_histogram_q
     else:
         _build_at = build_histogram_at
-    if subtract:
-        half = W // 2
-        left = _build_at(bins, gpair, state.pos, node0,
-                         n_nodes=half, n_bin=B, stride=2)
-        if axis_name is not None:
-            left = lax.psum(left, axis_name)
-        hist = combine_sibling_hists(left, hist_prev[:half], alive_lvl)
-    else:
-        hist = _build_at(bins, gpair, state.pos, node0,
-                         n_nodes=W, n_bin=B)
-        if axis_name is not None:
-            hist = lax.psum(hist, axis_name)
-    hist_eval = dequantise(hist, rho) if quantised else hist
+    with jax.named_scope("hist"):
+        if subtract:
+            half = W // 2
+            left = _build_at(bins, gpair, state.pos, node0,
+                             n_nodes=half, n_bin=B, stride=2)
+            if axis_name is not None:
+                left = lax.psum(left, axis_name)
+            hist = combine_sibling_hists(left, hist_prev[:half], alive_lvl)
+        else:
+            hist = _build_at(bins, gpair, state.pos, node0,
+                             n_nodes=W, n_bin=B)
+            if axis_name is not None:
+                hist = lax.psum(hist, axis_name)
+        hist_eval = dequantise(hist, rho) if quantised else hist
 
-    compat_lvl = lax.dynamic_slice_in_dim(state.setcompat, node0, W, axis=0)
-    allowed = jnp.einsum("ns,sf->nf", compat_lvl.astype(jnp.float32),
-                         set_matrix.astype(jnp.float32)) > 0.0
-    fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
-    fmask = allowed & fm
+    with jax.named_scope("split"):
+        compat_lvl = lax.dynamic_slice_in_dim(state.setcompat, node0, W, axis=0)
+        allowed = jnp.einsum("ns,sf->nf", compat_lvl.astype(jnp.float32),
+                             set_matrix.astype(jnp.float32)) > 0.0
+        fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
+        fmask = allowed & fm
 
-    node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
-    best = evaluate_splits(hist_eval, totals_lvl, n_bins, params, fmask,
-                           node_bounds,
-                           cat_mask=cat_mask if has_cat else None)
+        node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
+        best = evaluate_splits(hist_eval, totals_lvl, n_bins, params, fmask,
+                               node_bounds,
+                               cat_mask=cat_mask if has_cat else None)
 
-    gamma_eps = max(params.gamma, _EPS)
-    can_split = alive_lvl & (best.gain > gamma_eps)
+        gamma_eps = max(params.gamma, _EPS)
+        can_split = alive_lvl & (best.gain > gamma_eps)
 
-    budget = state.splits_left[0]
-    prio = best.gain if lossguide else -idx.astype(jnp.float32)
-    prio = jnp.where(can_split, prio, -jnp.inf)
-    order = jnp.argsort(-prio)
-    ranks = jnp.argsort(order).astype(jnp.int32)
-    can_split = can_split & (ranks < budget)
-    new_budget = budget - jnp.sum(can_split).astype(jnp.int32)
+        budget = state.splits_left[0]
+        prio = best.gain if lossguide else -idx.astype(jnp.float32)
+        prio = jnp.where(can_split, prio, -jnp.inf)
+        order = jnp.argsort(-prio)
+        ranks = jnp.argsort(order).astype(jnp.int32)
+        can_split = can_split & (ranks < budget)
+        new_budget = budget - jnp.sum(can_split).astype(jnp.int32)
 
-    new_leaf = alive_lvl & ~can_split
+        new_leaf = alive_lvl & ~can_split
 
-    thr_lvl = cuts_pad[best.feature, jnp.minimum(best.bin, B - 1)]
-    member = set_matrix.T[jnp.clip(best.feature, 0, set_matrix.shape[1] - 1)]
-    st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
-                       totals_lvl, compat_lvl, member, new_budget, lower_lvl,
-                       upper_lvl, params)
-    st = st._replace(
-        pos=_update_positions(bins, st.pos, best, can_split, node0, W, B,
-                              has_cat)
-    )
+        thr_lvl = cuts_pad[best.feature, jnp.minimum(best.bin, B - 1)]
+        member = set_matrix.T[jnp.clip(best.feature, 0, set_matrix.shape[1] - 1)]
+    with jax.named_scope("record"):
+        st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
+                           totals_lvl, compat_lvl, member, new_budget,
+                           lower_lvl, upper_lvl, params)
+    with jax.named_scope("route"):
+        st = st._replace(
+            pos=_update_positions(bins, st.pos, best, can_split, node0, W, B,
+                                  has_cat))
     return st, hist
 
 
@@ -483,8 +497,9 @@ def leaf_margin_delta(pos, leaf_val):
     """Per-row margin update from the finished tree — the prediction-cache
     fast path (reference: TreeUpdater::UpdatePredictionCache,
     include/xgboost/tree_updater.h:92): every row sits on its leaf already."""
-    safe = jnp.clip(pos, 0, leaf_val.shape[0] - 1)
-    return jnp.where(pos >= 0, leaf_val[safe], 0.0)
+    with jax.named_scope("margin"):
+        safe = jnp.clip(pos, 0, leaf_val.shape[0] - 1)
+        return jnp.where(pos >= 0, leaf_val[safe], 0.0)
 
 
 class GrownTree(NamedTuple):
@@ -610,7 +625,7 @@ class HistTreeGrower:
             hist_prev = None
             for d in range(md + 1):
                 fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-                with span(_LEVEL):
+                with span(_LEVEL, depth=d):
                     state, hist_prev = level_step(
                         state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm,
                         hist_prev, rho, depth=d, last_level=(d == md),
@@ -622,7 +637,7 @@ class HistTreeGrower:
         # 3 compiled programs regardless of depth: root, shared padded
         # interior (traced node0), leaf finalize
         fm = ones if feature_masks is None else feature_masks(0, 1)
-        with span(_LEVEL):
+        with span(_LEVEL, depth=0):
             state, hist = level_step(
                 state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm, None,
                 rho, depth=0, last_level=False, hist_impl=self.hist_impl,
@@ -632,14 +647,14 @@ class HistTreeGrower:
         for d in range(1, md):
             fm = (ones if feature_masks is None
                   else self._pad_mask(feature_masks(d, 1 << d), W))
-            with span(_LEVEL):
+            with span(_LEVEL, depth=d):
                 state, hist_pad = level_step_padded(
                     state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm,
                     hist_pad, (1 << d) - 1, rho, width=W,
                     subtract=self.subtract, hist_impl=self.hist_impl,
                     **common)
         fm = ones if feature_masks is None else feature_masks(md, 1 << md)
-        with span(_LEVEL):
+        with span(_LEVEL, depth=md):
             state, _ = level_step(
                 state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm, None,
                 rho, depth=md, last_level=True, hist_impl=self.hist_impl,
@@ -660,7 +675,11 @@ class HistTreeGrower:
     def to_host(state: TreeState) -> GrownTree:
         import numpy as np
 
-        with span("grow.to_host"):
+        # the one place a round with no evals blocks on the device: what is
+        # left of the round's host time is the loop's own
+        with span("grow.wait_device"):
+            jax.block_until_ready(state)
+        with span("grow.to_host", copies=len(GrownTree._fields)):
             return GrownTree(
                 is_cat=np.asarray(state.is_cat),
                 cat_set=np.asarray(state.cat_set),

@@ -19,6 +19,8 @@ import time
 from collections import defaultdict
 from typing import Dict, List, Tuple
 
+import jax.profiler
+
 from ..config import get_config
 from ..telemetry import spans as _spans
 
@@ -28,12 +30,13 @@ class Monitor:
         self.label = label
         self.totals: Dict[str, float] = defaultdict(float)
         self.counts: Dict[str, int] = defaultdict(int)
-        # name -> stack of (t0_ns, annotation-or-None): LIFO per label so
+        # name -> stack of (t0_ns, annotation): LIFO per label so
         # re-entrant brackets nest instead of clobbering each other
         self._open: Dict[str, List[Tuple[int, object]]] = defaultdict(list)
 
     def start(self, name: str) -> None:
-        ann = _spans._annotation(f"{self.label}.{name}")
+        ann = jax.profiler.TraceAnnotation(f"{self.label}.{name}")
+        ann.__enter__()
         self._open[name].append((time.perf_counter_ns(), ann))
 
     def stop(self, name: str) -> None:
@@ -42,11 +45,7 @@ class Monitor:
             return  # unmatched stop: ignore, like the pop(None) before
         t0, ann = stack.pop()
         dur_ns = time.perf_counter_ns() - t0
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:  # pragma: no cover - profiler backend quirk
-                pass
+        ann.__exit__(None, None, None)
         self.totals[name] += dur_ns / 1e9
         self.counts[name] += 1
         if _spans.enabled():
