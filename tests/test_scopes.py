@@ -5,6 +5,7 @@ table in its ``op_name``, so that any profile can be summed by them
 (telemetry/xplane.py).  Read from the compiled HLO text at toy shapes, with
 the histogram forced to the XLA matmul formulation the chip runs."""
 import re
+import types
 
 import numpy as np
 import pytest
@@ -106,6 +107,44 @@ def test_level_programs_name_every_row_sized_operation(toy, depth, last,
         assert seen == {}
     else:
         assert set(seen) == {"hist", "route"}, seen
+
+
+def row_sized_gathers(hlo: str, scope: str) -> list:
+    """The gathers under ``scope`` whose output is row-sized."""
+    found = []
+    for line in hlo.splitlines():
+        made = re.search(r" = (\S+) gather\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if (made and row_sized(made.group(1)) and name
+                and scope in name.group(1).split("/")):
+            found.append(line.strip()[:200])
+    return found
+
+
+@pytest.mark.parametrize("depth,subtract,padded", [
+    (0, False, False), (2, True, False), (1, True, True), (2, False, True)])
+def test_route_gathers_nothing_row_sized(toy, depth, subtract, padded):
+    """Beside the matmul histogram the position rewrite is the dense form
+    (tree/grow.py): on the chip its gathers were 42% and 52% of a round."""
+    hlo = level_hlo(toy, depth, False, subtract, padded)
+    assert "route" in scopes_of_row_sized(hlo)
+    assert not row_sized_gathers(hlo, "route")
+
+
+def test_a_row_sized_gather_would_be_seen(toy):
+    """The same reading finds the gather form's gathers: the page's and the
+    node tables'."""
+    best = types.SimpleNamespace(
+        feature=jnp.zeros(4, jnp.int32), bin=jnp.zeros(4, jnp.int32),
+        default_left=jnp.ones(4, bool))
+
+    def route(bins, pos):
+        with jax.named_scope("route"):
+            return grow._update_positions_gather(
+                bins, pos, best, jnp.ones(4, bool), 3, 4, B, False)
+
+    hlo = jax.jit(route).lower(toy["bins"], toy["state"].pos).compile().as_text()
+    assert len(row_sized_gathers(hlo, "route")) >= 2
 
 
 def test_level_program_names_its_node_work_too(toy):

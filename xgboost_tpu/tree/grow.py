@@ -25,8 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.histogram import (build_histogram, combine_sibling_hists,
-                             node_sums)
+from ..ops.histogram import (_use_scatter, build_histogram,
+                             combine_sibling_hists, node_sums)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 
@@ -188,7 +188,35 @@ def _record_level(st: TreeState, best, idx, can_split, new_leaf, w, thr_lvl,
 def _update_positions(bins, pos, best, can_split, node0: int, N: int, B: int,
                       has_cat: bool):
     """Route rows of splitting nodes to their children (RowPartitioner
-    analogue) — per-row elementwise, safe to run per page shard."""
+    analogue) — per-row elementwise, safe to run per page shard.
+
+    A row at level offset ``lc = pos - node0`` in ``[0, N)`` whose node can
+    split looks at ``binval = bins[r, feature[lc]]``: left if ``binval <=
+    bin[lc]`` (or, for a categorical split, if ``binval`` is not in
+    ``cat_set[lc]``), by ``default_left[lc]`` if ``binval >= B`` (missing);
+    ``pos' = 2*pos + 1 + (0|1)``.  Every other row keeps ``pos``.
+
+    Two forms of the same integer logic, bitwise equal on every input
+    (tests/test_route.py), chosen where the histogram's is: a level step
+    whose histogram is the dense one-hot matmul routes densely too, with no
+    per-row gather (on the chip the five gathers cost 0.24 to 0.57 s a call
+    at 10.5M rows, the dense pass 1.1 to 2.4 ms: PERF.md, PR 27); beside the
+    CPU's row-pass histogram a gather is the cheap form, and so it is for a
+    page too wide for a node's entry to fit one int32."""
+    dense = not _use_scatter() and _packed_bits(bins.shape[1], B) <= 31
+    form = _update_positions_dense if dense else _update_positions_gather
+    return form(bins, pos, best, can_split, node0, N, B, has_cat)
+
+
+def _packed_bits(F: int, B: int) -> int:
+    """Bits of a node's packed entry (``_update_positions_dense``): three
+    flags, ``bin + 1`` in ``[0, B]``, the feature."""
+    return 3 + int(B).bit_length() + max(F - 1, 1).bit_length()
+
+
+def _update_positions_gather(bins, pos, best, can_split, node0, N: int,
+                             B: int, has_cat: bool):
+    """One gather a row out of each node table and one out of the page."""
     local = pos - node0
     in_lvl = (local >= 0) & (local < N)
     lc = jnp.clip(local, 0, N - 1)
@@ -210,6 +238,49 @@ def _update_positions(bins, pos, best, can_split, node0: int, N: int, B: int,
     goleft = jnp.where(binval >= B, dl, goleft_split)  # sentinel B = missing
     child = 2 * pos + 1 + jnp.where(goleft, 0, 1)
     return jnp.where(in_lvl & can_r, child, pos)
+
+
+def _update_positions_dense(bins, pos, best, can_split, node0, N: int,
+                            B: int, has_cat: bool):
+    """No gather with a row-sized output: what a row needs of its node is
+    packed into one int32 a node on the node side and brought to the row by
+    a select over the N nodes; the split feature's bin by a select over the
+    F columns the histogram pass reads anyway.  Both are reduces that XLA
+    fuses with their compare, so neither the (N, R) nor the (R, F) int32
+    operand exists in memory.  Work a level: R*(N + F) selects."""
+    F = bins.shape[1]
+    i32 = jnp.int32
+    # bit 0 can_split, 1 default_left, 2 is_cat, then bin + 1 in [0, B] (a
+    # bin below 0 or above B - 1 routes every present value one way, as
+    # ``binval <= bin`` does), then the feature
+    bin_bits = int(B).bit_length()
+    assert _packed_bits(F, B) <= 31, (F, B)
+    packed = (can_split.astype(i32)
+              | (best.default_left.astype(i32) << 1)
+              | ((jnp.clip(best.bin, -1, B - 1).astype(i32) + 1) << 3)
+              | (jnp.clip(best.feature, 0, F - 1).astype(i32) << (3 + bin_bits)))
+    if has_cat:
+        packed = packed | (best.is_cat.astype(i32) << 2)
+    local = pos - node0
+    # rows above or below the level, and padded rows, match no node: 0
+    row = jnp.sum(jnp.where(jnp.arange(N, dtype=i32)[:, None] == local[None, :],
+                            packed[:, None], 0), axis=0)
+    can_r = (row & 1) == 1
+    dl = (row & 2) == 2
+    sb = ((row >> 3) & ((1 << bin_bits) - 1)) - 1
+    fr = row >> (3 + bin_bits)
+    binval = jnp.sum(jnp.where(jnp.arange(F, dtype=i32)[None, :] == fr[:, None],
+                               bins.astype(i32), 0), axis=1)
+    goleft = binval <= sb
+    if has_cat:
+        # the membership lookup keeps its gather: no cell has categories
+        lc = jnp.clip(local, 0, N - 1)
+        member = best.cat_set.reshape(-1)[lc * B + jnp.clip(binval, 0, B - 1)]
+        goleft = jnp.where((row & 4) == 4, ~member, goleft)
+    goleft = jnp.where(binval >= B, dl, goleft)  # sentinel B = missing
+    child = 2 * pos + 1 + jnp.where(goleft, 0, 1)
+    return jnp.where(can_r, child, pos)
+
 
 @functools.partial(
     jax.jit,
@@ -531,8 +602,6 @@ def default_padded_levels(max_depth: int) -> bool:
     extending this to the CPU default)."""
     if jax.default_backend() != "cpu" or max_depth <= 5:
         return True
-    from ..ops.histogram import _use_scatter
-
     # native/scatter row-pass kernels: padding costs only the padded hist
     # output blocks (memset + accumulate traffic, 2**(md-1)*F*B*2 floats
     # per level) and the scan over dead slots is short-circuited in the
