@@ -194,3 +194,33 @@ def test_sharded_level_program_allreduces_on_four_chips(topo, device_paths,
         _shape((W, F, B, 2), jnp.float32, rep), _shape((), jnp.int32, rep))
     compiled = grower._interior_fn.lower(*args).compile()
     assert "all-reduce" in compiled.as_text()
+
+
+def test_ranking_gradient_compiles_for_v5e(one_chip):
+    """The top-k LambdaMART gradient at the width of the ranking cell: groups
+    1,251 slots wide, 32 pairs a document, 2,080 groups in 20 blocks of 104
+    (the cell has 18,919 in 182; a block is the same program).  Inside the
+    block loop: two sorts and no gather; after it, the two row-sized gathers
+    back to row order."""
+    import re
+
+    from xgboost_tpu.objective.ranking import (_lambda_gradients_topk,
+                                               make_topk_layout)
+
+    sizes = np.full(2080, 120)
+    sizes[::100] = 1251
+    sizes[1::100] = 1
+    ptr = np.concatenate([[0], np.cumsum(sizes)])
+    layout = make_topk_layout(ptr, np.zeros(ptr[-1], np.float32), 32)
+    assert layout.gain.shape == (20, 104, 1251)
+    rows = -(-int(ptr[-1]) // 2048) * 2048
+    compiled = _lambda_gradients_topk.lower(
+        _shape((rows,), jnp.float32, one_chip),
+        jax.tree.map(lambda a: _shape(a.shape, a.dtype, one_chip), layout),
+        k=32, ndcg_weight=True, score_norm=True, group_norm=True).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" sort\(", text)) == 2
+    gathers = re.findall(r" = (\S+) gather\(", text)
+    assert len(gathers) == 2 and all(g.startswith(f"f32[{ptr[-1]}]")
+                                     for g in gathers)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29

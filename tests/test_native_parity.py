@@ -137,7 +137,7 @@ def test_lambdarank_native_matches_xla():
     ragged group sizes (incl. singleton groups) and both weight modes."""
     from xgboost_tpu.objective.ranking import (_lambda_gradients_topk,
                                                _lambda_gradients_topk_native,
-                                               make_group_layout)
+                                               make_topk_layout)
 
     rng = np.random.default_rng(5)
     sizes = np.concatenate([rng.integers(1, 40, size=30), [1, 2, 200]])
@@ -145,7 +145,6 @@ def test_lambdarank_native_matches_xla():
     R = int(gptr[-1])
     pred = rng.normal(size=R).astype(np.float32)
     y = rng.integers(0, 5, size=R).astype(np.float32)
-    idx, mask, inv = make_group_layout(gptr)
 
     for ndcg_w, snorm, gnorm, k in ((True, True, True, 8),
                                     (False, False, False, 3),
@@ -154,9 +153,8 @@ def test_lambdarank_native_matches_xla():
             jnp.asarray(pred), jnp.asarray(y), jnp.asarray(gptr), k=k,
             ndcg_weight=ndcg_w, score_norm=snorm, group_norm=gnorm)
         gb, hb = _lambda_gradients_topk(
-            jnp.asarray(pred), jnp.asarray(y), jnp.asarray(idx),
-            jnp.asarray(mask), jnp.asarray(inv), k=k, ndcg_weight=ndcg_w,
-            score_norm=snorm, group_norm=gnorm)
+            jnp.asarray(pred), make_topk_layout(gptr, y, k), k=k,
+            ndcg_weight=ndcg_w, score_norm=snorm, group_norm=gnorm)
         np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),
                                    rtol=2e-4, atol=2e-6)
         np.testing.assert_allclose(np.asarray(ha), np.asarray(hb),
@@ -168,21 +166,19 @@ def test_lambdarank_zero_spread_first_iteration():
     be skipped identically on both paths."""
     from xgboost_tpu.objective.ranking import (_lambda_gradients_topk,
                                                _lambda_gradients_topk_native,
-                                               make_group_layout)
+                                               make_topk_layout)
 
     rng = np.random.default_rng(1)
     gptr = np.array([0, 20, 50], np.int32)
     R = 50
     pred = np.full(R, 0.5, np.float32)
     y = rng.integers(0, 4, size=R).astype(np.float32)
-    idx, mask, inv = make_group_layout(gptr)
     ga, ha = _lambda_gradients_topk_native(
         jnp.asarray(pred), jnp.asarray(y), jnp.asarray(gptr), k=32,
         ndcg_weight=True, score_norm=True, group_norm=True)
     gb, hb = _lambda_gradients_topk(
-        jnp.asarray(pred), jnp.asarray(y), jnp.asarray(idx),
-        jnp.asarray(mask), jnp.asarray(inv), k=32, ndcg_weight=True,
-        score_norm=True, group_norm=True)
+        jnp.asarray(pred), make_topk_layout(gptr, y, 32), k=32,
+        ndcg_weight=True, score_norm=True, group_norm=True)
     np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), rtol=2e-4,
                                atol=2e-6)
     np.testing.assert_allclose(np.asarray(ha), np.asarray(hb), rtol=2e-4,

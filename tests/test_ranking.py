@@ -214,3 +214,168 @@ def test_rank_mean_multi_pair_normalized(ltr):
         gp = obj.get_gradient(jnp.zeros(len(y)), jnp.asarray(y), None, 0)
         g[npair] = float(jnp.abs(gp[:, 0, 0]).sum())
     assert g[4] < 2.0 * g[1], g
+
+
+# ---- the chip's form of the top-k gradient (objective/ranking.py
+# _lambda_gradients_topk over make_topk_layout's grid), called with arrays on
+# the CPU, against the numpy float64 reference of the benchmark
+import os  # noqa: E402
+import sys  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+RAGGED = [1, 2, 5, 31, 32, 33, 40, 300, 7, 12, 1, 64]  # 1, 2, under k, over k
+EQUAL_LABELS = 8  # the group of 7 whose labels are all equal
+
+
+def ragged_case(scores: str, seed: int = 3):
+    rng = np.random.default_rng(seed)
+    ptr = np.concatenate([[0], np.cumsum(RAGGED)])
+    R = int(ptr[-1])
+    y = rng.integers(0, 5, R).astype(np.float32)
+    y[ptr[EQUAL_LABELS]:ptr[EQUAL_LABELS + 1]] = 2.0
+    pred = {"distinct": rng.normal(size=R),
+            "tied": rng.integers(0, 4, R) * 0.25,
+            "round0": np.full(R, 0.5)}[scores].astype(np.float32)
+    return ptr, y, pred
+
+
+def loop_layout(group_ptr):
+    """make_group_layout as it was: a Python loop over groups."""
+    sizes = np.diff(group_ptr)
+    G, S = len(sizes), int(sizes.max())
+    idx = np.zeros((G, S), np.int32)
+    mask = np.zeros((G, S), bool)
+    inv = np.zeros(int(group_ptr[-1]), np.int32)
+    for g in range(G):
+        rows = np.arange(group_ptr[g], group_ptr[g + 1])
+        idx[g, :sizes[g]] = rows
+        mask[g, :sizes[g]] = True
+        inv[rows] = g * S + np.arange(sizes[g])
+    return idx, mask, inv
+
+
+@pytest.mark.parametrize("k", [32, 8])
+@pytest.mark.parametrize("scores", ["distinct", "tied", "round0"])
+def test_chip_form_of_topk_gradient_matches_the_numpy_reference(scores, k):
+    import jax.numpy as jnp
+
+    from benchmarks import reference_rank
+    from xgboost_tpu.objective.ranking import (_lambda_gradients_topk,
+                                               make_topk_layout)
+
+    ptr, y, pred = ragged_case(scores)
+    R, pad = len(y), 20  # the margin is padded past the last group
+    g, h = _lambda_gradients_topk(
+        jnp.asarray(np.pad(pred, (0, pad))), make_topk_layout(ptr, y, k), k=k,
+        ndcg_weight=True, score_norm=True, group_norm=True)
+    g, h = np.asarray(g), np.asarray(h)
+    assert g.shape == (R + pad,) and not g[R:].any() and not h[R:].any()
+    g_ref, h_ref = reference_rank.lambdarank_gpair(pred, y.astype(np.float64),
+                                                   ptr, k)
+    # float32 rounding of a sum of up to 300 terms, against the largest
+    np.testing.assert_allclose(g[:R], g_ref, rtol=0, atol=2e-6 * np.abs(g_ref).max())
+    np.testing.assert_allclose(h[:R], h_ref, rtol=0, atol=2e-6 * np.abs(h_ref).max())
+    for lone in (0, 10):  # groups of one document have no pair
+        assert g[ptr[lone]] == 0 and h[ptr[lone]] == 0
+    same = slice(ptr[EQUAL_LABELS], ptr[EQUAL_LABELS + 1])
+    assert not g[same].any() and not h[same].any()
+    assert np.abs(g_ref).max() > 0
+
+
+@pytest.mark.parametrize("sizes", [RAGGED, [5], [1, 1, 1], [3, 1251, 2]],
+                         ids=["ragged", "one_group", "singletons", "longest"])
+def test_group_layout_without_a_loop_equals_the_loop(sizes):
+    from xgboost_tpu.objective.ranking import make_group_layout
+
+    ptr = np.concatenate([[0], np.cumsum(sizes)])
+    for got, want in zip(make_group_layout(ptr), loop_layout(ptr)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+def test_topk_layout_holds_what_the_labels_fix():
+    from xgboost_tpu.objective import ranking
+
+    ptr, y, _ = ragged_case("distinct")
+    lay = ranking.make_topk_layout(ptr, np.pad(y, (0, 9)), 32)  # padded labels
+    n_blocks, gb, S = lay.gain.shape
+    assert S == 300 and n_blocks * gb >= len(RAGGED)
+    idx, mask, inv = loop_layout(ptr)
+    G = len(RAGGED)
+    np.testing.assert_array_equal(lay.slot, inv)
+    np.testing.assert_array_equal(lay.gain.reshape(-1, S)[:G],
+                                  np.where(mask, 2.0 ** y[idx] - 1, 0))
+    plain = ranking.make_topk_layout(ptr, y, 32, exp_gain=False)
+    np.testing.assert_array_equal(plain.gain.reshape(-1, S)[:G],
+                                  np.where(mask, y[idx], 0))
+    np.testing.assert_array_equal(lay.count.reshape(-1)[:G], RAGGED)
+    np.testing.assert_array_equal(lay.start.reshape(-1)[:G], ptr[:-1])
+    assert not lay.count.reshape(-1)[G:].any()
+    for g in range(G):
+        gain = np.sort(2.0 ** y[ptr[g]:ptr[g + 1]] - 1)[::-1]
+        want = max(np.sum(gain / np.log2(2 + np.arange(len(gain)))), 1e-10)
+        assert lay.idcg.reshape(-1)[g] == pytest.approx(want, rel=1e-6)
+    # blocks: many short groups share a block, the grid never far over 2^22
+    many = np.arange(0, 5000 * 40 + 1, 40)
+    wide = ranking.make_topk_layout(many, np.zeros(many[-1], np.float32), 32)
+    n_blocks, gb, S = wide.gain.shape
+    assert S == 40 and gb * 32 * S <= ranking._PAIR_CELLS_A_BLOCK
+    assert 5000 <= n_blocks * gb < 5000 + n_blocks
+
+
+def test_objective_builds_the_grid_once_and_counts_it(monkeypatch):
+    import jax.numpy as jnp
+
+    from xgboost_tpu.objective import create_objective, ranking
+    from xgboost_tpu.telemetry import flight
+    from xgboost_tpu.telemetry.spans import recent
+
+    monkeypatch.setattr(ranking, "_native_lambdarank_ok", lambda: False)
+    built = []
+    real = ranking.make_topk_layout
+    monkeypatch.setattr(ranking, "make_topk_layout",
+                        lambda *a: built.append(a[3]) or real(*a))
+    flight.clear()
+    ptr, y, pred = ragged_case("distinct")
+    labels = jnp.asarray(y)
+    obj = create_objective("rank:ndcg", {})
+    obj.set_group_info(ptr, labels)
+    first = obj.get_gradient(jnp.asarray(pred), labels, None, 0)
+    again = obj.get_gradient(jnp.asarray(pred), labels, None, 1)
+    assert built == [True] and first.shape == (len(y), 1, 2)
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(again))
+    (rec,) = recent("objective.group_layout")
+    S, G = max(RAGGED), len(RAGGED)
+    assert rec["rank.groups"] == G and rec["rank.docs"] == sum(RAGGED)
+    assert rec["rank.slots"] == G * S
+    assert rec["rank.pair_cells"] == G * 32 * S
+    # a caller that gave no labels (or other ones) gets its grid at the
+    # first gradient
+    other = create_objective("rank:ndcg", {})
+    other.set_group_info(ptr)
+    late = other.get_gradient(jnp.asarray(pred), labels, None, 0)
+    np.testing.assert_array_equal(np.asarray(late), np.asarray(first))
+    assert built == [True, True]
+
+
+def test_rank_ndcg_trains_on_the_chips_form(ltr, monkeypatch):
+    """xtb.train through the grid form (the native kernel gated off where
+    the objective asks for it) lands where the CPU's native path lands."""
+    from xgboost_tpu.objective import ranking
+
+    X, y, qid = ltr
+    params = {"objective": "rank:ndcg", "max_depth": 4, "eta": 0.3}
+
+    def margins():
+        d = xtb.DMatrix(X, label=y, qid=qid)
+        bst = xtb.train(params, d, 5, verbose_eval=False)
+        return bst.predict(d, output_margin=True)
+
+    usual = margins()
+    monkeypatch.setattr(ranking, "_native_lambdarank_ok", lambda: False)
+    grid = margins()
+    assert np.isfinite(grid).all()
+    np.testing.assert_allclose(grid, usual, rtol=1e-3, atol=1e-4)

@@ -167,6 +167,33 @@ def test_margin_update_is_scoped(toy):
     assert set(scopes_of_row_sized(hlo)) == {"margin"}
 
 
+def test_ranking_gradient_is_scoped_and_gathers_only_on_the_way_back():
+    """The jitted top-k gradient (objective/ranking.py): every traced
+    operation under ``gradient``; the scores reach the grid as slices, so
+    the only gathers are the grid's way back to row order."""
+    from xgboost_tpu.objective.ranking import (_lambda_gradients_topk,
+                                               make_topk_layout)
+
+    rng = np.random.default_rng(2)
+    sizes = rng.integers(1, 200, 64)
+    sizes[0] = 3 * R  # one group wider than the row-sized threshold
+    ptr = np.concatenate([[0], np.cumsum(sizes)])
+    y = rng.integers(0, 5, ptr[-1]).astype(np.float32)
+    hlo = _lambda_gradients_topk.lower(
+        jnp.zeros(int(ptr[-1]) + 7, jnp.float32), make_topk_layout(ptr, y, 32),
+        k=32, ndcg_weight=True, score_norm=True, group_norm=True
+    ).compile().as_text()
+    # (the compiler's own rewrites of the pair block carry no op_name at all)
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert len(names) > 50
+    assert {xplane.scope_of(n) for n in names if n.startswith("jit(")} == {
+        "gradient"}
+    element = [line for line in hlo.splitlines()
+               if re.search(r" gather\(.*slice_sizes=\{1\}", line)]
+    assert 1 <= len(element) <= 2
+    assert all(f" = f32[{ptr[-1]}" in g for g in element)
+
+
 def test_binning_program_is_scoped(monkeypatch):
     """``_bin`` is a closure of build_ellpack, jitted there: take it as it is
     handed to jax.jit (the CPU's native binning kernel switched off)."""

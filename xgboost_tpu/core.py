@@ -465,7 +465,7 @@ class Booster:
             if gp is None:
                 gp = np.array([0, dtrain.num_row()], np.int64)
             if getattr(self.objective, "_gidx_owner", None) != owner:
-                self.objective.set_group_info(gp)
+                self.objective.set_group_info(gp, cache.labels)
                 self.objective._gidx_owner = owner
         if getattr(dtrain, "cat_categories", None):
             cats = {int(k): list(v) for k, v in dtrain.cat_categories.items()}

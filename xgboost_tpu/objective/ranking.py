@@ -2,18 +2,38 @@
 src/objective/lambdarank_obj.cc / .cu, 675+ LoC).
 
 The reference samples ``lambdarank_num_pair_per_sample`` pairs per document
-within each query group (pair_method="mean", the default) or uses top-k pairs.
-Here groups are padded to a (G, S) doc tensor (S = max group size rounded up)
-so ranks, pair sampling, and lambda accumulation are fixed-shape vectorized
+within each query group (pair_method="mean") or uses top-k pairs (the
+default).  Here groups are padded to a (G, S) doc grid (S = the longest
+group) so ranks, pairs and lambda accumulation are fixed-shape vectorized
 ops; the per-group IDCG and rank discounts follow LambdaMARTCalcDeltaNDCG.
+The layout, and what of it the labels fix (the gains on the grid, the
+ideal DCG), is built once in ``set_group_info``; a round computes only what
+the margin moves.
 """
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.spans import span
 from . import ObjFunction, register_objective
+
+# pair cells (group x top-k x slot) of one lax.map block of the top-k
+# gradient: ~2^22 keeps a block's intermediates near 100 MB
+_PAIR_CELLS_A_BLOCK = 1 << 22
+
+
+def _group_slots(group_ptr: np.ndarray):
+    """(group of each row, its place inside the group), rows in CSR order."""
+    group_ptr = np.asarray(group_ptr, np.int64)
+    sizes = np.diff(group_ptr)
+    gid = np.repeat(np.arange(len(sizes), dtype=np.int64), sizes)
+    pos = np.arange(int(group_ptr[-1]), dtype=np.int64) - group_ptr[:-1][gid]
+    return gid, pos
 
 
 def make_group_layout(group_ptr: np.ndarray):
@@ -24,16 +44,60 @@ def make_group_layout(group_ptr: np.ndarray):
     sizes = np.diff(group_ptr)
     G = len(sizes)
     S = int(sizes.max()) if G else 1
-    idx = np.zeros((G, S), dtype=np.int32)
-    mask = np.zeros((G, S), dtype=bool)
-    inv = np.zeros(int(group_ptr[-1]), dtype=np.int32)
-    for g in range(G):
-        n = sizes[g]
-        rows = np.arange(group_ptr[g], group_ptr[g + 1])
-        idx[g, :n] = rows
-        mask[g, :n] = True
-        inv[rows] = g * S + np.arange(n)
-    return idx, mask, inv
+    gid, pos = _group_slots(group_ptr)
+    inv = (gid * S + pos).astype(np.int32)
+    idx = np.zeros(G * S, dtype=np.int32)
+    idx[inv] = np.arange(len(inv), dtype=np.int32)
+    mask = np.zeros(G * S, dtype=bool)
+    mask[inv] = True
+    return idx.reshape(G, S), mask.reshape(G, S), inv
+
+
+class TopkLayout(NamedTuple):
+    """What the top-k gradient reads besides the margin, all fixed by the
+    groups and the labels: groups in ``n_blocks`` blocks of ``gb`` (the last
+    block filled up with empty groups), each group ``S`` slots wide."""
+    start: np.ndarray  # (n_blocks, gb) int32: a group's first row
+    count: np.ndarray  # (n_blocks, gb) int32: its documents
+    gain: np.ndarray   # (n_blocks, gb, S) float32: 2^label - 1 on the grid
+    idcg: np.ndarray   # (n_blocks, gb) float32: ideal DCG of those gains
+    slot: np.ndarray   # (R,) int32: row -> flat slot of the grid
+
+
+def make_topk_layout(group_ptr: np.ndarray, labels: np.ndarray, k: int,
+                     exp_gain: bool = True) -> TopkLayout:
+    """Host, once a training matrix: the grid of ``_lambda_gradients_topk``.
+    No loop over groups: every array is a scatter or a segment sum over the
+    rows.  The gains are made here, in float64, with the ideal DCG that
+    is made of them.  ``exp_gain=False`` (the objectives that
+    weigh every pair alike) puts the labels themselves on the grid: only
+    their order is read."""
+    group_ptr = np.asarray(group_ptr, np.int64)
+    sizes = np.diff(group_ptr)
+    G, R = len(sizes), int(group_ptr[-1])
+    S = max(int(sizes.max()), 1)
+    n_blocks = -(-G // max(1, _PAIR_CELLS_A_BLOCK // (min(k, S) * S)))
+    gb = -(-G // n_blocks)  # the fewest empty groups in the last block
+    Gp = n_blocks * gb
+    gid, pos = _group_slots(group_ptr)
+    slot = gid * S + pos
+    y = np.asarray(labels, np.float64)[:R]
+    gain = np.exp2(y) - 1.0 if exp_gain else y
+    grid = np.zeros(Gp * S, np.float32)
+    grid[slot] = gain
+    # ideal DCG: a group's gains in descending order against the discounts
+    ideal = np.lexsort((-gain, gid))
+    idcg = np.bincount(gid, weights=gain[ideal] / np.log2(2.0 + pos),
+                       minlength=Gp)
+    start = np.zeros(Gp, np.int32)
+    start[:G] = group_ptr[:-1]
+    count = np.zeros(Gp, np.int32)
+    count[:G] = sizes
+    shape = (n_blocks, gb)
+    return TopkLayout(start.reshape(shape), count.reshape(shape),
+                      grid.reshape(shape + (S,)),
+                      np.maximum(idcg, 1e-10).astype(np.float32).reshape(shape),
+                      slot.astype(np.int32))
 
 
 class _LambdaRankBase(ObjFunction):
@@ -62,14 +126,39 @@ class _LambdaRankBase(ObjFunction):
                 "supported yet")
         self.score_norm = str(params.get("lambdarank_score_normalization",
                                          "1")).lower() in ("1", "true")
-        self._layout = None  # set by learner via set_group_info
+        # set by the learner via set_group_info
+        self._group_ptr = self._gptr = self._topk = self._topk_labels = None
 
-    def set_group_info(self, group_ptr: np.ndarray) -> None:
-        idx, mask, inv = make_group_layout(group_ptr)
-        self._gidx = jnp.asarray(idx)
-        self._gmask = jnp.asarray(mask)
-        self._ginv = jnp.asarray(inv)
-        self._gptr = jnp.asarray(np.asarray(group_ptr, np.int32))
+    def set_group_info(self, group_ptr: np.ndarray, labels=None) -> None:
+        """The groups of the training matrix and, where the caller has them
+        (the learner does), its labels: everything of a round's gradient
+        that the margin does not move is built here, once."""
+        self._group_ptr = np.asarray(group_ptr, np.int64)
+        self._gptr = self._topk = self._topk_labels = None
+        if self.pair_method == "mean":
+            idx, mask, inv = make_group_layout(self._group_ptr)
+            self._gidx = jnp.asarray(idx)
+            self._gmask = jnp.asarray(mask)
+            self._ginv = jnp.asarray(inv)
+        elif _native_lambdarank_ok():
+            self._gptr = jnp.asarray(self._group_ptr.astype(np.int32))
+        elif labels is not None:
+            self._bind_labels(labels)
+
+    def _bind_labels(self, labels) -> None:
+        """The top-k grid of these labels; the span's arguments are the
+        layout's counters (docs/observability.md)."""
+        with span("objective.group_layout") as sp:
+            layout = make_topk_layout(self._group_ptr, np.asarray(labels),
+                                      self.num_pair, self._use_ndcg_weight())
+            self._topk = jax.tree.map(jnp.asarray, layout)
+            self._topk_labels = labels
+            slots = int(layout.gain.size)
+            sp.args.update({
+                "rank.groups": len(self._group_ptr) - 1,
+                "rank.docs": int(self._group_ptr[-1]), "rank.slots": slots,
+                "rank.pair_cells": slots * min(self.num_pair,
+                                               layout.gain.shape[-1])})
 
     def default_metric(self):
         return "ndcg"
@@ -78,22 +167,19 @@ class _LambdaRankBase(ObjFunction):
         return True
 
     def get_gradient(self, preds, labels, weights, iteration: int = 0):
-        if self._layout is None and not hasattr(self, "_gidx"):
+        if self._group_ptr is None:
             raise ValueError(f"{self.name} requires group/qid information")
         pred = preds[:, 0] if preds.ndim == 2 else preds
+        flags = dict(k=self.num_pair, ndcg_weight=self._use_ndcg_weight(),
+                     score_norm=self.score_norm, group_norm=self.group_norm)
         if self.pair_method == "topk":
-            if _native_lambdarank_ok():
+            if self._gptr is not None:
                 grad, hess = _lambda_gradients_topk_native(
-                    pred, labels.astype(jnp.float32), self._gptr,
-                    k=self.num_pair, ndcg_weight=self._use_ndcg_weight(),
-                    score_norm=self.score_norm,
-                    group_norm=self.group_norm)
+                    pred, labels.astype(jnp.float32), self._gptr, **flags)
             else:
-                grad, hess = _lambda_gradients_topk(
-                    pred, labels.astype(jnp.float32), self._gidx,
-                    self._gmask, self._ginv, k=self.num_pair,
-                    ndcg_weight=self._use_ndcg_weight(),
-                    score_norm=self.score_norm, group_norm=self.group_norm)
+                if self._topk_labels is not labels:
+                    self._bind_labels(labels)
+                grad, hess = _lambda_gradients_topk(pred, self._topk, **flags)
         else:
             key = jax.random.PRNGKey(iteration)
             grad, hess = _lambda_gradients(
@@ -114,19 +200,12 @@ class _LambdaRankBase(ObjFunction):
         return jnp.stack([grad, hess], axis=-1)[:, None, :].astype(jnp.float32)
 
 
-import functools
-
-
 def _native_lambdarank_ok() -> bool:
-    """CPU gate for the native CSR-group top-k pair pass — the padded
-    (G, k, S) pair tensors below cost hundreds of MB of masked
+    """The native CSR-group top-k pair pass is the CPU backend's path — the
+    padded (G, k, S) pair tensors below cost hundreds of MB of masked
     intermediates per round that the sequential kernel never materializes
     (~4x at MSLR shapes).  Same per-host agreement story as the other
     kernels (utils/native.py)."""
-    import os
-
-    if os.environ.get("XTB_NO_NATIVE_LAMBDARANK", ""):
-        return False
     if jax.default_backend() != "cpu":
         return False
     from ..utils import native
@@ -143,8 +222,6 @@ def _lambda_gradients_topk_native(pred, y, gptr, *, k: int,
     _lambda_gradients_topk (same sort order incl. stable ties, pair set,
     LambdaGrad weights, group normalization); gradients agree to f32
     tolerance (tests/test_native_parity.py pins it)."""
-    import numpy as np
-
     from ..utils import native
 
     native.ensure_pool()
@@ -161,7 +238,7 @@ def _lambda_gradients_topk_native(pred, y, gptr, *, k: int,
 
 @functools.partial(jax.jit, static_argnames=("k", "ndcg_weight", "score_norm",
                                              "group_norm"))
-def _lambda_gradients_topk(pred, y, gidx, gmask, ginv, *, k: int,
+def _lambda_gradients_topk(pred, layout: TopkLayout, *, k: int,
                            ndcg_weight: bool, score_norm: bool,
                            group_norm: bool):
     """Top-k LambdaMART gradients, the reference's DEFAULT pair method
@@ -173,62 +250,61 @@ def _lambda_gradients_topk(pred, y, gidx, gmask, ginv, *, k: int,
     hessian doubled; per-group log2(1+sum_lambda)/sum_lambda rescale
     (lambdarank_normalization, lambdarank_obj.cc:227).
 
-    Memory: pairs form a (g_block, k, S) tensor; groups are processed in
-    blocks via lax.map so MSLR-scale G never materializes G*k*S at once.
+    One program, every device operation under scope ``gradient``.  A round
+    brings only the margin: a group's scores are one contiguous slice of it
+    (rows are in group order), the gains on the grid and the ideal DCG come
+    with ``layout``.  One stable sort carries the gains and the slots along
+    with the scores, a second one on the slots brings the pair back to grid
+    order: no ``take_along_axis``, and the only per-element gather is the
+    grid's way back to row order.
+
+    Memory: pairs form a (gb, k, S) tensor; groups are processed in blocks
+    via lax.map so MSLR-scale G never materializes G*k*S at once.
     """
+    with jax.named_scope("gradient"):
+        return _topk_grid(pred, layout, k, ndcg_weight, score_norm, group_norm)
+
+
+def _topk_grid(pred, layout, k, ndcg_weight, score_norm, group_norm):
     R = pred.shape[0]
-    G, S = gidx.shape
+    n_blocks, gb, S = layout.gain.shape
     kk = min(k, S)
-    # block size: ~2^22 pair cells per block keeps peak memory ~100MB
-    gb = max(1, min(G, (1 << 22) // max(kk * S, 1)))
-    n_blocks = (G + gb - 1) // gb
-    Gp = n_blocks * gb
-    pad_g = Gp - G
-
-    s_all = jnp.where(gmask, pred[gidx], -jnp.inf)
-    rel_all = y[gidx] * gmask
-    if pad_g:
-        s_all = jnp.concatenate(
-            [s_all, jnp.full((pad_g, S), -jnp.inf, s_all.dtype)])
-        rel_all = jnp.concatenate([rel_all, jnp.zeros((pad_g, S))])
-        mask_all = jnp.concatenate([gmask, jnp.zeros((pad_g, S), bool)])
-    else:
-        mask_all = gmask
-
+    # a slice of S rows from any group's first row stays inside the array
+    pred_ext = jnp.concatenate([pred.astype(jnp.float32),
+                                jnp.zeros(S, jnp.float32)])
     irange = jnp.arange(kk, dtype=jnp.int32)
     jrange = jnp.arange(S, dtype=jnp.int32)
-    # rank discounts by sorted position: rank = pos + 1 -> 1/log2(1 + rank)
-    disc_i = 1.0 / jnp.log2(2.0 + irange.astype(jnp.float32))
-    disc_j = 1.0 / jnp.log2(2.0 + jrange.astype(jnp.float32))
+    # rank discounts by sorted position: rank = pos + 1 -> 1/log2(1 + rank);
+    # constants of the program, made as the layout's ideal DCG is made
+    disc_j = jnp.asarray((1.0 / np.log2(2.0 + np.arange(S))).astype(np.float32))
+    disc_i = disc_j[:kk]
 
     def block(args):
-        s, rel, mask = args  # (gb, S)
-        order = jnp.argsort(-s, axis=1)  # stable; -inf padding sorts last
-        inv_order = jnp.argsort(order, axis=1)
-        s_srt = jnp.take_along_axis(s, order, axis=1)
-        rel_srt = jnp.take_along_axis(rel, order, axis=1)
-        m_srt = jnp.take_along_axis(mask, order, axis=1)
-        cnt = jnp.sum(mask, axis=1).astype(jnp.int32)  # (gb,)
-
-        gain_srt = (2.0 ** rel_srt - 1.0) * m_srt
-        ideal = jnp.sort(gain_srt, axis=1)[:, ::-1]
-        idcg = jnp.maximum(jnp.sum(ideal * disc_j[None, :], axis=1), 1e-10)
+        start, count, gain, idcg = args  # (gb,), (gb,), (gb, S), (gb,)
+        s = jax.vmap(lambda at: jax.lax.dynamic_slice(pred_ext, (at,), (S,))
+                     )(start)
+        mask = jrange[None, :] < count[:, None]
+        # stable and descending; padding sorts last, so the mask is its own
+        # sorted form
+        key_srt, gain_srt, order = jax.lax.sort(
+            (jnp.where(mask, -s, jnp.inf), gain,
+             jnp.broadcast_to(jrange, (gb, S))),
+            dimension=1, is_stable=True, num_keys=1)
+        s_srt = -key_srt
 
         si = s_srt[:, :kk][:, :, None]           # (gb, k, 1)
         sj = s_srt[:, None, :]                   # (gb, 1, S)
-        reli = rel_srt[:, :kk][:, :, None]
-        relj = rel_srt[:, None, :]
-        valid = (m_srt[:, :kk][:, :, None] & m_srt[:, None, :]
+        gi = gain_srt[:, :kk][:, :, None]        # a gain orders as its label
+        gj = gain_srt[:, None, :]
+        valid = (mask[:, :kk][:, :, None] & mask[:, None, :]
                  & (jrange[None, None, :] > irange[None, :, None])
-                 & (reli != relj))
-        high_is_i = reli > relj
+                 & (gi != gj))
+        high_is_i = gi > gj
         s_high = jnp.where(high_is_i, si, sj)
         s_low = jnp.where(high_is_i, sj, si)
         sig = jax.nn.sigmoid(s_high - s_low)
 
         if ndcg_weight:
-            gi = gain_srt[:, :kk][:, :, None]
-            gj = gain_srt[:, None, :]
             delta = jnp.abs((gi - gj)
                             * (disc_i[None, :, None] - disc_j[None, None, :])
                             ) / idcg[:, None, None]
@@ -237,9 +313,8 @@ def _lambda_gradients_topk(pred, y, gidx, gmask, ginv, *, k: int,
         if score_norm:
             # LambdaGrad norm_by_diff: skip when all scores equal (first
             # iteration) — best == worst per group
-            best = s_srt[:, 0]
-            worst = jnp.take_along_axis(
-                s_srt, jnp.maximum(cnt - 1, 0)[:, None], axis=1)[:, 0]
+            best = jnp.max(jnp.where(mask, s, -jnp.inf), axis=1)
+            worst = jnp.min(jnp.where(mask, s, jnp.inf), axis=1)
             spread = (best != worst)[:, None, None]
             delta = jnp.where(spread,
                               delta / (jnp.abs(s_high - s_low) + 0.01),
@@ -265,19 +340,17 @@ def _lambda_gradients_topk(pred, y, gidx, gmask, ginv, *, k: int,
             grad_srt = grad_srt * norm[:, None]
             hess_srt = hess_srt * norm[:, None]
 
-        grad_blk = jnp.take_along_axis(grad_srt, inv_order, axis=1)
-        hess_blk = jnp.take_along_axis(hess_srt, inv_order, axis=1)
+        _, grad_blk, hess_blk = jax.lax.sort(
+            (order, grad_srt, hess_srt), dimension=1, num_keys=1)
         return grad_blk, hess_blk
 
-    s_b = s_all.reshape(n_blocks, gb, S)
-    rel_b = rel_all.reshape(n_blocks, gb, S)
-    m_b = mask_all.reshape(n_blocks, gb, S)
-    grad_g, hess_g = jax.lax.map(block, (s_b, rel_b, m_b))
-    grad_g = grad_g.reshape(Gp, S)[:G].astype(jnp.float32)
-    hess_g = hess_g.reshape(Gp, S)[:G].astype(jnp.float32)
-    grad = jnp.pad(grad_g.reshape(-1)[ginv], (0, R - ginv.shape[0]))
-    hess = jnp.pad(hess_g.reshape(-1)[ginv], (0, R - ginv.shape[0]))
-    return grad, hess
+    grad_g, hess_g = jax.lax.map(
+        block, (layout.start, layout.count, layout.gain, layout.idcg))
+    # rows back from the grid: each owns exactly one slot, so a gather; the
+    # padded tail of the margin (R - len(slot) rows) stays zero
+    tail = (0, R - layout.slot.shape[0])
+    return (jnp.pad(grad_g.reshape(-1)[layout.slot], tail),
+            jnp.pad(hess_g.reshape(-1)[layout.slot], tail))
 
 
 @functools.partial(jax.jit, static_argnames=("num_pair", "ndcg_weight",
