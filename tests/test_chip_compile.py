@@ -224,3 +224,40 @@ def test_ranking_gradient_compiles_for_v5e(one_chip):
     assert len(gathers) == 2 and all(g.startswith(f"f32[{ptr[-1]}]")
                                      for g in gathers)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
+def test_hist_onehot_is_built_inside_the_matmul_for_v5e(one_chip,
+                                                        device_paths):
+    """The structure PR 29 rests on, at the ranking cell's width (136
+    columns, 16 built nodes): the one-hot is written feature-major, so the
+    chip's compiler makes the broadcast, the iota and the ``==`` producers
+    inside the convolution's fusion.  Row-major it cut them out as arrays of
+    their own, `s32[2048,136,256]` (285 MB a chunk: the program's whole temp
+    size then) and `pred[2048,34816]`, written and read back a chunk a level."""
+    import re
+
+    from xgboost_tpu.ops.histogram import build_histogram_at
+
+    def build(*args):  # a fresh function: a fresh trace under device_paths
+        return build_histogram_at.__wrapped__(*args, n_nodes=16, n_bin=B,
+                                              stride=2)
+
+    F_RANK, T = 136, 2048
+    compiled = jax.jit(build).lower(
+        _shape((ROWS, F_RANK), jnp.int16, one_chip),
+        _shape((ROWS, 2), jnp.float32, one_chip),
+        _shape((ROWS,), jnp.int32, one_chip),
+        _shape((), jnp.int32, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < T * F_RANK * B
+    onehot = re.compile(
+        r" = \w+\[(%d,%d,%d|%d,%d|%d,%d,%d|%d,%d)[\],]" % (
+            T, F_RANK, B, T, F_RANK * B, F_RANK, B, T, F_RANK * B, T))
+    text = compiled.as_text()
+    assert "convolution(" in text
+    computation, stored = None, []
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            computation = line.split()[0]
+        elif onehot.search(line) and "fused_computation" not in computation:
+            stored.append(line.strip()[:120])
+    assert not stored, stored

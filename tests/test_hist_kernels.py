@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from xgboost_tpu.ops.histogram import build_histogram, node_sums
@@ -244,4 +245,98 @@ def test_pallas_quantised_bench_shape_tiles_interpret():
         interpret=True, row_tile=T, feat_group=FG)
     want = hist_accumulate_q(bins, gq, pos, jnp.int32(node0), n_nodes, B,
                              stride=2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _chunk_case(F, n_bin, dtype, R, n_nodes, node0, stride, seed):
+    """A page with missing-sentinel bins (== n_bin) and rows above, below
+    and between the built nodes; gpair in eighths, so float32 sums are exact
+    in any order and the comparison below can ask for equality."""
+    rng = np.random.default_rng(seed)
+    bins = rng.integers(0, n_bin + 1, size=(R, F)).astype(dtype)
+    gpair = (rng.integers(-64, 65, size=(R, 2)) / 8.0).astype(np.float32)
+    pos = rng.integers(node0 - 2, node0 + stride * n_nodes + 2,
+                       size=R).astype(np.int32)
+    return jnp.asarray(bins), jnp.asarray(gpair), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("page", ["int16", "uint8"])
+@pytest.mark.parametrize("node0_kind", ["static", "traced"])
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("F", [1, 28, 136])
+def test_feature_major_chunk_matches_scatter(monkeypatch, F, stride,
+                                             node0_kind, page):
+    """The chip's histogram (`_hist_chunk`, one-hot written feature-major as
+    (F*B, T)) against the scatter driver at the cells' widths: 256 bins and
+    the missing sentinel on an int16 page, 255 and the sentinel on a uint8
+    one, chunk 0 outside the scan, a scanned chunk, and the ``rem`` tail."""
+    from xgboost_tpu.ops.histogram import _hist_accumulate, scatter_hist_driver
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    n_bin, dtype = {"int16": (256, np.int16), "uint8": (255, np.uint8)}[page]
+    chunk, N, node0 = 256, 3, 7
+    bins, gpair, pos = _chunk_case(F, n_bin, dtype, 2 * chunk + 77, N, node0,
+                                   stride, seed=F + stride)
+    if node0_kind == "static":
+        got = jax.jit(lambda b, g, p: _hist_accumulate(
+            b, g, p, node0, N, n_bin, chunk, stride))(bins, gpair, pos)
+    else:
+        got = jax.jit(lambda b, g, p, n0: _hist_accumulate(
+            b, g, p, n0, N, n_bin, chunk, stride))(bins, gpair, pos,
+                                                   jnp.int32(node0))
+    want = scatter_hist_driver(bins, gpair, pos, node0, N, n_bin, stride, 2,
+                               jnp.float32)
+    assert got.shape == (N, F, n_bin, 2)
+    assert float(jnp.abs(want).sum()) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_feature_major_chunk_under_vmap(monkeypatch):
+    """`build_histogram_multi`: K lock-step trees over one page, the chunk
+    body batched over gradient and positions and not over the bins."""
+    from xgboost_tpu.ops.histogram import (build_histogram_multi,
+                                           scatter_hist_driver)
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    K, F, B, N, node0 = 3, 5, 256, 2, 3
+    R = 2 * 2048 + 100
+    bins, gpair, pos = _chunk_case(F, B, np.int16, R, N, node0, 2, seed=1)
+    rng = np.random.default_rng(2)
+    gpair_rkc = jnp.stack([gpair * (k + 1) for k in range(K)], axis=1)
+    pos_k = jnp.stack([jnp.asarray(rng.permutation(np.asarray(pos)))
+                       for _ in range(K)])
+    got = jax.jit(lambda *a: build_histogram_multi.__wrapped__(
+        *a, n_nodes=N, n_bin=B, stride=2))(bins, gpair_rkc, pos_k,
+                                            jnp.int32(node0))
+    assert got.shape == (K, N, F, B, 2)
+    for k in range(K):
+        want = scatter_hist_driver(bins, gpair_rkc[:, k], pos_k[k], node0, N,
+                                   B, 2, 2, jnp.float32)
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want))
+
+
+def test_feature_major_chunk_under_shard_map(monkeypatch):
+    """The scan's carry is seeded with chunk 0, so that under `shard_map` it
+    enters with the varying type it leaves with (parallel/grower.py)."""
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    from xgboost_tpu.ops.histogram import _hist_accumulate, scatter_hist_driver
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    F, B, N, node0, chunk = 4, 256, 2, 1, 256
+    bins, gpair, pos = _chunk_case(F, B, np.int16, 3 * chunk + 50, N, node0,
+                                   1, seed=4)
+
+    def local(b, g, p):
+        return jax.lax.psum(
+            _hist_accumulate(b, g, p, node0, N, B, chunk, 1), "data")
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    got = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("data", None), P("data", None),
+                                    P("data")), out_specs=P()))(
+        bins, gpair, pos)
+    want = scatter_hist_driver(bins, gpair, pos, node0, N, B, 1, 2,
+                               jnp.float32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

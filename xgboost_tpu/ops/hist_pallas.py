@@ -2,11 +2,11 @@
 
 The CUDA reference builds histograms with shared-memory atomics
 (src/tree/gpu_hist/histogram.cu:37-120).  TPU has no atomics; the masked
-one-hot matmul formulation (ops/histogram.py) is MXU-shaped, but the plain XLA
-lowering materializes the (rows, F*B) one-hot operand in HBM — hundreds of GB
-of traffic per level at HIGGS scale.  This kernel fuses one-hot construction
-into VMEM so HBM sees only: bins read once (R*F*itemsize bytes), the gradient
-operand read once per feature group, histogram written once.
+one-hot matmul formulation (ops/histogram.py) is MXU-shaped, and XLA keeps the
+one-hot operand out of HBM only in the feature-major form written there (what
+the chip runs by default).  This kernel builds the one-hot in VMEM by hand, so
+HBM sees only: bins read once (R*F*itemsize bytes), the gradient operand read
+once per feature group, histogram written once.
 
 Layout — rows ride the 128-lane axis everywhere, so every block is
 lane-dense and no value is ever sliced off the lane axis:

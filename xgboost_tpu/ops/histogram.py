@@ -8,17 +8,31 @@ Instead we reformulate as a **masked one-hot matmul** that runs on the MXU:
 
     hist[n, f, b, c] = sum_r  onehot(bins[r,f], b) * (pos[r] == node(n)) * gpair[r, c]
 
-i.e. ``A.T @ G`` with ``A = onehot(bins)`` of shape (rows, F*B) and
+i.e. ``A @ G`` with ``A = onehot(bins)`` of shape (F*B, rows) and
 ``G[r, n*2+c] = gpair[r,c] * nodemask[r,n]`` of shape (rows, 2N).  No row
 sorting, no scatter, no atomics; per-row node membership lives in a ``pos``
 array updated elementwise each level (the analogue of RowPartitioner positions,
 src/tree/gpu_hist/row_partitioner.cuh:255, without the physical partition).
 
-Two implementations:
- - ``build_histogram``: chunked XLA einsum (reference path, works everywhere);
- - ``build_histogram_pallas`` (ops/hist_pallas.py): fuses one-hot construction
-   into VMEM so the (rows, F*B) operand never touches HBM — the production
-   TPU kernel.
+What runs on the chip is ``build_histogram`` (the root) and
+``build_histogram_at`` (every later level): the matmul in float32 at
+``HIGHEST`` under ``lax.scan`` over 2,048-row chunks, compiled by XLA.  The
+one-hot is written **feature-major**, ``(F, B, T)`` from the transposed chunk
+and contracted as ``(F*B, T) @ (T, 2N)``, because XLA:TPU lays a chunk out
+with its rows in the lanes (``s16[n,2048,F]{1,2,0}``): rows then stay the
+minor dimension from the page to the matmul's operand, ``bins_c.T`` is a
+bitcast, and the broadcast, the iota and the ``==`` become producers inside
+the convolution's fusion.  Row-major, ``(T, F, B)`` reshaped to ``(T, F*B)``
+and transposed, merges F and B under a minor dimension that has already moved,
+and the compiler then stores an ``s32[2048,F,256]`` broadcast (285 MB at 136
+columns, through HBM once a chunk a level) and a ``pred[2048,F*256]`` compare
+as arrays of their own: 74% of a round at 136 columns, 35% at 28 (PERF.md §6,
+PR 29).  tests/test_chip_compile.py holds the fused form in place.
+
+``build_histogram_pallas`` (ops/hist_pallas.py) builds the one-hot in VMEM by
+hand; it is opt-in (``hist_impl="pallas"``), keeps per-depth programs, and no
+benchmark cell runs it.  On the CPU backend neither matmul runs by default:
+the native row-pass kernel or the XLA scatter does (``_host_impl``).
 
 Determinism: float32 accumulation in a fixed sequential chunk order — within
 one topology, the role played by fixed-point gradient quantisation in the
@@ -43,20 +57,30 @@ from jax import lax
 _EXACT_F32 = lax.Precision.HIGHEST
 
 
+def _onehot_feature_major(bins_c, n_bin: int, dtype):
+    """(T, F) bins -> the (F*B, T) 0/1 operand of a chunk's matmul, float32 or
+    int8 (ops/quantise.py).  Rows stay the minor dimension, as the chunk is
+    laid out on the chip, so the compare is a producer inside the matmul's
+    fusion and the one-hot is never stored (module docstring).  The missing
+    sentinel (bin == n_bin) compares false everywhere."""
+    T, F = bins_c.shape
+    onehot = (bins_c.T.astype(jnp.int32)[:, None, :]
+              == jnp.arange(n_bin, dtype=jnp.int32)[None, :, None])  # (F, B, T)
+    return onehot.astype(dtype).reshape(F * n_bin, T)
+
+
 def _hist_chunk(bins_c, gpair_c, pos_c, node0: int, n_nodes: int, n_bin: int,
                 stride: int = 1):
     """One row-chunk's contribution: (T,F) bins -> (N,F,B,C) partial histogram."""
     T, F = bins_c.shape
     C = gpair_c.shape[1]
-    onehot = (bins_c.astype(jnp.int32)[:, :, None] == jnp.arange(n_bin, dtype=jnp.int32)).astype(
-        jnp.float32
-    )  # (T, F, B); missing sentinel B compares false everywhere
+    onehot = _onehot_feature_major(bins_c, n_bin, jnp.float32)
     nodemask = (
         pos_c[:, None] == (node0 + stride * jnp.arange(n_nodes, dtype=pos_c.dtype))
     ).astype(jnp.float32)  # (T, N)
     gm = (nodemask[:, :, None] * gpair_c[:, None, :]).reshape(T, n_nodes * C)
     out = jnp.dot(
-        onehot.reshape(T, F * n_bin).T, gm, preferred_element_type=jnp.float32,
+        onehot, gm, preferred_element_type=jnp.float32,
         precision=_EXACT_F32,
     )  # (F*B, N*C)
     return out.reshape(F, n_bin, n_nodes, C).transpose(2, 0, 1, 3)

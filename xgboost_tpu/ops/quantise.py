@@ -82,18 +82,18 @@ def _hist_chunk_q(bins_c, gq_c, pos_c, node0, n_nodes: int, n_bin: int,
     but in int8 operands with int32 accumulation — exact, and on TPU the
     MXU's int8 path, so determinism costs no matmul throughput.
     """
+    from .histogram import _onehot_feature_major
+
     T, F = bins_c.shape
     C, L = gq_c.shape[1], gq_c.shape[2]
-    onehot = (bins_c.astype(jnp.int32)[:, :, None]
-              == jnp.arange(n_bin, dtype=jnp.int32)).astype(jnp.int8)
+    onehot = _onehot_feature_major(bins_c, n_bin, jnp.int8)
     nodemask = (pos_c[:, None]
                 == (node0 + stride * jnp.arange(n_nodes, dtype=pos_c.dtype))
                 ).astype(jnp.int8)  # (T, N)
     # (T, N*C*L) — int8 product of a 0/1 mask and a limb is the limb
     gm = (nodemask[:, :, None] * gq_c.reshape(T, 1, C * L)).reshape(
         T, n_nodes * C * L)
-    out = jnp.dot(onehot.reshape(T, F * n_bin).T, gm,
-                  preferred_element_type=jnp.int32)
+    out = jnp.dot(onehot, gm, preferred_element_type=jnp.int32)
     return out.reshape(F, n_bin, n_nodes, C, L).transpose(2, 0, 1, 3, 4)
 
 

@@ -466,10 +466,16 @@ def level_step_padded(
     a TRACED ``node0`` — ONE compiled program serves every interior depth
     (VERDICT r3 #4: the per-depth compile wall).
 
-    ``width`` = 2**(max_depth-1), the widest interior level.  Padding is
-    cheap by design: the histogram one-hot matmul cost is flat in the node
-    count on CPU (operand materialization dominates) and the extra output
-    columns ride the same MXU tile on TPU (2*width <= 128 for depth <= 7).
+    ``width`` = 2**(max_depth-1), the widest interior level.  What the
+    padding costs on the chip: every depth builds ``width // 2`` left
+    children (ops/histogram.py: one-hot matmul at HIGHEST, the one-hot built
+    feature-major inside the matmul's fusion), and that fusion is nearly all
+    of a level's time.  It is almost flat from one built node to sixteen
+    (0.154 and 0.168 s a level at 10.5M x 28) and twice that at sixty-four
+    (0.328 s, depth 8: PERF.md §5), so up to depth 6 the padding is nearly
+    free and beyond it a shallow level pays the widest level's matmul.  On
+    the CPU the row-pass kernels add only where a row's node matches, and
+    the padding costs the wider output block alone.
 
     Correctness of the padding (garbage level offsets j >= 2**depth):
     - their heap slots overlay only DEEPER levels' ids, whose real writes
