@@ -41,7 +41,7 @@ MAX_DEPTH = 6
 # magnitudes; one bfloat16 pass rounds every term to 2**-9 of itself, which a
 # bin that holds a single row shows undiluted
 F32_LEVEL = 1e-4
-AUC_FLOOR = 0.75  # bench.py's "the model must actually learn"
+AUC_FLOOR = 0.75  # the model must actually learn
 # float32 sums of leaf values in another order, and the chip's own exp: the
 # first run on a v5e put predict 1.1e-06 from numpy's sigmoid of the same walk
 PRED_TOL = 1e-5
@@ -120,7 +120,7 @@ def phase_native(clock: Clock) -> None:
 
 
 def make_data(rows: int, seed: int):
-    from bench import make_data as higgs_like  # the shape of record's maker
+    from benchmarks.data import higgs_like  # the HIGGS cells' maker
 
     return higgs_like(rows, N_FEATURES, seed)
 
@@ -137,7 +137,7 @@ def sample_rows(X, seed: int) -> np.ndarray:
 
 
 def sample_auc(bst, X, y) -> float:
-    """bench.py's sanity check: AUC on a 200k-row sample."""
+    """Sanity check: AUC on a 200k-row sample."""
     import xgboost_tpu as xtb
     from xgboost_tpu.metric import auc
 

@@ -15,6 +15,7 @@ for the real number; results go into docs/bitpack.md.
 import functools
 import json
 import sys
+import time
 
 import jax
 
@@ -25,12 +26,24 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
-from bench import _median_time as timed  # noqa: E402 — shared timing helper
 from xgboost_tpu.ops.histogram import _hist_accumulate  # noqa: E402
 from xgboost_tpu.ops.histogram import build_histogram  # noqa: E402
 
 R, F = 1 << 20, 28
 N_NODES = 8
+
+
+def timed(fn, reps: int = 5) -> float:
+    """Median wall seconds of fn() with device completion; one warmup call
+    first so compile time never lands in the samples."""
+    jax.block_until_ready(fn())  # compile/warmup
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        out = fn()
+        jax.block_until_ready(out)
+        ts.append(time.perf_counter() - t0)
+    return sorted(ts)[len(ts) // 2]
 
 
 def _unpack4(packed):

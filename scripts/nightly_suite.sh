@@ -231,6 +231,6 @@ JAX_PLATFORMS=cpu python scripts/lifecycle_smoke.py 2 60
 # replayed from the same seed must retrain the bitwise-identical model
 JAX_PLATFORMS=cpu python scripts/online_smoke.py 2
 
-# the chip smoke, rehearsed on the CPU (bench.py and the smoke proper need
-# the chip and fail without it; the rehearsal's last line says "ok": false)
+# the chip smoke, rehearsed on the CPU (the smoke proper needs the chip and
+# fails without it; the rehearsal's last line says "ok": false)
 JAX_PLATFORMS=cpu python chip_smoke.py --rows 100000 --allow-cpu

@@ -144,7 +144,7 @@ def test_root_level_program_compiles_for_v5e(one_chip, device_paths):
     def root(*args):  # a fresh function, so a fresh trace under device_paths
         return level_step.__wrapped__(
             *args, None, None, depth=0, params=_split_params(),
-            last_level=False, hist_impl="xla", subtract=False)
+            last_level=False, subtract=False)
 
     compiled = jax.jit(root).lower(*_level_args(one_chip, one_chip)).compile()
     text = compiled.as_text()
@@ -162,8 +162,7 @@ def test_padded_level_program_compiles_for_v5e(one_chip, device_paths):
 
     def interior(*args):
         return level_step_padded.__wrapped__(
-            *args, width=W, params=_split_params(), hist_impl="xla",
-            subtract=True)
+            *args, width=W, params=_split_params(), subtract=True)
 
     args = _level_args(one_chip, one_chip) + (
         _shape((W, F, B, 2), jnp.float32, one_chip),  # hist_prev

@@ -1,4 +1,4 @@
-"""chip_smoke.py and bench.py without a chip, and the ``device`` parameter.
+"""chip_smoke.py without a chip, and the ``device`` parameter.
 
 The smoke is the proof that the system starts on the chip; here, on the CPU,
 it can only be shown to run to its end as a rehearsal (``--allow-cpu``, last
@@ -58,7 +58,6 @@ def test_smoke_four_chip_option_runs_the_sharded_phase_alone():
 @pytest.mark.parametrize("script, args", [
     ("chip_smoke.py", ()),
     ("chip_smoke.py", ("--chips", "4")),
-    ("bench.py", ()),
 ])
 def test_no_chip_no_result(script, args):
     """With no TPU the run ends at its device line: non-zero, no phase after

@@ -127,10 +127,13 @@ def test_leaf_positions_match_rows():
     np.testing.assert_allclose(delta_dev, delta_ref, rtol=1e-2, atol=5e-4)
 
 
-def test_padded_levels_parity_deep():
+@pytest.mark.parametrize("max_depth", [1, 2, 7])
+def test_padded_levels_parity_deep(max_depth):
     """The shared padded interior program (compile-wall fix) must grow
     identical trees to per-depth programs at depth > 5 — on CPU the default
-    flips to per-depth for speed, so pin the padded path explicitly."""
+    flips to per-depth for speed, so pin the padded path explicitly.  The
+    edges of the one depth-wise loop: no interior level at depth 1, one of
+    width 2 at depth 2 (both rules' width, two programs)."""
     import hashlib
 
     import xgboost_tpu as xtb
@@ -156,8 +159,8 @@ def test_padded_levels_parity_deep():
 
     args = (bins, gp, valid, jnp.asarray(ell.cuts_pad),
             jnp.asarray(ell.n_bins))
-    t_pad = HistTreeGrower(7, params, padded_levels=True).grow(*args)
-    t_per = HistTreeGrower(7, params, padded_levels=False).grow(*args)
+    t_pad = HistTreeGrower(max_depth, params, padded_levels=True).grow(*args)
+    t_per = HistTreeGrower(max_depth, params, padded_levels=False).grow(*args)
     for name in ("feat", "sbin", "thr", "leaf_val", "is_leaf"):
         np.testing.assert_array_equal(np.asarray(getattr(t_pad, name)),
                                       np.asarray(getattr(t_per, name)),

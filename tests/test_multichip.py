@@ -31,7 +31,13 @@ def problem():
     return ell, jnp.asarray(gp), jnp.asarray(valid)
 
 
-def test_sharded_tree_identical_to_single(problem, eight_devices):
+@pytest.mark.parametrize("shared_width", [True, False])
+def test_sharded_tree_identical_to_single(problem, eight_devices, monkeypatch,
+                                          shared_width):
+    """Under both width rules of the one depth-wise loop the mesh grower
+    inherits: one padded interior program, and a program a depth."""
+    monkeypatch.setattr("xgboost_tpu.tree.grow.default_padded_levels",
+                        lambda max_depth: shared_width)
     ell, gp, valid = problem
     params = SplitParams(0.3, 0.0, 1.0, 1.0, 0.0, 0.0)
 

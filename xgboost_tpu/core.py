@@ -1363,8 +1363,7 @@ class Booster:
         det = self.deterministic_histogram
         gkey = (max_depth, id(mesh), self._split_params,
                 self.tparam.interaction_constraints, self.tparam.max_leaves,
-                lossguide, str(self.params.get("_hist_impl", "xla")), proc_par,
-                best_first, det)
+                lossguide, proc_par, best_first, det)
         if not hasattr(self, "_grower_cache"):
             self._grower_cache = {}
         grower = self._grower_cache.get(gkey)
@@ -1414,7 +1413,6 @@ class Booster:
                     max_depth,
                     self._split_params,
                     mesh,
-                    hist_impl=str(self.params.get("_hist_impl", "xla")),
                     interaction_sets=self.tparam.interaction_constraints,
                     max_leaves=self.tparam.max_leaves,
                     lossguide=lossguide,
@@ -1424,7 +1422,6 @@ class Booster:
                 grower = HistTreeGrower(
                     max_depth,
                     self._split_params,
-                    hist_impl=str(self.params.get("_hist_impl", "xla")),
                     interaction_sets=self.tparam.interaction_constraints,
                     max_leaves=self.tparam.max_leaves,
                     lossguide=lossguide,
@@ -1511,7 +1508,6 @@ class Booster:
         lockstep_ok = (
             K > 1 and mesh is None and not proc_par and not best_first
             and not det and cat_mask_np is None and not adaptive
-            and str(self.params.get("_hist_impl", "xla")) == "xla"
             and str(self.params.get("_lockstep", "0")).lower()
             in ("1", "true"))
         for p_idx in range(max(self.num_parallel_tree, 1)):

@@ -89,7 +89,7 @@ def choose_tiles(n_features: int, n_bin: int, n_nodes: int,
 
 def _resolve_interpret(interpret):
     """``None`` = compile for the chip on TPU, interpret on CPU (so the
-    hist_impl="pallas" grower path works, slowly, in CPU tests).  Never
+    kernels' tests run, slowly, without a chip).  Never
     interpret mode on a chip, never a guess on any other platform."""
     if interpret is not None:
         return interpret

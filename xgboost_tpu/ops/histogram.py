@@ -29,10 +29,16 @@ columns, through HBM once a chunk a level) and a ``pred[2048,F*256]`` compare
 as arrays of their own: 74% of a round at 136 columns, 35% at 28 (PERF.md §6,
 PR 29).  tests/test_chip_compile.py holds the fused form in place.
 
-``build_histogram_pallas`` (ops/hist_pallas.py) builds the one-hot in VMEM by
-hand; it is opt-in (``hist_impl="pallas"``), keeps per-depth programs, and no
-benchmark cell runs it.  On the CPU backend neither matmul runs by default:
-the native row-pass kernel or the XLA scatter does (``_host_impl``).
+Which of these a level gets is decided here and nowhere else:
+``level_histogram`` is what the level body (tree/grow.py) and the page step
+(tree/stream.py) call.  It picks float32 or int8-limb sums by ``quantised``,
+the static or the traced entry point by what ``node0`` is, and the one-hot
+matmul, the XLA scatter or the native row-pass kernel by ``_host_impl``: on
+the CPU backend neither matmul runs by default.  The fused Pallas kernels
+(ops/hist_pallas.py: the one-hot built in VMEM by hand) are compiled and
+pinned by their tests and have no caller in the library; the PR that puts
+them in a round wires them behind ``level_histogram``, chosen from what the
+code observes (ROADMAP D1).
 
 Determinism: float32 accumulation in a fixed sequential chunk order — within
 one topology, the role played by fixed-point gradient quantisation in the
@@ -114,12 +120,6 @@ def hist_impl_override():
     return v if v in ("matmul", "scatter", "native") else None
 
 
-def _native_hist_available() -> bool:
-    from ..utils import native
-
-    return native.ffi_usable()
-
-
 def _host_impl():
     """Implementation for the CPU backend: the native C++ row-pass kernel
     (native/xtb_kernels.h via an XLA FFI custom call, ~5-10x the XLA
@@ -140,10 +140,15 @@ def _host_impl():
         return forced
     if jax.default_backend() != "cpu":
         return "matmul"
-    return "native" if _native_hist_available() else "scatter"
+    from ..utils import native
+
+    return "native" if native.ffi_usable() else "scatter"
 
 
-def _use_scatter() -> bool:
+def hist_is_row_pass() -> bool:
+    """Whether a level's histogram is a pass over the rows that adds where
+    a row's node matches (the native kernel, the XLA scatter) and not the
+    dense one-hot matmul: what the route and the shared width follow."""
     return _host_impl() in ("scatter", "native")
 
 
@@ -282,6 +287,33 @@ def build_histogram_at(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
     node0 = jnp.asarray(node0, jnp.int32)
     return _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk,
                             stride)
+
+
+def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
+                    stride: int = 1, quantised: bool = False):
+    """A level's histogram for nodes ``node0 + stride*[0, n_nodes)``: the
+    one way in for the level body and the page step, who branch on nothing.
+
+    ``quantised``: ``gpair`` is the (R, C, 3) int8 limb array and the sums
+    are exact int32 (ops/quantise.py); else float32, (n_nodes, F, B, C).
+    ``node0`` a Python int is a constant of the program (a program a depth:
+    ``build_histogram``, ``hist_accumulate_q``); a traced scalar is an operand
+    of one program for every depth (``build_histogram_at``,
+    ``build_histogram_q``)."""
+    static = isinstance(node0, int)
+    if quantised:
+        from .quantise import build_histogram_q, hist_accumulate_q
+
+        if static:
+            return hist_accumulate_q(bins, gpair, pos, node0, n_nodes, n_bin,
+                                     stride=stride)
+        return build_histogram_q(bins, gpair, pos, node0, n_nodes=n_nodes,
+                                 n_bin=n_bin, stride=stride)
+    if static:
+        return build_histogram(bins, gpair, pos, node0=node0, n_nodes=n_nodes,
+                               n_bin=n_bin, stride=stride)
+    return build_histogram_at(bins, gpair, pos, node0, n_nodes=n_nodes,
+                              n_bin=n_bin, stride=stride)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bin", "stride"))

@@ -11,16 +11,14 @@ the mesh is the communicator.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.split import SplitParams
-from ..telemetry import span
-from ..tree.grow import (TreeState, init_tree_state, level_step,
-                         level_step_padded, make_set_matrix,
+from ..tree.grow import (HistTreeGrower, TreeState, init_tree_state,
+                         level_step, level_step_padded, make_set_matrix,
                          max_nodes_for_depth)
 from .mesh import DATA_AXIS
 
@@ -36,25 +34,22 @@ def _state_specs(data_axis: str):
     )
 
 
-class ShardedHistTreeGrower:
-    """Drop-in replacement for HistTreeGrower over a 1-D mesh."""
+class ShardedHistTreeGrower(HistTreeGrower):
+    """Drop-in replacement for HistTreeGrower over a 1-D mesh: its loop and
+    its width rule, each level program wrapped in ``shard_map``."""
 
     def __init__(self, max_depth: int, params: SplitParams, mesh, *,
-                 hist_impl: str = "xla", interaction_sets=None,
-                 max_leaves: int = 0, lossguide: bool = False,
-                 quantised: bool = False) -> None:
-        self.max_depth = max_depth
-        self.params = params
+                 interaction_sets=None, max_leaves: int = 0,
+                 lossguide: bool = False, quantised: bool = False) -> None:
+        # quantised: int psum is exact, so trees are bitwise-identical for
+        # ANY chip count, and prepare_quantised runs as a jit over the
+        # already-sharded gpair: GSPMD's all-reduce-max and integer root
+        # reduce are exact, so rho and the root totals are identical on
+        # every topology
+        super().__init__(max_depth, params, interaction_sets=interaction_sets,
+                         max_leaves=max_leaves, lossguide=lossguide,
+                         quantised=quantised)
         self.mesh = mesh
-        self.hist_impl = hist_impl
-        self.interaction_sets = interaction_sets
-        self.max_leaves = max_leaves
-        self.lossguide = lossguide
-        # fixed-point limb histograms (ops/quantise.py): int psum is exact,
-        # so trees are bitwise-identical for ANY chip count — the
-        # GradientQuantiser contract (src/tree/gpu_hist/quantiser.cuh)
-        self.quantised = quantised
-        self.max_nodes = max_nodes_for_depth(max_depth)
         self._built_for = None
 
     def _build(self, n_features: int, n_bin: int = 1, has_cat: bool = False) -> None:
@@ -83,143 +78,44 @@ class ShardedHistTreeGrower:
         gspec = P(ax, None, None) if q else P(ax, None)
         row_specs = (sspec, P(ax, None), gspec, P(), P(), P(), P(), P())
         rho_specs = (P(),) if q else ()
-        self._level_fns = {}
-        # one shared padded interior program for all depths 1..max_depth-1
-        # (same compile-wall fix as HistTreeGrower; hist psum rides inside
-        # level_step_padded via axis_name) — per-depth programs only for the
-        # root and the leaf-finalize level, plus the pallas fallback.
-        # Same platform rule as HistTreeGrower (shared helper).
-        from ..tree.grow import default_padded_levels
 
-        self._padded = (self.hist_impl != "pallas" and self.max_depth >= 2
-                        and default_padded_levels(self.max_depth))
-        if self._padded:
-            W = 1 << (self.max_depth - 1)
-            pad_base = functools.partial(
-                level_step_padded, width=W, params=self.params, axis_name=ax,
-                hist_impl=self.hist_impl, lossguide=self.lossguide,
-                has_cat=has_cat, subtract=True, quantised=q,
-            )
-            self._interior_fn = jax.jit(
-                jax.shard_map(pad_base, mesh=self.mesh,
-                              in_specs=row_specs + (P(), P()) + rho_specs,
-                              out_specs=(sspec, P()))
-            )
-        depths = ((0, self.max_depth) if self._padded
-                  else range(self.max_depth + 1))
-        for d in depths:
-            last = d == self.max_depth
-            subtract = d > 0 and not last and not self._padded
-            base = functools.partial(
-                level_step,
-                depth=d,
-                params=self.params,
-                last_level=last,
-                axis_name=ax,
-                hist_impl=self.hist_impl,
-                lossguide=self.lossguide,
-                has_cat=has_cat,
-                subtract=subtract,
-                quantised=q,
-            )
-            if last:
-                # hist neither consumed nor produced on the last level
-                def fn(state, bins, gpair, cuts, nb, fm, sm, cmm, *r, _b=base):
-                    st, _ = _b(state, bins, gpair, cuts, nb, fm, sm, cmm)
-                    return st
+        def program(step, n_more: int, **static):
+            # after the rows' operands: hist_prev (replicated: psummed at
+            # its own level; None where a level takes none) and, for the
+            # shared program, node0.  The hist psum rides inside via
+            # axis_name.
+            return jax.jit(jax.shard_map(
+                functools.partial(
+                    step, params=self.params, axis_name=ax,
+                    lossguide=self.lossguide, has_cat=has_cat, quantised=q,
+                    **static),
+                mesh=self.mesh,
+                in_specs=row_specs + (P(),) * n_more + rho_specs,
+                out_specs=(sspec, P())))
 
-                in_specs, out_specs = row_specs + rho_specs, sspec
-            elif subtract:
-                # hist_prev is replicated (already psummed at its own level)
-                fn = base
-                in_specs = row_specs + (P(),) + rho_specs
-                out_specs = (sspec, P())
-            else:
-                if q:
-                    def fn(state, bins, gq, cuts, nb, fm, sm, cmm, rho,
-                           _b=base):
-                        return _b(state, bins, gq, cuts, nb, fm, sm, cmm,
-                                  None, rho)
-                else:
-                    fn = base
-                in_specs = row_specs + rho_specs
-                out_specs = (sspec, P())
-            self._level_fns[d] = jax.jit(
-                jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-            )
+        md = self.max_depth
+        self._interior_fn = (
+            program(level_step_padded, 2, width=1 << (md - 1), subtract=True)
+            if md >= 2 else None)
+        self._level_fns = {
+            d: program(level_step, 1, depth=d, last_level=(d == md),
+                       subtract=(0 < d < md))
+            for d in range(md + 1)}
         self._built_for = (n_features, n_bin, has_cat)
 
-    def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None,
-             cat_mask=None) -> TreeState:
-        F = bins.shape[1]
-        self._build(F, cuts_pad.shape[1], has_cat=cat_mask is not None)
-        ones = jnp.ones((1, F), dtype=bool)
-        setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
-        cm = jnp.asarray(cat_mask) if cat_mask is not None else jnp.zeros(F, bool)
-        state = self._init_fn(gpair, valid)
-        rho_args = ()
-        if self.quantised:
-            from ..ops.quantise import prepare_quantised
+    def _init_state(self, gpair, valid, setmat, cuts_pad,
+                    has_cat: bool) -> TreeState:
+        self._build(setmat.shape[1], cuts_pad.shape[1], has_cat)
+        return self._init_fn(gpair, valid)
 
-            # jit over the already-sharded gpair: GSPMD's all-reduce-max and
-            # integer root reduce are exact, so rho and the root totals are
-            # identical on every topology
-            gpair, rho, state = prepare_quantised(gpair, valid, state)
-            rho_args = (rho,)
-        # same fused-level span name as HistTreeGrower (each sharded level
-        # program is hist psum + split eval + position rewrite in one call)
-        _LEVEL = "grow.build_hist+eval_split"
-        if self._padded:
-            from ..tree.grow import HistTreeGrower
-
-            md = self.max_depth
-            W = 1 << (md - 1)
-            fm = ones if feature_masks is None else feature_masks(0, 1)
-            with span(_LEVEL, depth=0):
-                state, hist = self._level_fns[0](state, bins, gpair, cuts_pad,
-                                                 n_bins, fm, setmat, cm,
-                                                 *rho_args)
-            hist_pad = jnp.zeros((W,) + hist.shape[1:],
-                                 hist.dtype).at[:1].set(hist)
-            for d in range(1, md):
-                fm = (ones if feature_masks is None
-                      else HistTreeGrower._pad_mask(feature_masks(d, 1 << d), W))
-                with span(_LEVEL, depth=d):
-                    state, hist_pad = self._interior_fn(
-                        state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm,
-                        hist_pad, jnp.int32((1 << d) - 1), *rho_args)
-            fm = ones if feature_masks is None else feature_masks(md, 1 << md)
-            with span(_LEVEL, depth=md):
-                state = self._level_fns[md](state, bins, gpair, cuts_pad,
-                                            n_bins, fm, setmat, cm, *rho_args)
-            return state
-        hist_prev = None
-        for d in range(self.max_depth + 1):
-            fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-            with span(_LEVEL, depth=d):
-                if d == self.max_depth:
-                    state = self._level_fns[d](state, bins, gpair, cuts_pad,
-                                               n_bins, fm, setmat, cm,
-                                               *rho_args)
-                elif d == 0:
-                    state, hist_prev = self._level_fns[d](state, bins, gpair,
-                                                          cuts_pad, n_bins, fm,
-                                                          setmat, cm,
-                                                          *rho_args)
-                else:
-                    state, hist_prev = self._level_fns[d](state, bins, gpair,
-                                                          cuts_pad, n_bins, fm,
-                                                          setmat, cm,
-                                                          hist_prev,
-                                                          *rho_args)
-        return state
-
-    @staticmethod
-    def to_host(state: TreeState):
-        from ..tree.grow import HistTreeGrower
-
-        return HistTreeGrower.to_host(state)
+    def _run_level(self, d: int, shared: bool, state, page, fm, setmat, cm,
+                   hist_prev, rho, has_cat: bool):
+        rho_args = () if rho is None else (rho,)
+        if shared:
+            return self._interior_fn(state, *page, fm, setmat, cm, hist_prev,
+                                     jnp.int32((1 << d) - 1), *rho_args)
+        return self._level_fns[d](state, *page, fm, setmat, cm, hist_prev,
+                                  *rho_args)
 
 
 class ShardedMultiTargetGrower:
@@ -230,8 +126,6 @@ class ShardedMultiTargetGrower:
 
     def __init__(self, max_depth: int, params: SplitParams, n_targets: int,
                  mesh, *, max_leaves: int = 0, lossguide: bool = False) -> None:
-        from ..tree.grow_multi import MultiTreeState  # noqa: F401
-
         self.max_depth = max_depth
         self.params = params
         self.n_targets = n_targets
@@ -269,30 +163,19 @@ class ShardedMultiTargetGrower:
                 out_specs=sspec,
             )
         )
-        self._level_fns = {}
-        for d in range(self.max_depth + 1):
-            last = d == self.max_depth
-            subtract = d > 0 and not last
-            base = functools.partial(
-                level_step_multi, depth=d, params=self.params,
-                last_level=last, n_targets=self.n_targets,
-                subtract_on=subtract, axis_name=ax, lossguide=self.lossguide,
-            )
-            row_specs = (sspec, P(ax, None), P(ax, None, None), P(), P(), P())
-            if last:
-                def fn(state, bins, gpair, cuts, nb, fm, _b=base):
-                    st, _ = _b(state, bins, gpair, cuts, nb, fm)
-                    return st
-
-                in_specs, out_specs = row_specs, sspec
-            elif subtract:
-                fn, in_specs, out_specs = base, row_specs + (P(),), (sspec, P())
-            else:
-                fn, in_specs, out_specs = base, row_specs, (sspec, P())
-            self._level_fns[d] = jax.jit(
-                jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-            )
+        md = self.max_depth
+        # hist_prev is replicated (already psummed at its own level), and
+        # None at the root and on the last level
+        row_specs = (sspec, P(ax, None), P(ax, None, None), P(), P(), P(), P())
+        self._level_fns = {
+            d: jax.jit(jax.shard_map(
+                functools.partial(
+                    level_step_multi, depth=d, params=self.params,
+                    last_level=(d == md), n_targets=self.n_targets,
+                    subtract_on=(0 < d < md), axis_name=ax,
+                    lossguide=self.lossguide),
+                mesh=self.mesh, in_specs=row_specs, out_specs=(sspec, P())))
+            for d in range(md + 1)}
         self._built_for = (n_features, n_bin)
 
     def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None):
@@ -300,19 +183,12 @@ class ShardedMultiTargetGrower:
         self._build(F, cuts_pad.shape[1])
         ones = jnp.ones((1, F), dtype=bool)
         state = self._init_fn(gpair, valid)
-        hist_prev = None
+        hist = None
         for d in range(self.max_depth + 1):
             fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-            if d == self.max_depth:
-                state = self._level_fns[d](state, bins, gpair, cuts_pad,
-                                           n_bins, fm)
-            elif d == 0:
-                state, hist_prev = self._level_fns[d](state, bins, gpair,
-                                                      cuts_pad, n_bins, fm)
-            else:
-                state, hist_prev = self._level_fns[d](state, bins, gpair,
-                                                      cuts_pad, n_bins, fm,
-                                                      hist_prev)
+            state, hist = self._level_fns[d](
+                state, bins, gpair, cuts_pad, n_bins, fm,
+                None if d == self.max_depth else hist)
         return state
 
     @staticmethod

@@ -145,7 +145,7 @@ KNOWN_LEARNER_KEYS = {
 def split_unknown(params: Dict[str, Any]) -> List[str]:
     p = canonicalize(params)
     tree_keys = {("lambda" if f.name == "lambda_" else f.name) for f in dataclasses.fields(TrainParam)}
-    # leading-underscore keys are internal hooks (_hist_impl,
-    # _extmem_prefetch, ...), deliberately outside the public surface
+    # leading-underscore keys are internal hooks (_extmem_prefetch,
+    # _lockstep, ...), deliberately outside the public surface
     return [k for k in p if k not in tree_keys
             and k not in KNOWN_LEARNER_KEYS and not k.startswith("_")]

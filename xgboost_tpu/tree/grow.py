@@ -4,17 +4,25 @@ TPU-native re-design of the reference's GPU hist updater
 (src/tree/updater_gpu_hist.cu:617 UpdateTree; Driver loop src/tree/driver.h:30).
 The CUDA updater pops variable node batches from a priority queue and mutates
 the tree on host; under XLA we need static shapes, so the tree grows strictly
-level-by-level over a heap-indexed node array (node i -> children 2i+1, 2i+2),
-with one jitted ``level_step`` per depth (compile cache shared across all trees
-and boosting rounds).  Dead heap slots cost nothing: their node masks match no
-rows, so their histograms are zero and they become weightless leaves.
+level-by-level over a heap-indexed node array (node i -> children 2i+1, 2i+2).
+Dead heap slots cost nothing: their node masks match no rows, so their
+histograms are zero and they become weightless leaves.
 
-Everything runs on device — histogram (ops/histogram.py), split choice
-(ops/split.py), position update (the RowPartitioner analogue,
-src/tree/gpu_hist/row_partitioner.cuh — here an elementwise ``pos`` rewrite,
-no physical partition) and tree-array writes — so the whole step can be wrapped
-in ``shard_map`` with ``lax.psum`` on the histogram for multi-chip training
-(the reference's AllReduceHist, src/tree/gpu_hist/histogram.cu:598-608).
+A level is written once, in ``_level``: histogram (``level_histogram`` of
+ops/histogram.py, which alone knows which kernel that is) -> decide
+(``decide_level``: ops/split.py's scan, the gain threshold, the split budget,
+the tree-array writes) -> route (``_update_positions``, the RowPartitioner
+analogue, src/tree/gpu_hist/row_partitioner.cuh — here an elementwise ``pos``
+rewrite, no physical partition).  It has two jitted entry points, because a
+static and a traced ``node0`` are two programs: ``level_step`` (a program a
+depth) and ``level_step_padded`` (one program for every interior depth); the
+compile cache is shared across all trees and boosting rounds.
+``HistTreeGrower.grow`` is the one depth-wise loop; parallel/grower.py
+inherits it and wraps each program in ``shard_map``, with ``lax.psum`` on the
+histogram (the reference's AllReduceHist,
+src/tree/gpu_hist/histogram.cu:598-608): everything runs on device.  The
+class-batched and the vector-leaf levels (grow_lockstep.py, grow_multi.py)
+carry an axis of their own through every line and stay apart.
 """
 from __future__ import annotations
 
@@ -25,8 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.histogram import (_use_scatter, build_histogram,
-                             combine_sibling_hists, node_sums)
+from ..ops.histogram import (combine_sibling_hists, hist_is_row_pass,
+                             level_histogram, node_sums)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 
@@ -125,8 +133,6 @@ def init_tree_state(gpair, valid, *, max_nodes: int, axis_name: Optional[str] = 
     )
 
 
-
-
 def sync_root_totals(state):
     """Multi-process root GlobalSum (updater_gpu_hist.cu:581): the local root
     totals computed by init_*_state cross processes once.  Works for both the
@@ -142,8 +148,7 @@ def sync_root_totals(state):
 def _record_level(st: TreeState, best, idx, can_split, new_leaf, w, thr_lvl,
                   totals_lvl, compat_lvl, member, new_budget, lower_lvl,
                   upper_lvl, params: SplitParams):
-    """Apply one level's split decisions to the tree arrays (shared between
-    the in-core level_step and the external-memory streaming grower)."""
+    """Apply one level's split decisions to the tree arrays."""
     st = st._replace(
         feat=st.feat.at[idx].set(jnp.where(can_split, best.feature, -1)),
         sbin=st.sbin.at[idx].set(jnp.where(can_split, best.bin, 0)),
@@ -203,7 +208,7 @@ def _update_positions(bins, pos, best, can_split, node0: int, N: int, B: int,
     at 10.5M rows, the dense pass 1.1 to 2.4 ms: PERF.md, PR 27); beside the
     CPU's row-pass histogram a gather is the cheap form, and so it is for a
     page too wide for a node's entry to fit one int32."""
-    dense = not _use_scatter() and _packed_bits(bins.shape[1], B) <= 31
+    dense = not hist_is_row_pass() and _packed_bits(bins.shape[1], B) <= 31
     form = _update_positions_dense if dense else _update_positions_gather
     return form(bins, pos, best, can_split, node0, N, B, has_cat)
 
@@ -282,50 +287,21 @@ def _update_positions_dense(bins, pos, best, can_split, node0, N: int,
     return jnp.where(can_r, child, pos)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("depth", "params", "last_level", "axis_name", "hist_impl",
-                     "lossguide", "has_cat", "subtract", "quantised"),
-)
-def level_step(
-    state: TreeState,
-    bins,
-    gpair,
-    cuts_pad,
-    n_bins,
-    feature_mask,
-    set_matrix,
-    cat_mask,
-    hist_prev=None,
-    rho=None,
-    *,
-    depth: int,
-    params: SplitParams,
-    last_level: bool,
-    axis_name: Optional[str] = None,
-    hist_impl: str = "xla",
-    lossguide: bool = False,
-    has_cat: bool = False,
-    subtract: bool = False,
-    quantised: bool = False,
-):
-    """Expand every alive node at ``depth``: hist -> best split -> apply.
+def decide_level(state: TreeState, hist_of, cuts_pad, n_bins, feature_mask,
+                 set_matrix, cat_mask, node0, N: int, *, params: SplitParams,
+                 last_level: bool, lossguide: bool, has_cat: bool):
+    """A level less its rows: the level's slices of the tree arrays, then
+    either the leaf level's writes or histogram -> best splits -> who may
+    take them -> the tree arrays with that written in.
 
-    Mirrors one driver iteration of the reference
-    (updater_gpu_hist.cu:626-646: PartitionAndBuildHist + ReduceHist +
-    EvaluateSplits + ApplySplit), with the node batch = the whole level.
-
-    Returns ``(state, hist)`` — ``hist`` (N, F, B, C) feeds the next level's
-    subtraction trick (updater_gpu_hist.cu:309 SubtractHist): with
-    ``subtract=True`` and ``hist_prev`` = the parent level's histogram, only
-    left children (even level offsets) are built by matmul and each right
-    sibling is derived as ``parent - left`` — halving both the hist FLOPs and
-    (multi-chip) the psum payload.  ``hist`` is None on the last level.
-    """
-    node0 = (1 << depth) - 1
-    N = 1 << depth
+    ``hist_of`` maps the level's (N,) alive mask to ``(hist, hist_eval)``:
+    the level's sums as the next level subtracts from them, and as the
+    (N, F, B, 2) float32 the split scan reads.  ``_level`` builds them there,
+    between the slices and the scan; the growers whose rows come a page or a
+    process at a time (tree/stream.py) have summed them before, and route
+    the rows themselves.  Returns ``(state, best, can_split, hist)``, the
+    last three None on the last level."""
     B = cuts_pad.shape[1]
-
     with jax.named_scope("split"):
         idx = node0 + jnp.arange(N, dtype=jnp.int32)
         totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, N, axis=0)
@@ -345,51 +321,9 @@ def level_step(
                 ),
                 base_weight=state.base_weight.at[idx].set(w),
                 sum_hess=state.sum_hess.at[idx].set(totals_lvl[:, 1]),
-            ), None
+            ), None, None, None
 
-    if quantised:
-        # gpair here is the (R, C, 3) int8 limb array: integer builds and
-        # psums are exact/order-invariant, so hist bits are topology-free
-        # (the reference's GradientQuantiser contract, quantiser.cuh:52)
-        from ..ops.quantise import dequantise, hist_accumulate_q
-
-        if hist_impl == "pallas":
-            # int8 x int8 -> int32 MXU kernel: the determinism contract and
-            # the production kernel at once (VERDICT r4 #4)
-            from ..ops.hist_pallas import build_histogram_pallas_q
-
-            def _build(b, g, p, *, node0, n_nodes, n_bin, stride=1):
-                return build_histogram_pallas_q(
-                    b, g, p, node0=node0, n_nodes=n_nodes, n_bin=n_bin,
-                    stride=stride)
-        else:
-            def _build(b, g, p, *, node0, n_nodes, n_bin, stride=1):
-                return hist_accumulate_q(b, g, p, node0, n_nodes, n_bin,
-                                         stride=stride)
-    elif hist_impl == "pallas":
-        from ..ops.hist_pallas import build_histogram_pallas as _build
-    else:
-        _build = build_histogram
-    with jax.named_scope("hist"):
-        if subtract:
-            half = N // 2
-            # left children sit at even offsets 2j (heap id node0 + 2j);
-            # parent j of the previous level maps to offsets (2j, 2j+1)
-            left = _build(bins, gpair, state.pos, node0=node0, n_nodes=half,
-                          n_bin=B, stride=2)
-            if axis_name is not None:
-                left = lax.psum(left, axis_name)
-            hist = combine_sibling_hists(left, hist_prev, alive_lvl)
-        else:
-            hist = _build(bins, gpair, state.pos, node0=node0, n_nodes=N,
-                          n_bin=B)
-            if axis_name is not None:
-                # the distributed cost (SURVEY §3.1)
-                hist = lax.psum(hist, axis_name)
-        if quantised:
-            hist_eval = dequantise(hist, rho)  # the ONE rounding step
-        else:
-            hist_eval = hist
+    hist, hist_eval = hist_of(alive_lvl)
 
     with jax.named_scope("split"):
         # interaction constraints: allowed feature set per node = union of
@@ -428,17 +362,111 @@ def level_step(
         st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
                            totals_lvl, compat_lvl, member, new_budget,
                            lower_lvl, upper_lvl, params)
-    with jax.named_scope("route"):
-        st = st._replace(
-            pos=_update_positions(bins, st.pos, best, can_split, node0, N, B,
-                                  has_cat))
+    return st, best, can_split, hist
+
+
+def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
+           set_matrix, cat_mask, hist_prev, rho, node0, N: int, *,
+           params: SplitParams, last_level: bool, axis_name: Optional[str],
+           lossguide: bool, has_cat: bool, subtract: bool, quantised: bool):
+    """One level, the only place it is written: histogram -> decide -> route
+    over the ``N`` heap slots from ``node0``, a Python int (``level_step``: a
+    program a depth) or a traced scalar (``level_step_padded``: one program
+    for every interior depth).
+
+    Mirrors one driver iteration of the reference
+    (updater_gpu_hist.cu:626-646: PartitionAndBuildHist + ReduceHist +
+    EvaluateSplits + ApplySplit), with the node batch = the whole level.
+
+    Returns ``(state, hist)`` — ``hist`` (N, F, B, C) feeds the next level's
+    subtraction trick (updater_gpu_hist.cu:309 SubtractHist): with
+    ``subtract=True`` and ``hist_prev`` = the parent level's histogram, only
+    left children (even level offsets) are built by matmul and each right
+    sibling is derived as ``parent - left`` — halving both the hist FLOPs and
+    (multi-chip) the psum payload.  ``hist`` is None on the last level.
+    """
+    B = cuts_pad.shape[1]
+
+    def hist_of(alive_lvl):
+        # quantised: gpair is the (R, C, 3) int8 limb array: integer builds
+        # and psums are exact/order-invariant, so hist bits are topology-free
+        # (the reference's GradientQuantiser contract, quantiser.cuh:52)
+        with jax.named_scope("hist"):
+            if subtract:
+                half = N // 2
+                # left children sit at even offsets 2j (heap id node0 + 2j);
+                # parent j of the previous level maps to offsets (2j, 2j+1)
+                left = level_histogram(bins, gpair, state.pos, node0,
+                                       n_nodes=half, n_bin=B, stride=2,
+                                       quantised=quantised)
+                if axis_name is not None:
+                    left = lax.psum(left, axis_name)
+                # a parent level as wide as this one (the shared width) has
+                # its N/2 real rows first
+                hist = combine_sibling_hists(left, hist_prev[:half], alive_lvl)
+            else:
+                hist = level_histogram(bins, gpair, state.pos, node0,
+                                       n_nodes=N, n_bin=B, quantised=quantised)
+                if axis_name is not None:
+                    # the distributed cost (SURVEY §3.1)
+                    hist = lax.psum(hist, axis_name)
+            if not quantised:
+                return hist, hist
+            from ..ops.quantise import dequantise
+
+            return hist, dequantise(hist, rho)  # the ONE rounding step
+
+    st, best, can_split, hist = decide_level(
+        state, hist_of, cuts_pad, n_bins, feature_mask, set_matrix, cat_mask,
+        node0, N, params=params, last_level=last_level, lossguide=lossguide,
+        has_cat=has_cat)
+    if not last_level:
+        with jax.named_scope("route"):
+            st = st._replace(
+                pos=_update_positions(bins, st.pos, best, can_split, node0, N,
+                                      B, has_cat))
     return st, hist
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("width", "params", "axis_name", "hist_impl",
+    static_argnames=("depth", "params", "last_level", "axis_name",
                      "lossguide", "has_cat", "subtract", "quantised"),
+)
+def level_step(
+    state: TreeState,
+    bins,
+    gpair,
+    cuts_pad,
+    n_bins,
+    feature_mask,
+    set_matrix,
+    cat_mask,
+    hist_prev=None,
+    rho=None,
+    *,
+    depth: int,
+    params: SplitParams,
+    last_level: bool,
+    axis_name: Optional[str] = None,
+    lossguide: bool = False,
+    has_cat: bool = False,
+    subtract: bool = False,
+    quantised: bool = False,
+):
+    """Expand every alive node at ``depth`` (``_level``): a program a depth,
+    ``node0`` and the width ``2**depth`` its constants."""
+    return _level(state, bins, gpair, cuts_pad, n_bins, feature_mask,
+                  set_matrix, cat_mask, hist_prev, rho, (1 << depth) - 1,
+                  1 << depth, params=params, last_level=last_level,
+                  axis_name=axis_name, lossguide=lossguide, has_cat=has_cat,
+                  subtract=subtract, quantised=quantised)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("width", "params", "axis_name", "lossguide", "has_cat",
+                     "subtract", "quantised"),
 )
 def level_step_padded(
     state: TreeState,
@@ -456,13 +484,12 @@ def level_step_padded(
     width: int,
     params: SplitParams,
     axis_name: Optional[str] = None,
-    hist_impl: str = "xla",
     lossguide: bool = False,
     has_cat: bool = False,
     subtract: bool = True,
     quantised: bool = False,
 ):
-    """``level_step`` with the node dimension PADDED to a fixed ``width`` and
+    """``_level`` with the node dimension PADDED to a fixed ``width`` and
     a TRACED ``node0`` — ONE compiled program serves every interior depth
     (VERDICT r3 #4: the per-depth compile wall).
 
@@ -491,82 +518,11 @@ def level_step_padded(
     ``hist_prev``/returned ``hist`` use the padded level-offset layout
     (width, F, B, C); row j = heap node ``node0 + j``.
     """
-    from ..ops.histogram import build_histogram_at
-
-    W = width
-    B = cuts_pad.shape[1]
-    node0 = jnp.asarray(node0, jnp.int32)
-
-    with jax.named_scope("split"):
-        idx = node0 + jnp.arange(W, dtype=jnp.int32)
-        totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, W, axis=0)
-        alive_lvl = lax.dynamic_slice_in_dim(state.alive, node0, W, axis=0)
-        lower_lvl = lax.dynamic_slice_in_dim(state.lower, node0, W, axis=0)
-        upper_lvl = lax.dynamic_slice_in_dim(state.upper, node0, W, axis=0)
-        w = calc_weight(totals_lvl[:, 0], totals_lvl[:, 1], params, lower_lvl,
-                        upper_lvl)
-
-    if hist_impl == "pallas":
-        raise NotImplementedError(
-            "padded level sharing currently uses the XLA hist path; "
-            "hist_impl='pallas' keeps per-depth level_step")
-    if quantised:
-        from ..ops.quantise import build_histogram_q, dequantise
-
-        _build_at = build_histogram_q
-    else:
-        _build_at = build_histogram_at
-    with jax.named_scope("hist"):
-        if subtract:
-            half = W // 2
-            left = _build_at(bins, gpair, state.pos, node0,
-                             n_nodes=half, n_bin=B, stride=2)
-            if axis_name is not None:
-                left = lax.psum(left, axis_name)
-            hist = combine_sibling_hists(left, hist_prev[:half], alive_lvl)
-        else:
-            hist = _build_at(bins, gpair, state.pos, node0,
-                             n_nodes=W, n_bin=B)
-            if axis_name is not None:
-                hist = lax.psum(hist, axis_name)
-        hist_eval = dequantise(hist, rho) if quantised else hist
-
-    with jax.named_scope("split"):
-        compat_lvl = lax.dynamic_slice_in_dim(state.setcompat, node0, W, axis=0)
-        allowed = jnp.einsum("ns,sf->nf", compat_lvl.astype(jnp.float32),
-                             set_matrix.astype(jnp.float32)) > 0.0
-        fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
-        fmask = allowed & fm
-
-        node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
-        best = evaluate_splits(hist_eval, totals_lvl, n_bins, params, fmask,
-                               node_bounds,
-                               cat_mask=cat_mask if has_cat else None)
-
-        gamma_eps = max(params.gamma, _EPS)
-        can_split = alive_lvl & (best.gain > gamma_eps)
-
-        budget = state.splits_left[0]
-        prio = best.gain if lossguide else -idx.astype(jnp.float32)
-        prio = jnp.where(can_split, prio, -jnp.inf)
-        order = jnp.argsort(-prio)
-        ranks = jnp.argsort(order).astype(jnp.int32)
-        can_split = can_split & (ranks < budget)
-        new_budget = budget - jnp.sum(can_split).astype(jnp.int32)
-
-        new_leaf = alive_lvl & ~can_split
-
-        thr_lvl = cuts_pad[best.feature, jnp.minimum(best.bin, B - 1)]
-        member = set_matrix.T[jnp.clip(best.feature, 0, set_matrix.shape[1] - 1)]
-    with jax.named_scope("record"):
-        st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
-                           totals_lvl, compat_lvl, member, new_budget,
-                           lower_lvl, upper_lvl, params)
-    with jax.named_scope("route"):
-        st = st._replace(
-            pos=_update_positions(bins, st.pos, best, can_split, node0, W, B,
-                                  has_cat))
-    return st, hist
+    return _level(state, bins, gpair, cuts_pad, n_bins, feature_mask,
+                  set_matrix, cat_mask, hist_prev, rho,
+                  jnp.asarray(node0, jnp.int32), width, params=params,
+                  last_level=False, axis_name=axis_name, lossguide=lossguide,
+                  has_cat=has_cat, subtract=subtract, quantised=quantised)
 
 
 @jax.jit
@@ -614,7 +570,7 @@ def default_padded_levels(max_depth: int) -> bool:
     # native kernel — a clear win at the bench depth 6, but at depth 8 the
     # 128-wide buffers measurably outweigh the saved compiles, so deep CPU
     # trees keep per-depth programs
-    return _use_scatter() and max_depth <= 6
+    return hist_is_row_pass() and max_depth <= 6
 
 
 class HistTreeGrower:
@@ -626,8 +582,6 @@ class HistTreeGrower:
         max_depth: int,
         params: SplitParams,
         *,
-        axis_name: Optional[str] = None,
-        hist_impl: str = "xla",
         interaction_sets=None,
         max_leaves: int = 0,
         lossguide: bool = False,
@@ -637,8 +591,6 @@ class HistTreeGrower:
     ) -> None:
         self.max_depth = max_depth
         self.params = params
-        self.axis_name = axis_name
-        self.hist_impl = hist_impl
         self.interaction_sets = interaction_sets
         self.max_leaves = max_leaves
         self.lossguide = lossguide
@@ -648,21 +600,38 @@ class HistTreeGrower:
         # (src/tree/gpu_hist/quantiser.cuh); see ops/quantise.py
         self.quantised = quantised
         # one shared compiled program for all interior depths (padded node
-        # dim + traced node0) instead of one per depth — kills the compile
-        # wall.  Padding costs FLOPs at the narrow depths (every interior
-        # level is built at the widest level's width): on the MXU the extra
-        # output columns ride the same 128-lane tile (2**(md-1) <= 128 for
-        # md <= 8), but on CPU the matmul pays the full padded width, so
-        # deep CPU trees default to per-depth programs (compile there is
-        # cheap relative to step time).  Pallas keeps per-depth steps
-        # (static node0 kernel).
+        # dim + traced node0) instead of one per depth; None = the
+        # platform's rule (default_padded_levels has the reasons)
         if padded_levels is None:
             padded_levels = default_padded_levels(max_depth)
-        self.padded_levels = padded_levels and hist_impl != "pallas"
+        self.padded_levels = padded_levels
         self.max_nodes = max_nodes_for_depth(max_depth)
 
-    def _set_matrix(self, n_features: int):
-        return make_set_matrix(self.interaction_sets, n_features)
+    def _init_state(self, gpair, valid, setmat, cuts_pad,
+                    has_cat: bool) -> TreeState:
+        return init_tree_state(
+            gpair, valid, max_nodes=self.max_nodes, n_sets=setmat.shape[0],
+            n_bin=cuts_pad.shape[1],
+            max_splits=(self.max_leaves - 1) if self.max_leaves > 0 else 0,
+        )
+
+    def _run_level(self, d: int, shared: bool, state, page, fm, setmat, cm,
+                   hist_prev, rho, has_cat: bool):
+        """Dispatch depth ``d``'s program: ``(state, hist)``.  3 compiled
+        programs regardless of depth where the interior levels share a
+        width (root, shared padded interior with a traced node0, leaf
+        finalize), else one a depth."""
+        md = self.max_depth
+        common = dict(params=self.params, lossguide=self.lossguide,
+                      has_cat=has_cat, quantised=self.quantised)
+        if shared:
+            return level_step_padded(
+                state, *page, fm, setmat, cm, hist_prev, (1 << d) - 1, rho,
+                width=1 << (md - 1), subtract=self.subtract, **common)
+        return level_step(
+            state, *page, fm, setmat, cm, hist_prev, rho, depth=d,
+            last_level=(d == md), subtract=(self.subtract and 0 < d < md),
+            **common)
 
     def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None,
              cat_mask=None) -> TreeState:
@@ -670,70 +639,40 @@ class HistTreeGrower:
         (the ColumnSampler hook: bytree/bylevel/bynode, src/common/random.h).
         cat_mask: optional (F,) bool marking categorical features."""
         F = bins.shape[1]
-        B = cuts_pad.shape[1]
         ones = jnp.ones((1, F), dtype=bool)
-        setmat = jnp.asarray(self._set_matrix(F))
+        setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
         has_cat = cat_mask is not None
         cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
-        state = init_tree_state(
-            gpair, valid, max_nodes=self.max_nodes, axis_name=self.axis_name,
-            n_sets=setmat.shape[0],
-            max_splits=(self.max_leaves - 1) if self.max_leaves > 0 else 0,
-            n_bin=B,
-        )
+        state = self._init_state(gpair, valid, setmat, cuts_pad, has_cat)
         rho = None
         if self.quantised:
             from ..ops.quantise import prepare_quantised
 
-            gpair, rho, state = prepare_quantised(
-                gpair, valid, state, axis_name=self.axis_name)
+            gpair, rho, state = prepare_quantised(gpair, valid, state)
         md = self.max_depth
-        common = dict(params=self.params, axis_name=self.axis_name,
-                      lossguide=self.lossguide, has_cat=has_cat,
-                      quantised=self.quantised)
-        # one span per level: the compiled program fuses build_hist +
-        # eval_split + the position rewrite, so the bracket necessarily
-        # covers all three — the name keeps the reference phase vocabulary
-        # greppable in traces (bestfirst.py times the phases separately)
-        _LEVEL = "grow.build_hist+eval_split"
-        if not self.padded_levels or md < 2:
-            hist_prev = None
-            for d in range(md + 1):
-                fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-                with span(_LEVEL, depth=d):
-                    state, hist_prev = level_step(
-                        state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm,
-                        hist_prev, rho, depth=d, last_level=(d == md),
-                        hist_impl=self.hist_impl,
-                        subtract=(self.subtract and d > 0 and hist_prev is not None),
-                        **common)
-            return state
-
-        # 3 compiled programs regardless of depth: root, shared padded
-        # interior (traced node0), leaf finalize
-        fm = ones if feature_masks is None else feature_masks(0, 1)
-        with span(_LEVEL, depth=0):
-            state, hist = level_step(
-                state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm, None,
-                rho, depth=0, last_level=False, hist_impl=self.hist_impl,
-                subtract=False, **common)
-        W = 1 << (md - 1)
-        hist_pad = jnp.zeros((W,) + hist.shape[1:], hist.dtype).at[:1].set(hist)
-        for d in range(1, md):
-            fm = (ones if feature_masks is None
-                  else self._pad_mask(feature_masks(d, 1 << d), W))
-            with span(_LEVEL, depth=d):
-                state, hist_pad = level_step_padded(
-                    state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm,
-                    hist_pad, (1 << d) - 1, rho, width=W,
-                    subtract=self.subtract, hist_impl=self.hist_impl,
-                    **common)
-        fm = ones if feature_masks is None else feature_masks(md, 1 << md)
-        with span(_LEVEL, depth=md):
-            state, _ = level_step(
-                state, bins, gpair, cuts_pad, n_bins, fm, setmat, cm, None,
-                rho, depth=md, last_level=True, hist_impl=self.hist_impl,
-                subtract=False, **common)
+        page = (bins, gpair, cuts_pad, n_bins)
+        hist = None
+        for d in range(md + 1):
+            # the root and the leaf level have programs of their own; the
+            # levels between are 2**d slots wide, or all as wide as the
+            # widest of them
+            shared = self.padded_levels and 0 < d < md
+            fm = ones if feature_masks is None else feature_masks(d, 1 << d)
+            if shared:
+                W = 1 << (md - 1)
+                fm = self._pad_mask(fm, W)
+                if d == 1:
+                    # the root's histogram, handed over at that width
+                    hist = jnp.zeros((W,) + hist.shape[1:],
+                                     hist.dtype).at[:1].set(hist)
+            # one span per level: the compiled program fuses build_hist +
+            # eval_split + the position rewrite, so the bracket necessarily
+            # covers all three — the name keeps the reference phase vocabulary
+            # greppable in traces (bestfirst.py times the phases separately)
+            with span("grow.build_hist+eval_split", depth=d):
+                state, hist = self._run_level(
+                    d, shared, state, page, fm, setmat, cm,
+                    None if d == md else hist, rho, has_cat)
         return state
 
     @staticmethod

@@ -10,8 +10,8 @@ touched once per level; host->HBM transfer of page i+1 overlaps compute on
 page i (jax.device_put is async).
 
 Everything except the page loop reuses the in-core grower's pieces
-(evaluate_splits / _record_level / _update_positions), so the split semantics
-are bitwise identical to HistTreeGrower.
+(grow.decide_level / _update_positions / ops.histogram.level_histogram), so
+the split semantics are bitwise identical to HistTreeGrower.
 """
 from __future__ import annotations
 
@@ -24,12 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.histogram import build_histogram, combine_sibling_hists
-from ..ops.split import SplitParams, calc_weight, evaluate_splits
-from .grow import (TreeState, _record_level, _update_positions, init_tree_state,
+from ..ops.histogram import combine_sibling_hists, level_histogram
+from ..ops.split import SplitParams
+from .grow import (TreeState, _update_positions, decide_level, init_tree_state,
                    make_set_matrix, max_nodes_for_depth)
-
-_EPS = 1e-6
 
 
 def _sim_transfer_ms_per_mb() -> float:
@@ -59,14 +57,10 @@ def _page_step(page_bins, gpair_seg, pos_seg, prev_best, prev_can, *,
     if has_prev:
         pos_seg = _update_positions(page_bins, pos_seg, prev_best, prev_can,
                                     node0_prev, n_prev, n_bin, has_cat)
-    if build and quantised:
-        from ..ops.quantise import hist_accumulate_q
-
-        hist = hist_accumulate_q(page_bins, gpair_seg, pos_seg, node0,
-                                 n_nodes, n_bin, stride=stride)
-    elif build:
-        hist = build_histogram(page_bins, gpair_seg, pos_seg, node0=node0,
-                               n_nodes=n_nodes, n_bin=n_bin, stride=stride)
+    if build:
+        hist = level_histogram(page_bins, gpair_seg, pos_seg, node0,
+                               n_nodes=n_nodes, n_bin=n_bin, stride=stride,
+                               quantised=quantised)
     else:
         hist = jnp.zeros((n_nodes, 1, 1, 2), jnp.float32)
     return pos_seg, hist
@@ -79,47 +73,11 @@ def _decide_level(state: TreeState, hist, n_bins, cuts_pad, feature_mask,
                   set_matrix, cat_mask, *, depth: int, params: SplitParams,
                   lossguide: bool, last_level: bool):
     """evaluate + record for one level (no position update — pages do that)."""
-    node0 = (1 << depth) - 1
-    N = 1 << depth
-    B = cuts_pad.shape[1]
-    idx = node0 + jnp.arange(N, dtype=jnp.int32)
-    totals_lvl = lax.dynamic_slice_in_dim(state.totals, node0, N, axis=0)
-    alive_lvl = lax.dynamic_slice_in_dim(state.alive, node0, N, axis=0)
-    lower_lvl = lax.dynamic_slice_in_dim(state.lower, node0, N, axis=0)
-    upper_lvl = lax.dynamic_slice_in_dim(state.upper, node0, N, axis=0)
-    w = calc_weight(totals_lvl[:, 0], totals_lvl[:, 1], params, lower_lvl, upper_lvl)
-
-    if last_level:
-        return state._replace(
-            is_leaf=state.is_leaf.at[idx].set(alive_lvl),
-            leaf_val=state.leaf_val.at[idx].set(jnp.where(alive_lvl, params.eta * w, 0.0)),
-            base_weight=state.base_weight.at[idx].set(w),
-            sum_hess=state.sum_hess.at[idx].set(totals_lvl[:, 1]),
-        ), None, None
-
-    compat_lvl = lax.dynamic_slice_in_dim(state.setcompat, node0, N, axis=0)
-    allowed = jnp.einsum("ns,sf->nf", compat_lvl.astype(jnp.float32),
-                         set_matrix.astype(jnp.float32)) > 0.0
-    fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
-    node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
-    has_cat = bool(cat_mask.shape) and cat_mask.shape[0] > 0
-    best = evaluate_splits(hist, totals_lvl, n_bins, params, allowed & fm,
-                           node_bounds, cat_mask=cat_mask if has_cat else None)
-    gamma_eps = max(params.gamma, _EPS)
-    can_split = alive_lvl & (best.gain > gamma_eps)
-    budget = state.splits_left[0]
-    prio = best.gain if lossguide else -idx.astype(jnp.float32)
-    prio = jnp.where(can_split, prio, -jnp.inf)
-    ranks = jnp.argsort(jnp.argsort(-prio)).astype(jnp.int32)
-    can_split = can_split & (ranks < budget)
-    new_budget = budget - jnp.sum(can_split).astype(jnp.int32)
-    new_leaf = alive_lvl & ~can_split
-    thr_lvl = cuts_pad[best.feature, jnp.minimum(best.bin, B - 1)]
-    member = set_matrix.T[jnp.clip(best.feature, 0, set_matrix.shape[1] - 1)]
-    st = _record_level(state, best, idx, can_split, new_leaf, w, thr_lvl,
-                       totals_lvl, compat_lvl, member, new_budget, lower_lvl,
-                       upper_lvl, params)
-    return st, best, can_split
+    return decide_level(
+        state, lambda alive_lvl: (None, hist), cuts_pad, n_bins, feature_mask,
+        set_matrix, cat_mask, (1 << depth) - 1, 1 << depth, params=params,
+        last_level=last_level, lossguide=lossguide,
+        has_cat=bool(cat_mask.shape) and cat_mask.shape[0] > 0)[:3]
 
 
 class StreamingHistTreeGrower:
@@ -190,9 +148,7 @@ class StreamingHistTreeGrower:
             # page bytes stands in for the DMA the CPU backend doesn't have.
             # sleep yields the core, so XLA's async-dispatched page compute
             # proceeds underneath exactly like device compute under a real
-            # transfer — making overlap_gain measurable without TPU.  The
-            # TPU measurement itself is bench.py's extmem phase (prefetch
-            # vs serialized round), unchanged.
+            # transfer — making overlap_gain measurable without TPU.
             import time
 
             time.sleep(arr.nbytes / 1e6 * sim / 1e3)
