@@ -10,9 +10,9 @@ default  what HistTreeGrower.grow really dispatches, each program hashed at
          number formats, 8,192 rows, lowered for the CPU under the device's
          branches (XTB_HIST_IMPL=matmul, XTB_NO_NATIVE_SPLIT=1).
 --mesh   the same for ShardedHistTreeGrower on four forced host devices.
---v5e    root, shared interior and leaf program of each cell at its real
-         shape, lowered for a described v5e (nothing compiles, nothing runs):
-         the text the chip's cache key is made of.
+--v5e    root, shared interior (one a width tier) and leaf program of each
+         cell at its real shape, lowered for a described v5e (nothing
+         compiles, nothing runs): the text the chip's cache key is made of.
 """
 import argparse
 import hashlib
@@ -94,8 +94,8 @@ def dispatched(F, depth, shared, quantised):
     bins, gpair, valid, cuts, nb = data(F)
     g._build(F, B)
     g._init_fn = Recorder(g._init_fn, "init", seen)
-    if getattr(g, "_interior_fn", None) is not None:
-        g._interior_fn = Recorder(g._interior_fn, "interior", seen)
+    for w, fn in g._interior_fns.items():
+        g._interior_fns[w] = Recorder(fn, f"interior width={w}", seen)
     for d in g._level_fns:
         g._level_fns[d] = Recorder(g._level_fns[d], f"level[{d}]", seen)
     bins, gpair, valid = shard_rows(mesh, bins, gpair, valid)
@@ -121,16 +121,16 @@ def for_v5e(F, depth, rows):
             shape((rows, F), jnp.int16), shape((rows, 2), jnp.float32),
             shape((F, B), jnp.float32), shape((F,), jnp.int32),
             shape((1, F), bool), shape((1, F), bool), shape((F,), bool))
-    W = 1 << (depth - 1)
     common = dict(params=PARAMS, axis_name=None, lossguide=False,
                   has_cat=False, quantised=False)
     show("root", grow.level_step.lower(
         *head, None, None, depth=0, last_level=False, subtract=False,
         **common))
     # node0 as HistTreeGrower.grow passes it: a Python int, weakly typed
-    show(f"shared interior width={W}", grow.level_step_padded.lower(
-        *head, shape((W, F, B, 2), jnp.float32), 1, None, width=W,
-        subtract=True, **common))
+    for W in sorted({grow.level_width(d, depth) for d in range(1, depth)}):
+        show(f"shared interior width={W}", grow.level_step_padded.lower(
+            *head, shape((W, F, B, 2), jnp.float32), 1, None, width=W,
+            subtract=True, **common))
     show(f"leaf level depth={depth}", grow.level_step.lower(
         *head, None, None, depth=depth, last_level=True, subtract=False,
         **common))

@@ -106,13 +106,14 @@ def test_fused_hist_kernel_compiles_for_v5e(one_chip, form, bins_dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _level_args(sharding_rows, sharding_rep):
-    """Shapes of one level step's operands at ROWS x 28 x 256, depth 6."""
+def _level_args(sharding_rows, sharding_rep, depth=DEPTH):
+    """Shapes of one level step's operands at ROWS x 28 x 256, depth 6
+    unless told another."""
     from xgboost_tpu.tree.grow import init_tree_state, max_nodes_for_depth
 
     state = jax.eval_shape(
         lambda g, v: init_tree_state(
-            g, v, max_nodes=max_nodes_for_depth(DEPTH), n_bin=B),
+            g, v, max_nodes=max_nodes_for_depth(depth), n_bin=B),
         jax.ShapeDtypeStruct((ROWS, 2), jnp.float32),
         jax.ShapeDtypeStruct((ROWS,), bool))
     state = type(state)(*(
@@ -152,19 +153,22 @@ def test_root_level_program_compiles_for_v5e(one_chip, device_paths):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
 
 
-def test_padded_level_program_compiles_for_v5e(one_chip, device_paths):
-    """``level_step_padded``: the one program every interior depth shares,
-    32 node slots wide at depth 6, the 16 left ones built and the rest
-    subtracted."""
-    from xgboost_tpu.tree.grow import level_step_padded
+@pytest.mark.parametrize("depth", [DEPTH, 8])
+def test_padded_level_program_compiles_for_v5e(one_chip, device_paths, depth):
+    """``level_step_padded``: the program the interior depths of a width
+    share, 32 node slots wide, the 16 left ones built and the rest
+    subtracted.  At depth 6 it is every interior level's; at depth 8, with
+    the 511-slot state, levels 1-5's (``level_width``)."""
+    from xgboost_tpu.tree.grow import level_step_padded, level_width
 
-    W = 1 << (DEPTH - 1)
+    W = level_width(1, depth)
+    assert W == 32
 
     def interior(*args):
         return level_step_padded.__wrapped__(
             *args, width=W, params=_split_params(), subtract=True)
 
-    args = _level_args(one_chip, one_chip) + (
+    args = _level_args(one_chip, one_chip, depth) + (
         _shape((W, F, B, 2), jnp.float32, one_chip),  # hist_prev
         _shape((), jnp.int32, one_chip))              # node0, traced
     compiled = jax.jit(interior).lower(*args).compile()
@@ -191,7 +195,7 @@ def test_sharded_level_program_allreduces_on_four_chips(topo, device_paths,
     W = 1 << (DEPTH - 1)
     args = _level_args(rows, rep) + (
         _shape((W, F, B, 2), jnp.float32, rep), _shape((), jnp.int32, rep))
-    compiled = grower._interior_fn.lower(*args).compile()
+    compiled = grower._interior_fns[W].lower(*args).compile()
     assert "all-reduce" in compiled.as_text()
 
 

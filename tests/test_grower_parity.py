@@ -127,13 +127,16 @@ def test_leaf_positions_match_rows():
     np.testing.assert_allclose(delta_dev, delta_ref, rtol=1e-2, atol=5e-4)
 
 
-@pytest.mark.parametrize("max_depth", [1, 2, 7])
+@pytest.mark.parametrize("max_depth", [1, 2, 7, 8, 9])
 def test_padded_levels_parity_deep(max_depth):
-    """The shared padded interior program (compile-wall fix) must grow
+    """The shared padded interior programs (compile-wall fix) must grow
     identical trees to per-depth programs at depth > 5 — on CPU the default
     flips to per-depth for speed, so pin the padded path explicitly.  The
     edges of the one depth-wise loop: no interior level at depth 1, one of
-    width 2 at depth 2 (both rules' width, two programs)."""
+    width 2 at depth 2 (both rules' width, two programs); and of
+    ``level_width``'s tiers: depth 7 hands the histogram over once (32 ->
+    the cap 64), depth 8 once (32 -> 128), depth 9 twice (32 -> 128 -> the
+    cap 256)."""
     import hashlib
 
     import xgboost_tpu as xtb
@@ -165,3 +168,24 @@ def test_padded_levels_parity_deep(max_depth):
         np.testing.assert_array_equal(np.asarray(getattr(t_pad, name)),
                                       np.asarray(getattr(t_per, name)),
                                       err_msg=name)
+
+
+@pytest.mark.parametrize("max_depth", range(1, 13))
+def test_level_width_holds_the_level_under_the_cap(max_depth):
+    """``level_width``, the one place a shared interior program's width is
+    decided: every interior depth fits, none is wider than the widest
+    interior level, the width never shrinks on the way down (the hand-over
+    pads, it does not cut), the tiers are 32, 128, 512, ..., and up to
+    depth 6 there is one width, as before the tiers."""
+    from xgboost_tpu.tree.grow import level_width
+
+    widths = [level_width(d, max_depth) for d in range(1, max_depth)]
+    cap = 1 << (max_depth - 1)
+    for d, w in zip(range(1, max_depth), widths):
+        assert (1 << d) <= w <= cap, (d, w)
+        assert w == cap or w in (32, 128, 512, 2048), (d, w)
+    assert widths == sorted(widths)
+    if max_depth <= 6:
+        assert set(widths) <= {cap}
+    if max_depth == 8:
+        assert widths == [32] * 5 + [128] * 2

@@ -31,17 +31,20 @@ def problem():
     return ell, jnp.asarray(gp), jnp.asarray(valid)
 
 
-@pytest.mark.parametrize("shared_width", [True, False])
+@pytest.mark.parametrize("shared_width,max_depth",
+                         [(True, 4), (False, 4), (True, 7)])
 def test_sharded_tree_identical_to_single(problem, eight_devices, monkeypatch,
-                                          shared_width):
+                                          shared_width, max_depth):
     """Under both width rules of the one depth-wise loop the mesh grower
-    inherits: one padded interior program, and a program a depth."""
+    inherits: padded interior programs, and a program a depth.  Depth 7
+    crosses a tier of ``level_width``: two interior programs (32 and 64
+    slots) and the histogram handed over between them."""
     monkeypatch.setattr("xgboost_tpu.tree.grow.default_padded_levels",
                         lambda max_depth: shared_width)
     ell, gp, valid = problem
     params = SplitParams(0.3, 0.0, 1.0, 1.0, 0.0, 0.0)
 
-    single = HistTreeGrower(4, params)
+    single = HistTreeGrower(max_depth, params)
     s1 = single.grow(ell.bins, gp, valid, ell.cuts_pad, ell.n_bins)
 
     mesh = make_mesh(8)
@@ -51,7 +54,9 @@ def test_sharded_tree_identical_to_single(problem, eight_devices, monkeypatch,
     gp_s = jax.device_put(gp, row2d)
     valid_s = jax.device_put(valid, row1d)
 
-    multi = ShardedHistTreeGrower(4, params, mesh)
+    multi = ShardedHistTreeGrower(max_depth, params, mesh)
+    multi._build(ell.bins.shape[1], ell.cuts_pad.shape[1])
+    assert len(multi._interior_fns) == (2 if max_depth == 7 else 1)
     s8 = multi.grow(bins_s, gp_s, valid_s, ell.cuts_pad, ell.n_bins)
 
     np.testing.assert_array_equal(np.asarray(s1.feat), np.asarray(s8.feat))

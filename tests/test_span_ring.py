@@ -40,6 +40,9 @@ def test_recent_carries_parent_round_and_arguments():
     assert [r["parent"] for r in by_name["update.gradient"]] == ["train.round"] * 2
     assert {r["parent"] for r in by_name["grow.to_host"]} == {"update.update_tree"}
     assert [r["depth"] for r in by_name["grow.build_hist+eval_split"]] == [0, 1, 2, 3] * 2
+    # the slots a level was dispatched at: the interior levels of a depth-3
+    # tree share one program, as wide as the widest of them
+    assert [r["width"] for r in by_name["grow.build_hist+eval_split"]] == [1, 4, 4, 8] * 2
     assert all(r["copies"] == 12 for r in by_name["grow.to_host"])
     assert all("parent" not in r for r in by_name["train.round"])
     assert [r["round"] for r in by_name["train.after_iteration"]] == [1, 2]

@@ -18,8 +18,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.split import SplitParams
 from ..tree.grow import (HistTreeGrower, TreeState, init_tree_state,
-                         level_step, level_step_padded, make_set_matrix,
-                         max_nodes_for_depth)
+                         level_step, level_step_padded, level_width,
+                         make_set_matrix, max_nodes_for_depth)
 from .mesh import DATA_AXIS
 
 
@@ -94,9 +94,10 @@ class ShardedHistTreeGrower(HistTreeGrower):
                 out_specs=(sspec, P())))
 
         md = self.max_depth
-        self._interior_fn = (
-            program(level_step_padded, 2, width=1 << (md - 1), subtract=True)
-            if md >= 2 else None)
+        # a shared interior program a width tier, as the loop asks for them
+        self._interior_fns = {
+            w: program(level_step_padded, 2, width=w, subtract=True)
+            for w in sorted({level_width(d, md) for d in range(1, md)})}
         self._level_fns = {
             d: program(level_step, 1, depth=d, last_level=(d == md),
                        subtract=(0 < d < md))
@@ -108,12 +109,13 @@ class ShardedHistTreeGrower(HistTreeGrower):
         self._build(setmat.shape[1], cuts_pad.shape[1], has_cat)
         return self._init_fn(gpair, valid)
 
-    def _run_level(self, d: int, shared: bool, state, page, fm, setmat, cm,
+    def _run_level(self, d: int, width, state, page, fm, setmat, cm,
                    hist_prev, rho, has_cat: bool):
         rho_args = () if rho is None else (rho,)
-        if shared:
-            return self._interior_fn(state, *page, fm, setmat, cm, hist_prev,
-                                     jnp.int32((1 << d) - 1), *rho_args)
+        if width is not None:
+            return self._interior_fns[width](
+                state, *page, fm, setmat, cm, hist_prev,
+                jnp.int32((1 << d) - 1), *rho_args)
         return self._level_fns[d](state, *page, fm, setmat, cm, hist_prev,
                                   *rho_args)
 

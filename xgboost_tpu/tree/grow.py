@@ -15,8 +15,9 @@ the tree-array writes) -> route (``_update_positions``, the RowPartitioner
 analogue, src/tree/gpu_hist/row_partitioner.cuh — here an elementwise ``pos``
 rewrite, no physical partition).  It has two jitted entry points, because a
 static and a traced ``node0`` are two programs: ``level_step`` (a program a
-depth) and ``level_step_padded`` (one program for every interior depth); the
-compile cache is shared across all trees and boosting rounds.
+depth) and ``level_step_padded`` (one program a width, shared by the interior
+depths that ``level_width`` pads to it); the compile cache is shared across
+all trees and boosting rounds.
 ``HistTreeGrower.grow`` is the one depth-wise loop; parallel/grower.py
 inherits it and wraps each program in ``shard_map``, with ``lax.psum`` on the
 histogram (the reference's AllReduceHist,
@@ -372,7 +373,7 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
     """One level, the only place it is written: histogram -> decide -> route
     over the ``N`` heap slots from ``node0``, a Python int (``level_step``: a
     program a depth) or a traced scalar (``level_step_padded``: one program
-    for every interior depth).
+    for every interior depth of a width).
 
     Mirrors one driver iteration of the reference
     (updater_gpu_hist.cu:626-646: PartitionAndBuildHist + ReduceHist +
@@ -401,8 +402,8 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
                                        quantised=quantised)
                 if axis_name is not None:
                     left = lax.psum(left, axis_name)
-                # a parent level as wide as this one (the shared width) has
-                # its N/2 real rows first
+                # a parent level handed over at this level's width has its
+                # real rows first, and they are N/2 at most
                 hist = combine_sibling_hists(left, hist_prev[:half], alive_lvl)
             else:
                 hist = level_histogram(bins, gpair, state.pos, node0,
@@ -491,16 +492,14 @@ def level_step_padded(
 ):
     """``_level`` with the node dimension PADDED to a fixed ``width`` and
     a TRACED ``node0`` — ONE compiled program serves every interior depth
-    (VERDICT r3 #4: the per-depth compile wall).
+    that is dispatched at that width (VERDICT r3 #4: the per-depth compile
+    wall).
 
-    ``width`` = 2**(max_depth-1), the widest interior level.  What the
-    padding costs on the chip: every depth builds ``width // 2`` left
-    children (ops/histogram.py: one-hot matmul at HIGHEST, the one-hot built
-    feature-major inside the matmul's fusion), and that fusion is nearly all
-    of a level's time.  It is almost flat from one built node to sixteen
-    (0.154 and 0.168 s a level at 10.5M x 28) and twice that at sixty-four
-    (0.328 s, depth 8: PERF.md §5), so up to depth 6 the padding is nearly
-    free and beyond it a shallow level pays the widest level's matmul.  On
+    ``width`` >= 2**depth is ``level_width``'s to choose, and the reasons
+    are there.  What the padding costs on the chip: a level builds
+    ``width // 2`` left children whatever its depth (ops/histogram.py:
+    one-hot matmul at HIGHEST, the one-hot built feature-major inside the
+    matmul's fusion), and that fusion is nearly all of a level's time.  On
     the CPU the row-pass kernels add only where a row's node matches, and
     the padding costs the wider output block alone.
 
@@ -552,16 +551,43 @@ class GrownTree(NamedTuple):
     totals: "object"
 
 
+# The narrowest width a shared interior program is padded to: 32 slots = 16
+# built left children = 32 output columns of the histogram's matmul.  Up to
+# there the chip's cost of a level is flat (10.5M x 28: the matmul 0.154 s a
+# level for one built node, 0.168 s for sixteen; 0.165 and 0.173 s at 136
+# columns); beyond it the matmul is paid for: a whole level of a depth-8
+# tree takes 0.2006 s at 32 slots, 0.213 s at 64 and 0.3756 s at 128, 89% of
+# the three-pass bfloat16 peak (PERF.md §5, §7).
+_WIDTH_FLOOR = 32
+
+
+def level_width(d: int, max_depth: int) -> int:
+    """Slots the shared program of interior depth ``d`` is padded to; the
+    only place that is decided.  The smallest of 32, 128, 512, ... that holds
+    the level's ``2**d`` nodes, and never more than the widest interior
+    level, ``2**(max_depth-1)``: up to depth 6 one width, as it always was.
+
+    Steps of x4 and not x2: every tier is one more program with a scan over
+    all the rows in it, and at 10.5M rows such a program compiles cold for
+    about five minutes (ROADMAP S12)."""
+    width = _WIDTH_FLOOR
+    while width < (1 << d):
+        width *= 4
+    return min(1 << (max_depth - 1), width)
+
+
 def default_padded_levels(max_depth: int) -> bool:
-    """Platform rule for sharing ONE padded interior level program across
-    depths: on accelerators the padding rides the 128-lane MXU tile for
-    free and killing the per-depth compile wall matters.  On CPU the rule
-    depends on the histogram impl: the native/scatter row-pass kernels add
-    only for rows whose node matches, so a padded node dimension costs just
-    the wider (memset) output block and the shared program wins there too;
-    only the forced matmul impl still pays the full padded operand width
-    at every depth (r5: the bench compile_est 8.8s -> ~4s came from
-    extending this to the CPU default)."""
+    """Platform rule for sharing padded interior level programs across
+    depths (one a width tier of ``level_width``) in place of a program a
+    depth: on accelerators the padding is nearly free up to the narrowest
+    tier's width (``_WIDTH_FLOOR`` has the readings) and killing the
+    per-depth compile wall matters.  On CPU the rule depends on the
+    histogram impl: the native/scatter row-pass kernels add only for rows
+    whose node matches, so a padded node dimension costs just the wider
+    (memset) output block and the shared program wins there too; only the
+    forced matmul impl still pays the full padded operand width at every
+    depth (r5: the bench compile_est 8.8s -> ~4s came from extending this
+    to the CPU default)."""
     if jax.default_backend() != "cpu" or max_depth <= 5:
         return True
     # native/scatter row-pass kernels: padding costs only the padded hist
@@ -599,9 +625,10 @@ class HistTreeGrower:
         # topology (chips x processes) — the GradientQuantiser contract
         # (src/tree/gpu_hist/quantiser.cuh); see ops/quantise.py
         self.quantised = quantised
-        # one shared compiled program for all interior depths (padded node
-        # dim + traced node0) instead of one per depth; None = the
-        # platform's rule (default_padded_levels has the reasons)
+        # shared compiled programs for the interior depths (padded node
+        # dim + traced node0, one a width of level_width) instead of one
+        # per depth; None = the platform's rule (default_padded_levels has
+        # the reasons)
         if padded_levels is None:
             padded_levels = default_padded_levels(max_depth)
         self.padded_levels = padded_levels
@@ -615,19 +642,20 @@ class HistTreeGrower:
             max_splits=(self.max_leaves - 1) if self.max_leaves > 0 else 0,
         )
 
-    def _run_level(self, d: int, shared: bool, state, page, fm, setmat, cm,
-                   hist_prev, rho, has_cat: bool):
-        """Dispatch depth ``d``'s program: ``(state, hist)``.  3 compiled
-        programs regardless of depth where the interior levels share a
-        width (root, shared padded interior with a traced node0, leaf
-        finalize), else one a depth."""
+    def _run_level(self, d: int, width: Optional[int], state, page, fm,
+                   setmat, cm, hist_prev, rho, has_cat: bool):
+        """Dispatch depth ``d``'s program: ``(state, hist)``.  With a
+        ``width``, the shared padded interior program of that width with a
+        traced node0: root, leaf finalize and one program a tier of
+        ``level_width`` (one up to depth 6, two up to depth 8), however
+        deep the tree.  With None, a program a depth."""
         md = self.max_depth
         common = dict(params=self.params, lossguide=self.lossguide,
                       has_cat=has_cat, quantised=self.quantised)
-        if shared:
+        if width is not None:
             return level_step_padded(
                 state, *page, fm, setmat, cm, hist_prev, (1 << d) - 1, rho,
-                width=1 << (md - 1), subtract=self.subtract, **common)
+                width=width, subtract=self.subtract, **common)
         return level_step(
             state, *page, fm, setmat, cm, hist_prev, rho, depth=d,
             last_level=(d == md), subtract=(self.subtract and 0 < d < md),
@@ -654,24 +682,28 @@ class HistTreeGrower:
         hist = None
         for d in range(md + 1):
             # the root and the leaf level have programs of their own; the
-            # levels between are 2**d slots wide, or all as wide as the
-            # widest of them
-            shared = self.padded_levels and 0 < d < md
+            # levels between are 2**d slots wide, or padded to the width of
+            # their tier
+            width = (level_width(d, md)
+                     if self.padded_levels and 0 < d < md else None)
             fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-            if shared:
-                W = 1 << (md - 1)
-                fm = self._pad_mask(fm, W)
-                if d == 1:
-                    # the root's histogram, handed over at that width
-                    hist = jnp.zeros((W,) + hist.shape[1:],
-                                     hist.dtype).at[:1].set(hist)
+            if width is not None:
+                fm = self._pad_mask(fm, width)
+                if hist.shape[0] != width:
+                    # the parent level's histogram (the root's, or a
+                    # narrower tier's), handed over at this level's width:
+                    # its real rows first, zero rows after
+                    hist = jnp.zeros((width,) + hist.shape[1:],
+                                     hist.dtype).at[:hist.shape[0]].set(hist)
             # one span per level: the compiled program fuses build_hist +
             # eval_split + the position rewrite, so the bracket necessarily
             # covers all three — the name keeps the reference phase vocabulary
-            # greppable in traces (bestfirst.py times the phases separately)
-            with span("grow.build_hist+eval_split", depth=d):
+            # greppable in traces (bestfirst.py times the phases separately);
+            # width = the slots the level was dispatched at
+            with span("grow.build_hist+eval_split", depth=d,
+                      width=width or (1 << d)):
                 state, hist = self._run_level(
-                    d, shared, state, page, fm, setmat, cm,
+                    d, width, state, page, fm, setmat, cm,
                     None if d == md else hist, rho, has_cat)
         return state
 
