@@ -13,6 +13,10 @@ default  what HistTreeGrower.grow really dispatches, each program hashed at
 --v5e    root, shared interior (one a width tier) and leaf program of each
          cell at its real shape, lowered for a described v5e (nothing
          compiles, nothing runs): the text the chip's cache key is made of.
+
+In every mode the best-first pass of the cell higgs-leafwise-255.train
+(tree/bestfirst.py ``level_step_bestfirst``) comes last, where the checkout
+has one.
 """
 import argparse
 import hashlib
@@ -136,6 +140,34 @@ def for_v5e(F, depth, rows):
         **common))
 
 
+def bestfirst_pass(F, rows, leaves, sharding=None):
+    """The one program of a best-first tree, as BestFirstGrower.grow calls
+    it with no column sampling and no constraint."""
+    from xgboost_tpu.tree import bestfirst
+
+    if not hasattr(bestfirst, "level_step_bestfirst"):
+        print("  (this checkout has no level_step_bestfirst)")
+        return
+    params = PARAMS._replace(min_child_weight=100.0)
+    g = bestfirst.BestFirstGrower(0, params, max_leaves=leaves)
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+
+    state = jax.eval_shape(
+        lambda pos, root: bestfirst._init_state(
+            pos, root, S=g._grow_slots, F=F, B=B, n_sets=1),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.float32))
+    show(f"best-first pass pairs={g.pairs}", bestfirst.level_step_bestfirst.lower(
+        type(state)(*(shape(x.shape, x.dtype) for x in state)),
+        shape((rows, F), jnp.int16), shape((rows, 2), jnp.float32),
+        shape((F,), jnp.int32), shape((1, F), bool), shape((1, 2, F), bool),
+        shape((1, F), bool), shape((F,), bool), pairs=g.pairs,
+        max_leaves=leaves, max_depth=0, gamma_eps=1e-6, params=params,
+        has_cat=False, monotone=False))
+
+
 LEVEL_STEP, LEVEL_STEP_PADDED = grow.level_step, grow.level_step_padded
 if args.v5e:
     # the cells of BENCHMARK.json: columns, depth, rows as the page pads them
@@ -144,6 +176,14 @@ if args.v5e:
                                  ("mslr-web30k-ndcg.train", 136, 6, 2_271_232)):
         print(f"{cell}: {rows} x {F}, depth {depth}, for a described v5e")
         for_v5e(F, depth, rows)
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    print("higgs-leafwise-255.train: 10500096 x 28, 255 leaves, for a "
+          "described v5e")
+    bestfirst_pass(28, 10_500_096, 255, SingleDeviceSharding(
+        topologies.get_topology_desc(platform="tpu",
+                                     topology_name="v5e:2x2").devices[0]))
 else:
     cases = [(28, 6, True, False), (28, 8, True, False), (136, 6, True, False),
              (28, 6, False, False), (28, 6, True, True), (28, 4, False, True),
@@ -154,3 +194,6 @@ else:
               f"{'shared width' if shared else 'a program a depth'}"
               f"{', int8 limbs' if quantised else ''}")
         dispatched(F, depth, shared, quantised)
+    if not args.mesh:
+        print("F=28 best-first, 255 leaves")
+        bestfirst_pass(28, 8192, 255)

@@ -175,6 +175,46 @@ def test_padded_level_program_compiles_for_v5e(one_chip, device_paths, depth):
     assert "custom_call_target=\"xtb_" not in compiled.as_text()
 
 
+def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
+    """``level_step_bestfirst`` at 28 columns, 256 bins and the cell's budget
+    of 255 leaves: 32 pairs a pass, the one-hot matmul for the 32 built
+    children, the packed-table route, the replayed queue.  Nothing in it may
+    gather or scatter a row-sized array (PERF.md, PR 27: 0.05-0.1 s each at
+    10.5M rows), and no host kernel may be traced into it."""
+    import re
+
+    from xgboost_tpu.ops.split import SplitParams
+    from xgboost_tpu.tree import bestfirst
+
+    params = SplitParams(eta=0.1, gamma=0.0, min_child_weight=100.0,
+                         lambda_=1.0, alpha=0.0, max_delta_step=0.0)
+    grower = bestfirst.BestFirstGrower(0, params, max_leaves=255)
+    assert grower.pairs == 32
+    state = jax.eval_shape(
+        lambda pos, root: bestfirst._init_state(
+            pos, root, S=grower._grow_slots, F=F, B=B, n_sets=1),
+        jax.ShapeDtypeStruct((ROWS,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.float32))
+    state = type(state)(*(_shape(s.shape, s.dtype, one_chip) for s in state))
+
+    def one_pass(*args):  # a fresh function: a fresh trace under device_paths
+        return bestfirst.level_step_bestfirst.__wrapped__(
+            *args, pairs=grower.pairs, max_leaves=255, max_depth=0,
+            gamma_eps=1e-6, params=params, has_cat=False, monotone=False)
+
+    compiled = jax.jit(one_pass).lower(
+        state, _shape((ROWS, F), jnp.int16, one_chip),
+        _shape((ROWS, 2), jnp.float32, one_chip),
+        _shape((F,), jnp.int32, one_chip), _shape((1, F), bool, one_chip),
+        _shape((1, 2, F), bool, one_chip), _shape((1, F), bool, one_chip),
+        _shape((F,), bool, one_chip)).compile()
+    text = compiled.as_text()
+    assert "custom_call_target=\"xtb_" not in text
+    moved = re.findall(r"= \w+\[(\d+)[\],][^\n]* (?:gather|scatter)\(", text)
+    assert moved and max(int(n) for n in moved) <= grower._grow_slots, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
+
+
 def test_sharded_level_program_allreduces_on_four_chips(topo, device_paths,
                                                         monkeypatch):
     """What ``n_devices=4`` runs: the padded level step under shard_map on a

@@ -161,6 +161,47 @@ def test_level_program_names_its_node_work_too(toy):
     assert not loose, f"traced operations outside every scope: {sorted(loose)}"
 
 
+def bestfirst_hlo(toy, finish=False) -> str:
+    from xgboost_tpu.tree import bestfirst
+
+    a = toy
+    grower = bestfirst.BestFirstGrower(0, a["params"], max_leaves=12)
+    state = bestfirst._init_state(
+        jnp.zeros(R, jnp.int32), jnp.zeros(2, jnp.float32),
+        S=grower._grow_slots, F=F, B=B, n_sets=1)
+    assert grower._grow_slots < R  # no slot table reaches R elements
+    if finish:
+        return bestfirst._finish.lower(
+            state, n_slots=grower.n_slots).compile().as_text()
+    return bestfirst.level_step_bestfirst.lower(
+        state, a["bins"], a["gpair"], a["nb"], a["ones"],
+        jnp.ones((1, 2, F), bool), a["setm"], a["cm"], pairs=grower.pairs,
+        max_leaves=12, max_depth=0, gamma_eps=1e-6, params=a["params"],
+        has_cat=False, monotone=False).compile().as_text()
+
+
+def test_bestfirst_pass_names_its_work_and_gathers_nothing_row_sized(toy):
+    """The best-first pass (tree/bestfirst.py): its row-sized work is the
+    route and the histogram; its node work the queue's two ends, the split
+    scan and the block of slots written; nothing traced is left unscoped."""
+    hlo = bestfirst_hlo(toy)
+    # (the slots' histograms, written under ``record``, outgrow R at toy size)
+    assert {"hist", "route"} <= set(scopes_of_row_sized(hlo)) <= {
+        "hist", "route", "record"}
+    assert not row_sized_gathers(hlo, "route")
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert {"queue", "route", "hist", "split", "record"} <= {
+        xplane.scope_of(n) for n in names}
+    loose = {n for n in names if xplane.scope_of(n) == xplane.UNSCOPED
+             and n.startswith("jit(")}
+    assert not loose, f"traced operations outside every scope: {sorted(loose)}"
+    # (the compiler's own tree of the select's reduce carries no op_name)
+    finish = bestfirst_hlo(toy, finish=True)
+    assert "route" in {xplane.scope_of(n) for n in
+                       re.findall(r'op_name="([^"]*)"', finish)}
+    assert not row_sized_gathers(finish, "route")
+
+
 def test_margin_update_is_scoped(toy):
     st = toy["state"]
     hlo = grow.leaf_margin_delta.lower(st.pos, st.leaf_val).compile().as_text()

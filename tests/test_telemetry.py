@@ -169,6 +169,25 @@ def test_span_records_phase_histogram():
     assert 'phase="t_unit.phase"' in telemetry.render_prometheus()
 
 
+def test_count_in_round_sums_on_the_round_span_and_nowhere_else():
+    """Counters a grower adds land among the arguments of the round span
+    that is open, summed over the round; outside a round nothing is kept."""
+    from xgboost_tpu.telemetry import flight
+
+    flight.clear()
+    _spans.count_in_round(passes=9)  # no round open: dropped
+    for i in (0, 1):
+        with _spans.step_span("train.round", i):
+            with _spans.span("update.update_tree"):
+                _spans.count_in_round(passes=2, rows=100)
+                _spans.count_in_round(passes=3, rows=50)
+    _spans.count_in_round(passes=9)
+    rounds = _spans.recent("train.round")
+    assert [(r["round"], r["passes"], r["rows"]) for r in rounds] == [
+        (0, 5, 150), (1, 5, 150)]
+    assert "passes" not in _spans.recent("update.update_tree")[0]
+
+
 def test_monitor_shim_reentrant_and_totals():
     """utils/timer.Monitor: stacked start/stop (the re-entrancy satellite)
     feeding the same phase histogram when telemetry is enabled."""

@@ -670,9 +670,14 @@ class Booster:
 
     def _resolve_max_depth(self, lossguide: bool) -> int:
         """Default depth cap for the level-synchronous growers when
-        max_depth<=0: 10 heap levels under lossguide (static shapes), 6
-        depthwise (the reference's default max_depth).  The best-first
-        grower resolves 0 as "unbounded" instead and does not use this."""
+        max_depth<=0: 6 depthwise (the reference's default max_depth), 10
+        heap levels under lossguide.  The latter is the round-1
+        approximation of lossguide (a split budget a level over a heap), and
+        only ``max_leaves <= 1`` still reaches it (no leaf budget, so nothing
+        for a priority queue to spend) and the paged grower
+        (tree/stream.py): lossguide with a leaf budget is the best-first
+        grower's (tree/bestfirst.py), the serial driver's tree, which
+        resolves 0 as "unbounded" and does not come here."""
         md = self.tparam.max_depth
         if md <= 0:
             md = 10 if lossguide else 6

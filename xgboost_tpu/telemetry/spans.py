@@ -32,9 +32,9 @@ import jax.profiler as _profiler
 from . import flight, trace
 from .registry import get_registry
 
-__all__ = ["span", "step_span", "recent", "current_round", "enable",
-           "disable", "enabled", "record_phase", "Span", "phase_totals",
-           "PHASE_HISTOGRAM"]
+__all__ = ["span", "step_span", "recent", "current_round", "count_in_round",
+           "enable", "disable", "enabled", "record_phase", "Span",
+           "phase_totals", "PHASE_HISTOGRAM"]
 
 PHASE_HISTOGRAM = "xtb_phase_seconds"
 
@@ -52,6 +52,7 @@ class _Open(threading.local):
     def __init__(self) -> None:
         self.names: List[str] = []
         self.round: Optional[int] = None
+        self.step: Optional["Span"] = None  # the round span, while open
 
 
 _open = _Open()
@@ -74,6 +75,16 @@ def disable() -> None:
 def current_round() -> Optional[int]:
     """The training round this thread is in, None outside one."""
     return _open.round
+
+
+def count_in_round(**counts: int) -> None:
+    """Add ``counts`` to the counters that the round span open on this
+    thread carries as its arguments (its ring record holds the round's sums);
+    outside a round nothing is counted."""
+    if _open.step is not None:
+        args = _open.step.args
+        for key, n in counts.items():
+            args[key] = args.get(key, 0) + n
 
 
 def _hist():
@@ -169,7 +180,12 @@ class _StepSpan(Span):
 
     def begin(self) -> "Span":
         self.args["seq0"] = flight.seq()
+        _open.step = self
         return super().begin()
+
+    def end(self) -> int:
+        _open.step = None
+        return super().end()
 
 
 def span(name: str, **args: Any) -> Span:

@@ -33,8 +33,8 @@ import sys
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 #: The device scopes of the training path (docs/observability.md has the table).
-SCOPES = ("hist", "split", "record", "route", "margin", "predict", "bin",
-          "gradient")
+SCOPES = ("hist", "split", "record", "route", "queue", "margin", "predict",
+          "bin", "gradient")
 UNSCOPED = "(unscoped)"
 NO_SPAN = "(no span)"
 #: An idle gap shorter than this is the device's own turn-around, not the host's.

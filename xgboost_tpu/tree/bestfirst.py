@@ -3,215 +3,527 @@
 TPU-native equivalent of the reference's Driver priority queue
 (src/tree/driver.h:30) + lossguide updater behavior: expand the single
 highest-gain leaf anywhere in the tree, repeat until the ``max_leaves``
-budget or no positive gain remains.  The round-1 grower approximated this
-with a per-level budget over a heap layout, capping growth at 2^10 slots;
-here the tree lives in a flat node TABLE (2*max_leaves slots, ids in
-creation order), so depth is bounded only by ``max_depth`` (0 = unbounded)
-and max_leaves can be arbitrarily large.
+budget or no positive gain remains.  The tree is the serial driver's, node
+ids in pop order, children ``(n, n+1)``, depth bounded only by ``max_depth``
+(0 = unbounded); how it is reached is not serial.
 
-Per expansion the device work is: route the chosen node's rows (elementwise
-``pos`` rewrite), one histogram matmul for BOTH children (ids are
-consecutive, so the standard kernel covers them with n_nodes=2), and a
-2-node split evaluation.  The host loop pulls one scalar (chosen node +
-gain) per step — the same sequential shape as the reference's driver pop.
+*Evaluate ahead, commit in order.*  The histogram's one-hot matmul costs a
+pass over every row whether it builds one node or sixteen (PERF.md §5), so a
+pass (``level_step_bestfirst``, one jitted program) takes the ``pairs``
+nodes the driver is likeliest to pop next among those whose children are not
+known yet (a node the tree does not hold yet among them: it is popped no
+sooner than its ancestors, so its rank is the least gain on its way down from
+the tree), routes their rows, builds the smaller child of each pair from the
+rows (``level_histogram``, the sibling as parent minus child from the
+histogram kept for every unsplit node) and scans both children for their
+best splits (``evaluate_splits``).  Then the serial driver is replayed on the
+device over what is now known: pop the open leaf of highest gain; if its
+children were evaluated, commit the split (the children take the next two
+ids) and go on; stop at the first popped leaf whose children are not.  The
+best open leaf is always the first of the ``pairs``, so a pass commits at
+least one split, and a node's candidate is a function of its own histogram
+alone, so a pair that was evaluated and never popped costs its columns of
+one pass and nothing else: its rows sit below their leaf and take the
+leaf's value.  A tree needs at least its depth in passes; how many follows
+its shape (the queue stalls wherever a child just made is the next to be
+popped).  A tree that spends its whole budget is given the same number of
+passes whatever its shape (``_SPARE``), so that a round costs the same
+every time.
+
+While it grows the tree lives in SLOTS in evaluation order (a pass writes
+its children as one contiguous block, the built child first); ``_finish``
+puts nodes and rows into pop order.  The host reads three integers a pass
+(done, splits, slots in use) and decides nothing but whether the tree needs
+another; it sends the next pass before it reads the last.
 """
 from __future__ import annotations
 
+import collections
 import functools
-from typing import NamedTuple, Optional
+import math
+from typing import NamedTuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..models.tree import RegTree
-from ..ops.histogram import build_histogram_at, node_sums
-from ..ops.split import SplitParams, calc_weight, evaluate_splits
+from ..ops.histogram import (combine_sibling_hists, hist_is_row_pass,
+                             level_histogram, node_sums)
+from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
+from ..telemetry.spans import count_in_round
+from .grow import _update_positions, make_set_matrix
 
 _EPS = 1e-6
+# Pairs of children a pass evaluates.  The tree does not depend on it
+# (tests/test_bestfirst.py); the passes a tree takes and what a pass costs
+# do.  A 255-leaf tree on HIGGS (depth 12-14, so 13-15 passes at the least)
+# takes 36-38 passes at 8 pairs, 22-23 at 16, 17-18 at 24, 15-16 at 32 and
+# 14-15 at 48 and at 64 (CPU, 1M rows; 21-24 at 16 on the chip at 10.5M);
+# a pass over 10.5M x 28 costs 0.2019 s at 16 built nodes (32 output columns
+# of the matmul), 0.213 s at 32 (64 columns) and 0.3756 s at 64 (PERF.md §5):
+# the product is least at 32.
+_PAIRS = 32
+# How far below the tree a node's rank in the queue is followed exactly.
+_WAIT = 8
+# A tree that spends its budget runs ``_least_passes * (1 + _SPARE)`` passes
+# whatever its shape: 18 at 255 leaves and 32 pairs, where the trees of HIGGS
+# need 15 to 18 (mean 15.7; PERF.md §6).  The passes beyond a tree's own do
+# no work that the tree needs: they are there so that a round takes the same
+# time on every tree and seed, which the benchmark's admission of a cell asks
+# (a spread under 0.5% over seeds, where three data-dependent trees a window
+# spread 3%), and they cost 12% of the rate.  A tree that stops short of its
+# budget is given none.  Take this out (the constant, ``_least_passes``,
+# ``BestFirstGrower.passes`` and the second half of the loop's condition)
+# once the benchmark's window can hold a cell whose work follows its data.
+_SPARE = 0.375
+
+
+def _least_passes(max_leaves: int, pairs: int) -> int:
+    """The passes no tree that spends its budget can do without: the root's,
+    then as many pairs a pass as there are open leaves, ``pairs`` at most,
+    every one of them committed."""
+    passes, splits = 1, 0
+    while splits < max_leaves - 1:
+        splits += min(pairs, splits + 1)
+        passes += 1
+    return passes
 
 
 class BFState(NamedTuple):
-    pos: jnp.ndarray        # (R_pad,) int32 — table node id per row
-    # tree arrays, creation order (root 0)
-    parent: jnp.ndarray     # (N,) int32
-    left: jnp.ndarray       # (N,) int32, -1 = leaf/unused
-    right: jnp.ndarray      # (N,) int32
-    depth: jnp.ndarray      # (N,) int32
-    feat: jnp.ndarray       # (N,) int32
-    sbin: jnp.ndarray       # (N,) int32
-    dleft: jnp.ndarray      # (N,) bool
-    gain: jnp.ndarray       # (N,) f32 — recorded loss_chg of applied splits
-    totals: jnp.ndarray     # (N, 2) f32
-    lower: jnp.ndarray      # (N,) f32 monotone bounds
-    upper: jnp.ndarray      # (N,) f32
-    setcompat: jnp.ndarray  # (N, n_sets) bool
-    is_cat: jnp.ndarray     # (N,) bool
-    cat_set: jnp.ndarray    # (N, B) bool
-    # candidate split per OPEN leaf (computed when the node was created)
-    cand_gain: jnp.ndarray  # (N,) f32, -inf when closed/invalid
-    cand_feat: jnp.ndarray  # (N,) int32
-    cand_bin: jnp.ndarray   # (N,) int32
-    cand_dleft: jnp.ndarray  # (N,) bool
-    cand_lsum: jnp.ndarray  # (N, 2)
-    cand_rsum: jnp.ndarray  # (N, 2)
-    cand_lw: jnp.ndarray    # (N,) f32 clipped child weights
-    cand_rw: jnp.ndarray    # (N,) f32
-    cand_is_cat: jnp.ndarray  # (N,) bool
-    cand_cat_set: jnp.ndarray  # (N, B) bool
+    """The tree under construction, by slot (module docstring).  A node is
+    *committed* once the replay has given it its id (``fid >= 0``), *split*
+    once its own split is committed, *expanded* once its children have slots
+    (``left >= 0``); an open leaf is committed and not split."""
+
+    pos: jnp.ndarray        # (R_pad,) int32 — slot per row, -1 = padding
+    hist: jnp.ndarray       # (S, F, B, 2) f32 — of every node not yet split
+    parent: jnp.ndarray     # (S,) int32 slot
+    left: jnp.ndarray       # (S,) int32 slot of the left child, -1 before
+    right: jnp.ndarray      # (S,) int32
+    fid: jnp.ndarray        # (S,) int32 — id in pop order, -1 before
+    split: jnp.ndarray      # (S,) bool
+    depth: jnp.ndarray      # (S,) int32
+    totals: jnp.ndarray     # (S, 2) f32
+    lower: jnp.ndarray      # (S,) f32 monotone bounds
+    upper: jnp.ndarray      # (S,) f32
+    setcompat: jnp.ndarray  # (S, n_sets) bool
+    path: jnp.ndarray       # (S,) uint32 — the way down from the root, hashed
+    # the node's best split, known as soon as its histogram is
+    cand_gain: jnp.ndarray  # (S,) f32, -inf where there is none
+    cand_feat: jnp.ndarray  # (S,) int32
+    cand_bin: jnp.ndarray   # (S,) int32
+    cand_dleft: jnp.ndarray  # (S,) bool
+    cand_lsum: jnp.ndarray  # (S, 2)
+    cand_rsum: jnp.ndarray  # (S, 2)
+    cand_lw: jnp.ndarray    # (S,) f32 clipped child weights
+    cand_rw: jnp.ndarray    # (S,) f32
+    cand_is_cat: jnp.ndarray  # (S,) bool
+    cand_cat_set: jnp.ndarray  # (S, B) bool
+    n_alloc: jnp.ndarray    # () int32 — slots in use (0: the root is to come)
+    n_splits: jnp.ndarray   # () int32 — splits committed
+    done: jnp.ndarray       # () bool — budget spent or no gain left
+    told: jnp.ndarray       # (3,) int32 — done, n_splits, n_alloc: the
+    #                         host's one read a pass
 
 
-@functools.partial(jax.jit, static_argnames=("params", "max_depth", "has_cat",
-                                             "n"))
-def _eval_nodes(state: BFState, hist, cuts_pad, n_bins, feature_mask,
-                set_matrix, cat_mask, i0, *, n: int, params: SplitParams,
-                max_depth: int, has_cat: bool):
-    """Compute split candidates for the (consecutive) node ids [i0, i0+n)
-    from their (already cross-rank-reduced) histogram."""
-    ids = i0 + jnp.arange(n, dtype=jnp.int32)
-    totals = state.totals[ids]
-    compat = state.setcompat[ids]
-    allowed = jnp.einsum("ns,sf->nf", compat.astype(jnp.float32),
-                         set_matrix.astype(jnp.float32)) > 0.0
-    fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
-    bounds = jnp.stack([state.lower[ids], state.upper[ids]], axis=1)
-    best = evaluate_splits(hist, totals, n_bins, params, allowed & fm, bounds,
-                           cat_mask=cat_mask if has_cat else None)
-    gain = best.gain
-    if max_depth > 0:
-        gain = jnp.where(state.depth[ids] < max_depth, gain, -jnp.inf)
-    return state._replace(
-        cand_gain=state.cand_gain.at[ids].set(gain),
-        cand_feat=state.cand_feat.at[ids].set(best.feature),
-        cand_bin=state.cand_bin.at[ids].set(best.bin),
-        cand_dleft=state.cand_dleft.at[ids].set(best.default_left),
-        cand_lsum=state.cand_lsum.at[ids].set(best.left_sum),
-        cand_rsum=state.cand_rsum.at[ids].set(best.right_sum),
-        cand_lw=state.cand_lw.at[ids].set(best.left_weight),
-        cand_rw=state.cand_rw.at[ids].set(best.right_weight),
-        cand_is_cat=state.cand_is_cat.at[ids].set(best.is_cat),
-        cand_cat_set=state.cand_cat_set.at[ids].set(best.cat_set),
+class Picked(NamedTuple):
+    """What a pass chose to evaluate: between its two halves."""
+
+    sel: jnp.ndarray         # (k,) int32 slots of the parents, best first
+    ok: jnp.ndarray          # (k,) bool
+    build_left: jnp.ndarray  # (k,) bool — the left child is the built one
+    root: jnp.ndarray        # () bool — this pass builds the root
+
+
+class BFTree(NamedTuple):
+    """A finished tree in pop order (``n_slots`` long, ``n_nodes`` used) and
+    every row's leaf."""
+
+    pos: jnp.ndarray
+    left: jnp.ndarray
+    right: jnp.ndarray
+    parent: jnp.ndarray
+    feat: jnp.ndarray
+    sbin: jnp.ndarray
+    dleft: jnp.ndarray
+    gain: jnp.ndarray
+    totals: jnp.ndarray
+    lower: jnp.ndarray
+    upper: jnp.ndarray
+    is_cat: jnp.ndarray
+    cat_set: jnp.ndarray
+    n_nodes: int
+
+
+def _init_state(pos, root_totals, *, S: int, F: int, B: int, n_sets: int):
+    i32, f32 = jnp.int32, jnp.float32
+    return BFState(
+        pos=pos,
+        hist=jnp.zeros((S, F, B, 2), f32),
+        parent=jnp.full(S, -1, i32),
+        left=jnp.full(S, -1, i32),
+        right=jnp.full(S, -1, i32),
+        fid=jnp.full(S, -1, i32).at[0].set(0),
+        split=jnp.zeros(S, bool),
+        depth=jnp.zeros(S, i32),
+        totals=jnp.zeros((S, 2), f32).at[0].set(root_totals),
+        lower=jnp.full(S, -jnp.inf, f32),
+        upper=jnp.full(S, jnp.inf, f32),
+        setcompat=jnp.ones((S, n_sets), bool),
+        path=jnp.zeros(S, jnp.uint32),
+        cand_gain=jnp.full(S, -jnp.inf, f32),
+        cand_feat=jnp.zeros(S, i32),
+        cand_bin=jnp.zeros(S, i32),
+        cand_dleft=jnp.ones(S, bool),
+        cand_lsum=jnp.zeros((S, 2), f32),
+        cand_rsum=jnp.zeros((S, 2), f32),
+        cand_lw=jnp.zeros(S, f32),
+        cand_rw=jnp.zeros(S, f32),
+        cand_is_cat=jnp.zeros(S, bool),
+        cand_cat_set=jnp.zeros((S, B), bool),
+        n_alloc=jnp.zeros((), i32),
+        n_splits=jnp.zeros((), i32),
+        done=jnp.zeros((), bool),
+        told=jnp.zeros(3, i32),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("params", "monotone"))
-def _apply_split(state: BFState, bins, set_matrix, nid, l_id, r_id,
-                 params: SplitParams, monotone: bool):
-    """Expand node ``nid`` into (l_id, r_id): record the split, route rows."""
-    B = state.cat_set.shape[1]
-    f = state.cand_feat[nid]
-    sb = state.cand_bin[nid]
-    dl = state.cand_dleft[nid]
-    is_cat = state.cand_is_cat[nid]
-    cset = state.cand_cat_set[nid]
+def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
+            gamma_eps: float, has_cat: bool):
+    """First half of a pass: choose the parents, route their rows, build the
+    pass's histograms from the rows.  Returns ``(state, picked, built)`` with
+    ``built`` (pairs, F, B, 2): of the built child of each pair, or of the
+    root in the first pass (slot 0 is the built child of pair 0 there)."""
+    k, B = pairs, state.cand_cat_set.shape[1]
+    i32 = jnp.int32
+    with jax.named_scope("queue"):
+        j = jnp.arange(k, dtype=i32)
+        root = state.n_alloc == 0
+        S = state.fid.shape[0]
+        held = state.fid >= 0
+        up = jnp.clip(state.parent, 0, None)
+        # when the driver can pop a node: by its gain if the tree holds it,
+        # else no sooner than its parent (exact for waits up to _WAIT deep;
+        # the order only decides what is worth evaluating, never the tree)
+        rank = lax.fori_loop(
+            0, _WAIT, lambda _, r: jnp.where(
+                held, state.cand_gain, jnp.minimum(state.cand_gain, r[up])),
+            state.cand_gain)
+        unknown = ((jnp.arange(S, dtype=i32) < state.n_alloc) & ~state.split
+                   & (state.left < 0) & (rank > gamma_eps))
+        top, sel = lax.top_k(jnp.where(unknown, rank, -jnp.inf), k)
+        sel = sel.astype(i32)
+        # never more pairs than splits are left to commit; and where the
+        # evaluated splits that wait for their turn fill their room, the
+        # best open leaf alone, whose split does not wait
+        waiting = (state.n_alloc - 1) // 2 - state.n_splits
+        n_sel = jnp.minimum(
+            jnp.minimum(jnp.sum(top > -jnp.inf).astype(i32),
+                        max_leaves - 1 - state.n_splits),
+            jnp.maximum(max_leaves - waiting, 1))
+        ok = j < n_sel
+        # the smaller child (by its hessian sum) is built, its sibling
+        # derived: what the subtraction loses is measured against the parent
+        build_left = state.cand_lsum[sel, 1] <= state.cand_rsum[sel, 1]
+    with jax.named_scope("route"):
+        # a row's place among the chosen parents (and whether its pair is
+        # built right), -1 for every other row: one select over the k
+        # parents; then the level step's own route over those k "nodes"
+        key = jnp.where(ok, 2 * j + (~build_left).astype(i32) + 1, 0)
+        code = jnp.sum(jnp.where(sel[:, None] == state.pos[None, :],
+                                 key[:, None], 0), axis=0) - 1
+        jr, flip = code >> 1, code & 1
+        chosen = BestSplit(
+            gain=top, feature=state.cand_feat[sel], bin=state.cand_bin[sel],
+            default_left=state.cand_dleft[sel], left_sum=None, right_sum=None,
+            left_weight=None, right_weight=None,
+            is_cat=state.cand_is_cat[sel], cat_set=state.cand_cat_set[sel])
+        routed = _update_positions(bins, jr, chosen, ok, 0, k, B, has_cat)
+        go_right = routed - (2 * jr + 1)
+        # the built child takes the even slot of its pair
+        pos = jnp.where(jr >= 0, state.n_alloc + 2 * jr + (go_right ^ flip),
+                        state.pos)
+    with jax.named_scope("hist"):
+        built = level_histogram(bins, gpair, pos, state.n_alloc, n_nodes=k,
+                                n_bin=B, stride=2)
+    return (state._replace(pos=pos),
+            Picked(sel=sel, ok=ok, build_left=build_left, root=root), built)
 
-    st = state._replace(
-        left=state.left.at[nid].set(l_id),
-        right=state.right.at[nid].set(r_id),
-        feat=state.feat.at[nid].set(f),
-        sbin=state.sbin.at[nid].set(sb),
-        dleft=state.dleft.at[nid].set(dl),
-        gain=state.gain.at[nid].set(state.cand_gain[nid]),
-        is_cat=state.is_cat.at[nid].set(is_cat),
-        cat_set=state.cat_set.at[nid].set(cset),
-        cand_gain=state.cand_gain.at[nid].set(-jnp.inf),  # closed
-        parent=state.parent.at[l_id].set(nid).at[r_id].set(nid),
-        depth=state.depth.at[l_id].set(state.depth[nid] + 1)
-                         .at[r_id].set(state.depth[nid] + 1),
-        totals=state.totals.at[l_id].set(state.cand_lsum[nid])
-                           .at[r_id].set(state.cand_rsum[nid]),
-    )
-    # interaction constraints: children keep only sets containing f
-    # (constraints.cc FeatureInteractionConstraint path restriction)
-    member = set_matrix[:, jnp.clip(f, 0, set_matrix.shape[1] - 1)]  # (n_sets,)
-    child_compat = state.setcompat[nid] & member
-    st = st._replace(
-        setcompat=st.setcompat.at[l_id].set(child_compat)
-                              .at[r_id].set(child_compat))
-    if monotone:
-        # bounds propagation (constraints.cc ValueConstraint::SetChild)
-        cvec = jnp.asarray(params.monotone, jnp.int32)
-        c_at = cvec[jnp.clip(f, 0, len(params.monotone) - 1)]
-        mid = 0.5 * (state.cand_lw[nid] + state.cand_rw[nid])
-        lo, hi = state.lower[nid], state.upper[nid]
-        st = st._replace(
-            lower=st.lower.at[l_id].set(jnp.where(c_at < 0, mid, lo))
-                         .at[r_id].set(jnp.where(c_at > 0, mid, lo)),
-            upper=st.upper.at[l_id].set(jnp.where(c_at > 0, mid, hi))
-                         .at[r_id].set(jnp.where(c_at < 0, mid, hi)),
+
+def _settle(state: BFState, picked: Picked, built, n_bins, root_mask,
+            pair_masks, set_matrix, cat_mask, *, pairs: int, max_leaves: int,
+            max_depth: int, gamma_eps: float, params: SplitParams,
+            has_cat: bool, monotone: bool):
+    """Second half of a pass: the siblings by subtraction, every child's
+    best split, the block of 2*pairs slots written, the driver replayed."""
+    k = pairs
+    i32 = jnp.int32
+    sel, ok, bl, root = picked
+    S = state.fid.shape[0]
+    a = state.n_alloc
+
+    def pair(x):  # (k, ...) of the parents -> (2k, ...) of their children
+        return jnp.repeat(x, 2, axis=0)
+
+    with jax.named_scope("split"):
+        c = jnp.arange(2 * k, dtype=i32)
+        first = root & (c == 0)  # the root as a child of nothing
+        # a child is its parent's left one where its slot is the built one
+        # (even) and the left child was built, or neither
+        is_left = (c % 2 == 0) == pair(bl)
+        valid = jnp.where(root, first, pair(ok))
+    with jax.named_scope("hist"):
+        block = combine_sibling_hists(built, state.hist[sel], valid)
+    with jax.named_scope("split"):
+        def side(lv, rv):
+            pick = is_left.reshape((-1,) + (1,) * (lv.ndim - 1))
+            return jnp.where(pick, pair(lv), pair(rv))
+
+        totals = side(state.cand_lsum[sel], state.cand_rsum[sel])
+        depth = pair(state.depth[sel]) + 1
+        lower, upper = pair(state.lower[sel]), pair(state.upper[sel])
+        f = state.cand_feat[sel]
+        if monotone:
+            # bounds propagation (constraints.cc ValueConstraint::SetChild)
+            cvec = jnp.asarray(params.monotone, i32)
+            c_at = pair(cvec[jnp.clip(f, 0, len(params.monotone) - 1)])
+            mid = pair(0.5 * (state.cand_lw[sel] + state.cand_rw[sel]))
+            lower, upper = (
+                jnp.where(jnp.where(is_left, c_at < 0, c_at > 0), mid, lower),
+                jnp.where(jnp.where(is_left, c_at > 0, c_at < 0), mid, upper))
+        # interaction constraints: children keep only sets containing f
+        # (constraints.cc FeatureInteractionConstraint path restriction)
+        member = set_matrix.T[jnp.clip(f, 0, set_matrix.shape[1] - 1)]
+        compat = pair(state.setcompat[sel] & member)
+        # a child's column draw is keyed by its parent's way down from the
+        # root and its side, so the tree does not depend on which pass
+        # evaluated it
+        draw = pair_masks[pair(state.path[sel]) % pair_masks.shape[0],
+                          jnp.where(is_left, 0, 1)]
+        path = 2 * pair(state.path[sel]) + jnp.where(is_left, 1, 2).astype(
+            jnp.uint32)
+        fr = first.reshape(-1, 1)
+        totals = jnp.where(fr, state.totals[:1], totals)
+        depth = jnp.where(first, 0, depth)
+        path = jnp.where(first, 0, path)
+        lower = jnp.where(first, -jnp.inf, lower)
+        upper = jnp.where(first, jnp.inf, upper)
+        compat = jnp.where(fr, True, compat)
+        draw = jnp.where(fr, root_mask, draw)
+        allowed = jnp.einsum("ns,sf->nf", compat.astype(jnp.float32),
+                             set_matrix.astype(jnp.float32)) > 0.0
+        best = evaluate_splits(block, totals, n_bins, params, allowed & draw,
+                               jnp.stack([lower, upper], axis=1),
+                               cat_mask=cat_mask if has_cat else None)
+        gain = jnp.where(valid, best.gain, -jnp.inf)
+        if max_depth > 0:
+            gain = jnp.where(depth < max_depth, gain, -jnp.inf)
+    with jax.named_scope("record"):
+        def put(arr, blk):
+            return lax.dynamic_update_slice_in_dim(arr, blk.astype(arr.dtype),
+                                                   a, axis=0)
+
+        # parents that were not chosen write to the last slot, which no
+        # block reaches
+        tgt = jnp.where(ok, sel, S - 1)
+        built_slot = a + 2 * jnp.arange(k, dtype=i32)
+        st = state._replace(
+            hist=put(state.hist, block),
+            parent=put(state.parent, jnp.where(first, -1, pair(sel))),
+            left=put(state.left, jnp.full(2 * k, -1, i32))
+            .at[tgt].set(jnp.where(bl, built_slot, built_slot + 1)),
+            right=put(state.right, jnp.full(2 * k, -1, i32))
+            .at[tgt].set(jnp.where(bl, built_slot + 1, built_slot)),
+            fid=put(state.fid, jnp.where(first, 0, -1)),
+            split=put(state.split, jnp.zeros(2 * k, bool)),
+            depth=put(state.depth, depth),
+            totals=put(state.totals, totals),
+            lower=put(state.lower, lower),
+            upper=put(state.upper, upper),
+            setcompat=put(state.setcompat, compat),
+            path=put(state.path, path),
+            cand_gain=put(state.cand_gain, gain),
+            cand_feat=put(state.cand_feat, best.feature),
+            cand_bin=put(state.cand_bin, best.bin),
+            cand_dleft=put(state.cand_dleft, best.default_left),
+            cand_lsum=put(state.cand_lsum, best.left_sum),
+            cand_rsum=put(state.cand_rsum, best.right_sum),
+            cand_lw=put(state.cand_lw, best.left_weight),
+            cand_rw=put(state.cand_rw, best.right_weight),
+            cand_is_cat=put(state.cand_is_cat, best.is_cat),
+            cand_cat_set=put(state.cand_cat_set, best.cat_set),
+            n_alloc=jnp.where(root, 1, a + 2 * jnp.sum(ok).astype(i32)),
         )
-
-    # route rows of nid (RowPartitioner analogue, single node)
-    binval = bins[:, jnp.clip(f, 0, bins.shape[1] - 1)].astype(jnp.int32)
-    goleft_num = binval <= sb
-    in_set = cset[jnp.clip(binval, 0, B - 1)]
-    goleft_split = jnp.where(is_cat, ~in_set, goleft_num)
-    goleft = jnp.where(binval >= B, dl, goleft_split)
-    at_node = state.pos == nid
-    new_pos = jnp.where(at_node, jnp.where(goleft, l_id, r_id), state.pos)
-    return st._replace(pos=new_pos)
+    with jax.named_scope("queue"):
+        return _replay(st, max_leaves=max_leaves, gamma_eps=gamma_eps)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _pick_best(cand_gain):
-    nid = jnp.argmax(cand_gain)
-    return nid.astype(jnp.int32), cand_gain[nid]
+def _replay(st: BFState, *, max_leaves: int, gamma_eps: float) -> BFState:
+    """The serial driver (driver.h pop/push) over what is known: commits, in
+    pop order, every split whose children have been evaluated, and stops at
+    the first popped leaf whose children have not, or where the driver
+    itself stops (``done``)."""
+    i32 = jnp.int32
+    far = jnp.iinfo(jnp.int32).max
+
+    def step(carry):
+        fid, split, n, _, _ = carry
+        is_open = (fid >= 0) & ~split
+        gain = jnp.where(is_open, st.cand_gain, -jnp.inf)
+        top = jnp.max(gain)
+        # of equal gains the lowest id, as the driver's argmax over ids
+        nid = jnp.argmin(jnp.where(is_open & (gain == top), fid, far))
+        done = (top <= gamma_eps) | (n >= max_leaves - 1)
+        commit = ~done & (st.left[nid] >= 0)
+        kid = jnp.where(commit, 2 * n + 1, -1)
+        return (fid.at[jnp.where(commit, st.left[nid], nid)]
+                .set(jnp.where(commit, kid, fid[nid]))
+                .at[jnp.where(commit, st.right[nid], nid)]
+                .set(jnp.where(commit, kid + 1, fid[nid])),
+                split.at[nid].set(split[nid] | commit),
+                n + commit.astype(i32), ~commit, done)
+
+    fid, split, n, _, done = lax.while_loop(
+        lambda carry: ~carry[3], step,
+        (st.fid, st.split, st.n_splits, jnp.zeros((), bool),
+         jnp.zeros((), bool)))
+    return st._replace(fid=fid, split=split, n_splits=n, done=done,
+                       told=jnp.stack([done.astype(i32), n, st.n_alloc]))
+
+
+_EXPAND_STATIC = ("pairs", "max_leaves", "gamma_eps", "has_cat")
+_STATIC = _EXPAND_STATIC + ("max_depth", "params", "monotone")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def level_step_bestfirst(state: BFState, bins, gpair, n_bins, root_mask,
+                         pair_masks, set_matrix, cat_mask, *, pairs: int,
+                         max_leaves: int, max_depth: int, gamma_eps: float,
+                         params: SplitParams, has_cat: bool, monotone: bool):
+    """One pass (module docstring), the whole of it one program: a tree is
+    this program run until ``state.done``, the root's pass its first run."""
+    state, picked, built = _expand(
+        state, bins, gpair, pairs=pairs, max_leaves=max_leaves,
+        gamma_eps=gamma_eps, has_cat=has_cat)
+    return _settle(state, picked, built, n_bins, root_mask, pair_masks,
+                   set_matrix, cat_mask, pairs=pairs, max_leaves=max_leaves,
+                   max_depth=max_depth, gamma_eps=gamma_eps, params=params,
+                   has_cat=has_cat, monotone=monotone)
+
+
+# the two halves apart, for rows that live in several processes: the built
+# histograms cross them through the host between the halves
+_expand_alone = jax.jit(_expand, static_argnames=_EXPAND_STATIC)
+_settle_alone = jax.jit(_settle, static_argnames=_STATIC)
+
+
+def _lookup(table, pos):
+    """``table[pos]`` for every row (0 where ``pos`` is negative): where the
+    histogram is the dense matmul a select over the table, no row-sized
+    gather (tree/grow.py ``_update_positions`` has the readings)."""
+    if hist_is_row_pass():
+        return jnp.where(pos >= 0, table[jnp.clip(pos, 0, None)], 0)
+    slots = jnp.arange(table.shape[0], dtype=pos.dtype)
+    return jnp.sum(jnp.where(slots[:, None] == pos[None, :],
+                             table[:, None], 0), axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots",))
+def _finish(st: BFState, *, n_slots: int) -> BFTree:
+    """Slots to pop order.  A row below a leaf whose evaluated split was
+    never committed goes back to that leaf."""
+    with jax.named_scope("record"):
+        S = st.fid.shape[0]
+        own = st.fid >= 0
+        # the nearest ancestor the tree holds, by pointer doubling
+        anc = lax.while_loop(
+            lambda a: ~jnp.all(own[a]), lambda a: a[a],
+            jnp.where(own, jnp.arange(S, dtype=jnp.int32),
+                      jnp.clip(st.parent, 0, None)))
+        node_of = st.fid[anc]
+        # slot of every id; ids past the tree's end read the last slot
+        slot = jnp.full(n_slots, S - 1, jnp.int32).at[
+            jnp.where(own, st.fid, n_slots)].set(
+                jnp.arange(S, dtype=jnp.int32), mode="drop")
+        inner = st.split[slot]
+
+        def child(side):
+            return jnp.where(inner, st.fid[jnp.clip(side[slot], 0, None)], -1)
+
+        parent = jnp.where(slot == 0, -1,
+                           st.fid[jnp.clip(st.parent[slot], 0, None)])
+    with jax.named_scope("route"):
+        pos = jnp.where(st.pos >= 0, _lookup(node_of, st.pos), -1)
+    return BFTree(
+        pos=pos, left=child(st.left), right=child(st.right), parent=parent,
+        feat=jnp.where(inner, st.cand_feat[slot], -1),
+        sbin=jnp.where(inner, st.cand_bin[slot], 0),
+        dleft=jnp.where(inner, st.cand_dleft[slot], True),
+        gain=jnp.where(inner, st.cand_gain[slot], 0.0),
+        totals=st.totals[slot], lower=st.lower[slot], upper=st.upper[slot],
+        is_cat=inner & st.cand_is_cat[slot],
+        cat_set=st.cand_cat_set[slot] & inner[:, None],
+        n_nodes=2 * st.n_splits + 1)
 
 
 class BestFirstGrower:
-    """Lossguide driver: host loop of device expansions (driver.h pop/push)."""
+    """Lossguide driver: passes of ``level_step_bestfirst`` until the
+    replayed queue (driver.h pop/push) is done."""
 
     def __init__(self, max_depth: int, params: SplitParams, *,
                  max_leaves: int, interaction_sets=None,
                  distributed: bool = False, mesh=None) -> None:
-        from .grow import make_set_matrix
-
         assert max_leaves > 1
         self.max_depth = max_depth  # 0 = unbounded
         self.params = params
         self.max_leaves = max_leaves
         self.interaction_sets = interaction_sets
-        self._make_set_matrix = make_set_matrix
         self.n_slots = 2 * max_leaves  # any L-leaf binary tree: 2L-1 nodes
-        # distributed=True: row shards live in other PROCESSES — the per-
-        # expansion histogram goes through the host collective (the
-        # AllReduceHist exchange), after which every rank's driver pops the
-        # same node.  mesh: rows sharded over in-process devices — inputs are
-        # placed row-sharded and GSPMD inserts the psum inside the hist
-        # matmul itself (driver.h queue semantics, global across shards,
-        # either way).
+        self.pairs = min(_PAIRS, max_leaves - 1)
+        # what a tree that spends its budget runs at the least (_SPARE)
+        self.passes = math.ceil(
+            _least_passes(max_leaves, self.pairs) * (1 + _SPARE))
+        # while it grows: the root, two slots a committed split, two an
+        # evaluated split that waits (max_leaves of them and a pass's more,
+        # _expand), a pass's block beyond the last slot in use, and one
+        # slot that nothing reaches
+        self._grow_slots = 4 * max_leaves + 4 * self.pairs
+        # distributed=True: row shards live in other PROCESSES — a pass's
+        # built histograms go through the host collective (the
+        # AllReduceHist exchange) between its two halves, after which every
+        # rank's replay pops the same nodes.  mesh: rows sharded over
+        # in-process devices — inputs are placed row-sharded and GSPMD
+        # inserts the psum inside the hist matmul itself (driver.h queue
+        # semantics, global across shards, either way).
         self.distributed = distributed
         self.mesh = mesh
 
-    def _node_hist(self, bins, gpair, pos, i0, n, n_bin):
-        # separately-timed phases (unlike the fused depthwise level_step):
-        # the best-first host loop dispatches hist, split-eval, and apply as
-        # distinct device calls, so the spans attribute them individually
-        with span("grow.build_hist"):
-            hist = build_histogram_at(bins, gpair, pos, i0, n_nodes=n,
-                                      n_bin=n_bin)
-            if self.distributed:
-                from .. import collective
-
-                hist = jnp.asarray(collective.allreduce(np.asarray(hist)))
-        return hist
+    def _masks(self, feature_masks, F: int):
+        """(root's mask (1, F), the pairs' draws (P, 2, F)).  Column
+        sampling: a fresh bylevel/bynode draw a pair (the reference's
+        ColumnSampler draws as nodes are created), a pair taking the draw
+        that its parent's way down from the root hashes to; the bytree mask
+        is shared through the closure.  No sampling: one row of ones."""
+        if feature_masks is None:
+            return jnp.ones((1, F), bool), jnp.ones((1, 2, F), bool)
+        root = jnp.asarray(feature_masks(0, 1))
+        draws = np.stack([
+            np.broadcast_to(np.asarray(feature_masks(0, 2)), (2, F))
+            for _ in range(2 * self.max_leaves - 1)])
+        return root, jnp.asarray(draws)
 
     def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None,
-             cat_mask=None) -> BFState:
+             cat_mask=None) -> BFTree:
         F = bins.shape[1]
         B = cuts_pad.shape[1]
-        N = self.n_slots
         has_cat = cat_mask is not None
         cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
-        setmat = jnp.asarray(self._make_set_matrix(self.interaction_sets, F))
-        # column sampling: fresh bylevel/bynode draw per expansion (the
-        # reference's ColumnSampler draws as nodes are created); the bytree
-        # mask is shared through the feature_masks closure
-        fm = (jnp.ones((1, F), bool) if feature_masks is None
-              else feature_masks(0, 1))
-        n_sets = setmat.shape[0]
+        setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
+        root_mask, pair_masks = self._masks(feature_masks, F)
 
         if self.mesh is not None:
             from ..parallel import shard_rows
@@ -220,84 +532,76 @@ class BestFirstGrower:
         pos = jnp.where(valid, 0, -1).astype(jnp.int32)
         root = node_sums(gpair, pos, node0=0, n_nodes=1)[0]
         if self.distributed:
-            from .. import collective
+            from .. import collective  # the loop's passes use it too
 
             root = jnp.asarray(collective.allreduce(np.asarray(root)))
-        state = BFState(
-            pos=pos,
-            parent=jnp.full(N, -1, jnp.int32),
-            left=jnp.full(N, -1, jnp.int32),
-            right=jnp.full(N, -1, jnp.int32),
-            depth=jnp.zeros(N, jnp.int32),
-            feat=jnp.full(N, -1, jnp.int32),
-            sbin=jnp.zeros(N, jnp.int32),
-            dleft=jnp.ones(N, bool),
-            gain=jnp.zeros(N, jnp.float32),
-            totals=jnp.zeros((N, 2), jnp.float32).at[0].set(root),
-            lower=jnp.full(N, -jnp.inf, jnp.float32),
-            upper=jnp.full(N, jnp.inf, jnp.float32),
-            setcompat=jnp.ones((N, n_sets), bool),
-            is_cat=jnp.zeros(N, bool),
-            cat_set=jnp.zeros((N, B), bool),
-            cand_gain=jnp.full(N, -jnp.inf, jnp.float32),
-            cand_feat=jnp.zeros(N, jnp.int32),
-            cand_bin=jnp.zeros(N, jnp.int32),
-            cand_dleft=jnp.ones(N, bool),
-            cand_lsum=jnp.zeros((N, 2), jnp.float32),
-            cand_rsum=jnp.zeros((N, 2), jnp.float32),
-            cand_lw=jnp.zeros(N, jnp.float32),
-            cand_rw=jnp.zeros(N, jnp.float32),
-            cand_is_cat=jnp.zeros(N, bool),
-            cand_cat_set=jnp.zeros((N, B), bool),
-        )
-        hist0 = self._node_hist(bins, gpair, state.pos, jnp.int32(0), 1, B)
-        with span("grow.eval_split"):
-            state = _eval_nodes(state, hist0, cuts_pad, n_bins, fm, setmat,
-                                cm, jnp.int32(0), n=1, params=self.params,
-                                max_depth=self.max_depth, has_cat=has_cat)
+        state = _init_state(pos, root, S=self._grow_slots, F=F, B=B,
+                            n_sets=setmat.shape[0])
+        static = dict(
+            pairs=self.pairs, max_leaves=self.max_leaves,
+            max_depth=self.max_depth,
+            gamma_eps=max(float(self.params.gamma), _EPS),
+            params=self.params, has_cat=has_cat,
+            monotone=(self.params.monotone is not None
+                      and any(c != 0 for c in self.params.monotone)))
+        rows, width = int(bins.shape[0]), 2 * self.pairs
 
-        monotone = (self.params.monotone is not None
-                    and any(c != 0 for c in self.params.monotone))
-        gamma_eps = max(self.params.gamma, _EPS)
-        n_nodes = 1
-        for _ in range(self.max_leaves - 1):
-            nid, gain = _pick_best(state.cand_gain)
-            if float(gain) <= gamma_eps:  # driver.h: queue exhausted
-                break
-            l_id, r_id = n_nodes, n_nodes + 1
-            with span("grow.update_tree"):
-                state = _apply_split(state, bins, setmat, nid,
-                                     jnp.int32(l_id), jnp.int32(r_id),
-                                     self.params, monotone)
-            fme = (jnp.ones((1, F), bool) if feature_masks is None
-                   else feature_masks(0, 2))
-            hist2 = self._node_hist(bins, gpair, state.pos,
-                                    jnp.int32(l_id), 2, B)
-            with span("grow.eval_split"):
-                state = _eval_nodes(
-                    state, hist2, cuts_pad, n_bins, fme, setmat, cm,
-                    jnp.int32(l_id), n=2, params=self.params,
-                    max_depth=self.max_depth, has_cat=has_cat)
-            n_nodes += 2
-        self._n_nodes = n_nodes
-        return state
+        def run(state):
+            if not self.distributed:
+                return level_step_bestfirst(
+                    state, bins, gpair, n_bins, root_mask, pair_masks,
+                    setmat, cm, **static)
+            state, picked, built = _expand_alone(
+                state, bins, gpair,
+                **{name: static[name] for name in _EXPAND_STATIC})
+            built = jnp.asarray(collective.allreduce(np.asarray(built)))
+            return _settle_alone(state, picked, built, n_bins, root_mask,
+                                 pair_masks, setmat, cm, **static)
 
-    def to_regtree(self, state: BFState, cuts_pad) -> "tuple[RegTree, np.ndarray]":
-        """(RegTree in table order, leaf_val array for the margin update)."""
-        n = self._n_nodes
-        left = np.asarray(state.left)[:n]
-        right = np.asarray(state.right)[:n]
-        parent = np.asarray(state.parent)[:n]
-        feat = np.asarray(state.feat)[:n]
-        sbin = np.asarray(state.sbin)[:n]
-        dleft = np.asarray(state.dleft)[:n]
-        gain = np.asarray(state.gain)[:n]
-        totals = np.asarray(state.totals)[:n]
-        lower = np.asarray(state.lower)[:n]
-        upper = np.asarray(state.upper)[:n]
-        is_cat = np.asarray(state.is_cat)[:n]
-        cat_set = np.asarray(state.cat_set)[:n]
-        cuts_np = np.asarray(cuts_pad)
+        sent = passes = n_alloc = n_splits = 0
+        told = collections.deque()
+        done = False
+        while not done or (n_splits == self.max_leaves - 1
+                           and passes < self.passes):
+            # one span a pass (the program fuses route, histogram, split scan
+            # and replay; width = the child slots it evaluates), and in it
+            # the one read that says whether the tree needs another; a tree
+            # that spent its budget early runs to self.passes (_SPARE).  While
+            # that schedule lasts the device is kept one pass ahead of the
+            # read, so that it does not wait for the host between two passes
+            # (a tree that stops short of its budget runs that one pass more)
+            with span("grow.bestfirst_pass", rows=rows, width=width) as sp:
+                while sent < max(passes + 1, min(passes + 2, self.passes)):
+                    state = run(state)
+                    told.append(state.told)
+                    sent += 1
+                with span("grow.wait_device"):
+                    done, splits_now, alloc_now = (
+                        int(v) for v in np.asarray(told.popleft()))
+                sp.args.update(pairs=(alloc_now - max(n_alloc, 1)) // 2,
+                               committed=splits_now - n_splits)
+            passes, n_alloc, n_splits = passes + 1, alloc_now, splits_now
+        count_in_round(**{
+            "bestfirst.passes": sent,
+            "bestfirst.pairs_evaluated": (n_alloc - 1) // 2,
+            "bestfirst.pairs_committed": n_splits,
+            "bestfirst.hist_rows": sent * rows})
+        return _finish(state, n_slots=self.n_slots)._replace(
+            n_nodes=2 * n_splits + 1)
+
+    def to_regtree(self, tree: BFTree, cuts_pad) -> "tuple[RegTree, np.ndarray]":
+        """(RegTree in pop order, leaf_val array for the margin update)."""
+        n = int(tree.n_nodes)
+        fields = ("left", "right", "parent", "feat", "sbin", "dleft", "gain",
+                  "totals", "lower", "upper", "is_cat", "cat_set")
+        # every pass has been waited for; what is left is _finish
+        with span("grow.wait_device"):
+            jax.block_until_ready(tree)
+        with span("grow.to_host", copies=len(fields) + 1):
+            (left, right, parent, feat, sbin, dleft, gain, totals, lower,
+             upper, is_cat, cat_set) = (
+                np.asarray(getattr(tree, name))[:n] for name in fields)
+            cuts_np = np.asarray(cuts_pad)
         B = cuts_np.shape[1]
 
         p = self.params
@@ -315,7 +619,7 @@ class BestFirstGrower:
         for i in np.nonzero(~leaf_mask)[0]:
             if is_cat[i]:
                 cats[int(i)] = np.nonzero(cat_set[i])[0].astype(np.int32)
-        tree = RegTree(
+        regtree = RegTree(
             left_children=left.astype(np.int32),
             right_children=right.astype(np.int32),
             parents=parent.astype(np.int32),
@@ -329,4 +633,4 @@ class BestFirstGrower:
             split_type=is_cat.astype(np.int32),
             categories=cats or {},
         )
-        return tree, jnp.asarray(leaf_val_full)
+        return regtree, jnp.asarray(leaf_val_full)

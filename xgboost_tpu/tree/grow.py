@@ -698,7 +698,7 @@ class HistTreeGrower:
             # one span per level: the compiled program fuses build_hist +
             # eval_split + the position rewrite, so the bracket necessarily
             # covers all three — the name keeps the reference phase vocabulary
-            # greppable in traces (bestfirst.py times the phases separately);
+            # greppable in traces (bestfirst.py's pass has a span of its own);
             # width = the slots the level was dispatched at
             with span("grow.build_hist+eval_split", depth=d,
                       width=width or (1 << d)):
