@@ -165,7 +165,10 @@ def bestfirst_pass(F, rows, leaves, sharding=None):
         shape((F,), jnp.int32), shape((1, F), bool), shape((1, 2, F), bool),
         shape((1, F), bool), shape((F,), bool), pairs=g.pairs,
         max_leaves=leaves, max_depth=0, gamma_eps=1e-6, params=params,
-        has_cat=False, monotone=False))
+        has_cat=False, monotone=False,
+        # one chip's matmul scans a list of rows where they are few (PR 33)
+        **({"list_rows": int(bestfirst._LIST_SHARE * rows)}
+           if hasattr(bestfirst, "_LIST_SHARE") else {})))
 
 
 LEVEL_STEP, LEVEL_STEP_PADDED = grow.level_step, grow.level_step_padded

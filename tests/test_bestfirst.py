@@ -246,11 +246,18 @@ def page():
 
 
 def _grow(page, monkeypatch, *, pairs, max_leaves, max_depth=0, mcw=1.0,
-          gamma=0.0, **grower_kw):
+          gamma=0.0, list_share=None, **grower_kw):
+    """``list_share``: under the chip's histogram (the one-hot matmul, whose
+    pass scans a list of rows), with ``_LIST_SHARE`` forced to it ("const":
+    left as it is); None: the CPU backend's kernels, every pass the page."""
     from xgboost_tpu.ops.split import SplitParams
     from xgboost_tpu.tree import bestfirst
 
     monkeypatch.setattr(bestfirst, "_PAIRS", pairs)
+    if list_share is not None:
+        monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+        if list_share != "const":
+            monkeypatch.setattr(bestfirst, "_LIST_SHARE", list_share)
     params = SplitParams(eta=0.1, gamma=gamma, min_child_weight=mcw,
                          lambda_=1.0, alpha=0.0, max_delta_step=0.0)
     grower = bestfirst.BestFirstGrower(max_depth, params,
@@ -308,6 +315,47 @@ def test_the_tree_is_the_serial_drivers(page, monkeypatch, max_leaves,
     assert _same_structure(tree, ref), (tree.n_nodes, len(ref.left))
     if gamma and max_leaves == 255:
         assert tree.num_leaves < max_leaves  # gamma stopped it, not the budget
+
+
+@pytest.mark.parametrize("list_share", [0.0, 1.0, "const"],
+                         ids=["never", "always", "const"])
+@pytest.mark.parametrize("pairs", [4, 16])
+@pytest.mark.parametrize("max_depth,mcw,gamma", [
+    (0, 1.0, 0.0), (0, 100.0, 0.0), (5, 1.0, 0.0), (5, 100.0, 0.0),
+    (0, 1.0, 30.0)], ids=["plain", "mcw100", "depth5", "depth5-mcw100",
+                          "gamma30"])
+@pytest.mark.parametrize("max_leaves", [2, 3, 31, 255])
+def test_the_tree_is_the_serial_drivers_whatever_a_pass_scans(
+        page, monkeypatch, max_leaves, max_depth, mcw, gamma, pairs,
+        list_share):
+    """The same, under the chip's histogram, whose pass scans the list of
+    its built children's rows where they are at most ``_LIST_SHARE`` of the
+    page: no pass taking the list (0: but the empty ones), every pass (1:
+    the root's too), and the constant as it stands."""
+    from xgboost_tpu.telemetry import flight
+    from xgboost_tpu.telemetry.spans import recent
+
+    flight.clear()
+    tree, _ = _grow(page, monkeypatch, pairs=pairs, max_leaves=max_leaves,
+                    max_depth=max_depth, mcw=mcw, gamma=gamma,
+                    list_share=list_share)
+    ref = _serial(page, max_leaves=max_leaves, max_depth=max_depth, mcw=mcw,
+                  gamma=gamma)
+    assert _same_structure(tree, ref), (tree.n_nodes, len(ref.left))
+    passes = recent("grow.bestfirst_pass")
+    rows = passes[0]["rows"]
+    assert passes[0]["scanned"] == rows  # the root's: every chunk, or the page
+    for p in passes[1:]:
+        if list_share == 1.0 or not p["pairs"]:
+            assert p["scanned"] < rows and p["scanned"] % 2048 == 0
+        elif list_share == 0.0:
+            assert p["scanned"] == rows
+        if not p["pairs"]:
+            assert p["scanned"] == 0  # an empty list runs no chunk
+    if list_share == "const" and max_leaves == 255 and not (
+            gamma or max_depth):
+        took = [p["scanned"] < rows for p in passes[1:]]
+        assert any(took) and not all(took)
 
 
 def test_the_chips_route_and_lookup_give_the_same_tree(page, monkeypatch):
@@ -385,13 +433,18 @@ def test_a_pass_commits_and_the_round_span_counts(monkeypatch):
             == sum(p["committed"] for p in mine)
         assert r["bestfirst.pairs_evaluated"] == sum(p["pairs"] for p in mine) \
             >= r["bestfirst.pairs_committed"]
-        assert r["bestfirst.hist_rows"] == r["bestfirst.passes"] * mine[0]["rows"]
+        assert r["bestfirst.hist_rows"] == r["bestfirst.passes"] * mine[0]["rows"] \
+            == sum(p["scanned"] for p in mine) + (
+                tree.num_leaves < 24) * mine[0]["rows"]
+        assert r["bestfirst.listed_passes"] == 0  # the CPU's kernels: the page
         assert mine[0]["pairs"] == 0 and mine[0]["committed"] == 0  # the root
         assert all(p["width"] == 2 * 23 for p in mine)  # a pair a split
     # the one read a pass is inside its span; _finish is waited for once
     waits = recent("grow.wait_device")
     inside = [w for w in waits if w.get("parent") == "grow.bestfirst_pass"]
-    assert len(inside) == len(passes) and len(waits) == len(inside) + 2
+    # (and the pass sent ahead of a tree that stopped short is read after)
+    short = sum(t.num_leaves < 24 for t in bst.trees)
+    assert len(inside) == len(passes) and len(waits) == len(inside) + 2 + short
     copies = recent("grow.to_host")
     assert len(copies) == 2 and all(c["copies"] == 13 for c in copies)
 
@@ -423,6 +476,107 @@ def test_a_tree_that_stops_early_costs_the_passes_it_used(monkeypatch):
         recent("grow.bestfirst_pass")) + 1
     assert r["bestfirst.hist_rows"] == len(runs) * recent(
         "grow.bestfirst_pass")[0]["rows"]
+
+
+def test_a_listed_pass_costs_its_rows_and_an_empty_one_none(monkeypatch):
+    """Under the chip's histogram ``hist_rows`` is what the passes scanned:
+    the page in the root's pass, whole chunks of the list in a pass whose
+    built children hold at most ``_LIST_SHARE`` of the rows, nothing in the
+    passes that fill the schedule of a finished tree; ``listed_passes``
+    counts the passes that took the list, and the four integers of a pass
+    are still one read."""
+    from xgboost_tpu.telemetry import flight
+    from xgboost_tpu.telemetry.spans import recent
+    from xgboost_tpu.tree import bestfirst
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    monkeypatch.setattr(bestfirst, "_LIST_SHARE", 0.25)
+    flight.clear()
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(4000, 5)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(np.float32)
+    d = xtb.QuantileDMatrix(X, label=y, max_bin=32)
+    bst = xtb.train({"objective": "binary:logistic", "max_bin": 32,
+                     "grow_policy": "lossguide", "max_leaves": 24,
+                     "max_depth": 0}, d, 2, verbose_eval=False)
+    passes = recent("grow.bestfirst_pass")
+    seen = 0
+    for r, tree in zip(recent("train.round"), bst.trees):
+        mine = [p for p in passes if p["round"] == r["round"]]
+        rows = mine[0]["rows"]
+        assert mine[0]["scanned"] == rows
+        for p in mine[1:]:
+            assert p["scanned"] == rows or (
+                p["scanned"] % 2048 == 0 and p["scanned"] <= rows // 4 + 2047)
+            assert (p["scanned"] == 0) == (p["pairs"] == 0)
+        short = tree.num_leaves < 24  # one pass sent ahead, read after: empty
+        assert r["bestfirst.hist_rows"] == sum(p["scanned"] for p in mine)
+        assert r["bestfirst.listed_passes"] == short + sum(
+            p["scanned"] < rows for p in mine) > 0
+        assert r["bestfirst.hist_rows"] < r["bestfirst.passes"] * rows
+        seen += tree.num_leaves == 24 and not mine[-1]["pairs"]
+    assert seen  # a tree that spent its budget early: empty passes were run
+    inside = [w for w in recent("grow.wait_device")
+              if w.get("parent") == "grow.bestfirst_pass"]
+    assert len(inside) == len(passes)
+
+
+def test_across_processes_each_lists_its_own_rows(monkeypatch):
+    """Rows in two processes under the chip's histogram: each lists the rows
+    of the built children that it holds (``_expand_alone``) and the
+    allreduced histograms are the ones the two pages scanned whole give, so
+    both ranks grow the trees they grow with no pass taking the list."""
+    import threading
+
+    from xgboost_tpu import collective
+    from xgboost_tpu.telemetry import flight
+    from xgboost_tpu.telemetry.spans import recent
+    from xgboost_tpu.testing.data import make_binary
+    from xgboost_tpu.tree import bestfirst
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    X, y = make_binary(6000, 5, seed=4)
+    params = {"objective": "binary:logistic", "grow_policy": "lossguide",
+              "max_leaves": 12, "max_depth": 0, "eta": 0.4, "max_bin": 16}
+
+    def two_ranks(group):
+        results, errors = {}, {}
+
+        def worker(rank):
+            try:
+                with collective.CommunicatorContext(
+                        dmlc_communicator="in-memory", in_memory_world_size=2,
+                        in_memory_rank=rank, in_memory_group=group):
+                    members = collective._TLS.backend._group
+                    lo, hi = (0, 2500) if rank == 0 else (2500, 6000)
+                    b = xtb.train(params, xtb.DMatrix(X[lo:hi], label=y[lo:hi]),
+                                  2, verbose_eval=False)
+                    # (a leaf's value moves in its last bits with the rows
+                    # that share a chunk: the trees' shape is what is held)
+                    results[rank] = [
+                        (t.left_children.tolist(), t.split_indices.tolist(),
+                         t.split_bins.tolist()) for t in b.trees]
+            except Exception as e:  # noqa: BLE001
+                errors[rank] = e
+                members.barrier.abort()
+
+        threads = [threading.Thread(target=worker, args=(r,), daemon=True)
+                   for r in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=600)
+        assert not any(t.is_alive() for t in threads), "worker deadlocked"
+        assert not errors, errors
+        assert results[0] == results[1]
+        return results[0]
+
+    flight.clear()
+    listed = two_ranks("bflist")
+    took = [r["bestfirst.listed_passes"] for r in recent("train.round")]
+    assert len(took) == 4 and min(took) > 3  # a rank a round: past the empty ones
+    monkeypatch.setattr(bestfirst, "_LIST_SHARE", 0.0)
+    assert two_ranks("bfpage") == listed
 
 
 @pytest.mark.parametrize("params", [

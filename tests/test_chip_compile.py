@@ -178,9 +178,12 @@ def test_padded_level_program_compiles_for_v5e(one_chip, device_paths, depth):
 def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
     """``level_step_bestfirst`` at 28 columns, 256 bins and the cell's budget
     of 255 leaves: 32 pairs a pass, the one-hot matmul for the 32 built
-    children, the packed-table route, the replayed queue.  Nothing in it may
-    gather or scatter a row-sized array (PERF.md, PR 27: 0.05-0.1 s each at
-    10.5M rows), and no host kernel may be traced into it."""
+    children, the packed-table route, the replayed queue, and on one chip
+    the list of the built children's rows with the scan over its chunks.
+    Nothing in it may gather or scatter a row-sized array (PERF.md, PR 27:
+    0.05-0.1 s each at 10.5M rows: the listed scan gathers a chunk's 2,048
+    rows at a time), the list is one sort, and no host kernel may be traced
+    into it."""
     import re
 
     from xgboost_tpu.ops.split import SplitParams
@@ -200,7 +203,8 @@ def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
     def one_pass(*args):  # a fresh function: a fresh trace under device_paths
         return bestfirst.level_step_bestfirst.__wrapped__(
             *args, pairs=grower.pairs, max_leaves=255, max_depth=0,
-            gamma_eps=1e-6, params=params, has_cat=False, monotone=False)
+            gamma_eps=1e-6, params=params, has_cat=False, monotone=False,
+            list_rows=int(bestfirst._LIST_SHARE * ROWS))
 
     compiled = jax.jit(one_pass).lower(
         state, _shape((ROWS, F), jnp.int16, one_chip),
@@ -211,7 +215,9 @@ def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
     text = compiled.as_text()
     assert "custom_call_target=\"xtb_" not in text
     moved = re.findall(r"= \w+\[(\d+)[\],][^\n]* (?:gather|scatter)\(", text)
-    assert moved and max(int(n) for n in moved) <= grower._grow_slots, moved
+    assert moved and max(int(n) for n in moved) <= max(grower._grow_slots,
+                                                       2048), moved
+    assert len(re.findall(r"= \w+\[%d\][^\n]* sort\(" % ROWS, text)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
 
 
@@ -269,38 +275,53 @@ def test_ranking_gradient_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
+@pytest.mark.parametrize("form", ["scan", "listed"])
 def test_hist_onehot_is_built_inside_the_matmul_for_v5e(one_chip,
-                                                        device_paths):
+                                                        device_paths, form):
     """The structure PR 29 rests on, at the ranking cell's width (136
     columns, 16 built nodes): the one-hot is written feature-major, so the
     chip's compiler makes the broadcast, the iota and the ``==`` producers
     inside the convolution's fusion.  Row-major it cut them out as arrays of
     their own, `s32[2048,136,256]` (285 MB a chunk: the program's whole temp
-    size then) and `pred[2048,34816]`, written and read back a chunk a level."""
+    size then) and `pred[2048,34816]`, written and read back a chunk a level.
+    ``listed``: the best-first pass's two loops (`build_histogram_listed`:
+    gathered chunks of a row list, or the page's chunks sliced in the body)
+    hold the same form, and neither copies its accumulator a chunk."""
     import re
 
-    from xgboost_tpu.ops.histogram import build_histogram_at
+    from xgboost_tpu.ops.histogram import (RowList, build_histogram_at,
+                                           build_histogram_listed)
+
+    kernel = {"scan": build_histogram_at,
+              "listed": build_histogram_listed}[form]
 
     def build(*args):  # a fresh function: a fresh trace under device_paths
-        return build_histogram_at.__wrapped__(*args, n_nodes=16, n_bin=B,
-                                              stride=2)
+        return kernel.__wrapped__(*args, n_nodes=16, n_bin=B, stride=2)
 
     F_RANK, T = 136, 2048
+    rows = () if form == "scan" else (RowList(
+        entries=_shape((ROWS,), jnp.int32, one_chip),
+        n=_shape((), jnp.int32, one_chip), scan=_shape((), bool, one_chip)),)
     compiled = jax.jit(build).lower(
         _shape((ROWS, F_RANK), jnp.int16, one_chip),
         _shape((ROWS, 2), jnp.float32, one_chip),
         _shape((ROWS,), jnp.int32, one_chip),
-        _shape((), jnp.int32, one_chip)).compile()
+        _shape((), jnp.int32, one_chip), *rows).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < T * F_RANK * B
     onehot = re.compile(
         r" = \w+\[(%d,%d,%d|%d,%d|%d,%d,%d|%d,%d)[\],]" % (
             T, F_RANK, B, T, F_RANK * B, F_RANK, B, T, F_RANK * B, T))
     text = compiled.as_text()
-    assert "convolution(" in text
-    computation, stored = None, []
+    # chunk 0 and the scan's body; the list's loop and the page's
+    assert len(re.findall(r" convolution\(", text)) == 2
+    computation, stored, copied = "", [], []
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
             computation = line.split()[0]
         elif onehot.search(line) and "fused_computation" not in computation:
             stored.append(line.strip()[:120])
+        elif ("region" in computation and re.search(
+                r" = f32\[16,%d,%d,2\]\S* copy" % (F_RANK, B), line)):
+            copied.append(line.strip()[:120])
     assert not stored, stored
+    assert not copied, copied

@@ -340,3 +340,83 @@ def test_feature_major_chunk_under_shard_map(monkeypatch):
     want = scatter_hist_driver(bins, gpair, pos, node0, N, B, 1, 2,
                                jnp.float32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+_LISTED = {
+    # name: (rows at the built nodes, of R = 2 chunks and 77; None = every row)
+    "empty": 0, "one-row": 1, "a-chunk": 256, "a-chunk-and-one": 257,
+    "every-row": None, "one-node": 300}
+
+
+@pytest.mark.parametrize("scan", ["list", "page"])
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("case", sorted(_LISTED))
+def test_listed_histogram_matches_the_straight_one(monkeypatch, case, stride,
+                                                   scan):
+    """`build_histogram_listed` (the best-first pass's: a loop over the
+    chunks of a row list, as long as the list; or, `scan` false, over the
+    page's own chunks sliced inside the loop) against `_hist_accumulate` on
+    the same `pos`, traced `node0`: an empty list, one row, a chunk to the
+    row, a chunk and one, every row of a page that ends inside a chunk, the
+    rows of one node.  Unit hessians: counts exact, gradient sums within
+    1e-6 of the node's sum of |g| (other rows share a chunk, no more).  The
+    list itself: grouped by node, rows in order inside a node, a row's node
+    on its entry, rows at no built node (or between two, `stride=2`) left
+    out; a list longer than `most` is counted, not written, and the page
+    scanned."""
+    from xgboost_tpu.ops.histogram import (RowList, _hist_accumulate,
+                                           _row_bits, build_histogram_listed,
+                                           row_list, row_list_fits,
+                                           rows_scanned)
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    F, B, chunk, N, node0 = 5, 64, 256, 3, 7
+    R = 2 * chunk + 77
+    rng = np.random.default_rng(len(case) + stride)
+    bins = jnp.asarray(rng.integers(0, B + 1, size=(R, F)).astype(np.int16))
+    gpair = jnp.asarray(np.stack([rng.normal(size=R), np.ones(R)], 1)
+                        .astype(np.float32))
+    nodes = node0 + stride * np.arange(N)
+    n = R if _LISTED[case] is None else _LISTED[case]
+    pos = np.full(R, node0 - 1, np.int32)  # no built node, nor between two
+    at = rng.permutation(R)[:n]
+    pos[at] = nodes[0] if case == "one-node" else rng.choice(nodes, size=n)
+    if stride == 2:  # rows between two built nodes are not listed
+        between = rng.permutation(np.setdiff1d(np.arange(R), at))[:R // 8]
+        pos[between] = node0 + 1
+    # node0 traced, as the best-first pass hands it
+    rows = jax.jit(lambda p, n0: row_list(
+        p, n0, n_nodes=N, stride=stride,
+        most=R if scan == "list" else n - 1))(jnp.asarray(pos),
+                                              jnp.int32(node0))
+    assert isinstance(rows, RowList) and row_list_fits(R, N)
+    entries, count = rows.entries, rows.n
+    assert int(count) == n and bool(rows.scan) == (scan == "list")
+    # by node, then by row; a row's node rides above the row's bits
+    if scan == "list":
+        by_node = np.lexsort((at, pos[at]))
+        np.testing.assert_array_equal(
+            np.asarray(entries[:n]) & ((1 << _row_bits(R)) - 1), at[by_node])
+        np.testing.assert_array_equal(
+            node0 + stride * (np.asarray(entries[:n]) >> _row_bits(R)),
+            pos[at[by_node]])
+    pos = jnp.asarray(pos)
+    assert int(rows_scanned(rows, R, chunk)) == (
+        -(-n // chunk) * chunk if scan == "list" else R)
+    got = jax.jit(lambda b, g, p, n0, r: build_histogram_listed.__wrapped__(
+        b, g, p, n0, r, n_nodes=N, n_bin=B, chunk=chunk, stride=stride))(
+            bins, gpair, pos, jnp.int32(node0), rows)
+    want = jax.jit(lambda b, g, p, n0: _hist_accumulate(
+        b, g, p, n0, N, B, chunk, stride))(bins, gpair, pos,
+                                           jnp.int32(node0))
+    assert got.shape == (N, F, B, 2)
+    np.testing.assert_array_equal(np.asarray(got[..., 1]),
+                                  np.asarray(want[..., 1]))
+    assert float(got[..., 1].sum()) == float(
+        (np.asarray(bins)[np.sort(at)] < B).sum())
+    sum_abs = np.zeros(N)
+    for i, node in enumerate(nodes):
+        sum_abs[i] = np.abs(np.asarray(gpair)[np.asarray(pos) == node, 0]).sum()
+    gap = np.abs(np.asarray(got[..., 0]) - np.asarray(want[..., 0])).max(
+        axis=(1, 2))
+    assert (gap <= 1e-6 * sum_abs).all(), (gap, sum_abs)

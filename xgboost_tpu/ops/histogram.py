@@ -28,11 +28,17 @@ and the compiler then stores an ``s32[2048,F,256]`` broadcast (285 MB at 136
 columns, through HBM once a chunk a level) and a ``pred[2048,F*256]`` compare
 as arrays of their own: 74% of a round at 136 columns, 35% at 28 (PERF.md §6,
 PR 29).  tests/test_chip_compile.py holds the fused form in place.
+``build_histogram_listed`` is the same chunk under a loop whose length is
+known only on the device: over a list of rows, gathered 2,048 at a time, so
+that the histogram of nodes that hold a few per cent of the page costs
+their rows and not the page (the best-first pass, tree/bestfirst.py).
 
 Which of these a level gets is decided here and nowhere else:
-``level_histogram`` is what the level body (tree/grow.py) and the page step
-(tree/stream.py) call.  It picks float32 or int8-limb sums by ``quantised``,
-the static or the traced entry point by what ``node0`` is, and the one-hot
+``level_histogram`` is what the level body (tree/grow.py), the page step
+(tree/stream.py) and the best-first pass (tree/bestfirst.py) call.  It picks
+float32 or int8-limb sums by ``quantised``, the static or the traced entry
+point by what ``node0`` is, the listed scan where it is handed a list of
+rows (the best-first pass alone hands one), and the one-hot
 matmul, the XLA scatter or the native row-pass kernel by ``_host_impl``: on
 the CPU backend neither matmul runs by default.  The fused Pallas kernels
 (ops/hist_pallas.py: the one-hot built in VMEM by hand) are compiled and
@@ -50,7 +56,7 @@ histograms with integer reductions — see ops/quantise.py.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -289,8 +295,129 @@ def build_histogram_at(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
                             stride)
 
 
+class RowList(NamedTuple):
+    """The rows a histogram is wanted of, where they may be few (``row_list``)."""
+
+    entries: jnp.ndarray  # (R,) int32 - a row and its node, by node, then row
+    n: jnp.ndarray        # () int32 - how many
+    scan: jnp.ndarray     # () bool - scan the list; else the page, straight
+
+
+def _row_bits(n_rows: int) -> int:
+    """Bits of a list's entry that hold the row; the node's index among the
+    nodes asked for rides above them."""
+    return max(n_rows - 1, 1).bit_length()
+
+
+def row_list_fits(n_rows: int, n_nodes: int) -> bool:
+    """Whether a row and its node fit one int32 entry of a list (below the
+    entry that no row holds): 67M rows at 32 nodes."""
+    return _row_bits(n_rows) + max(n_nodes - 1, 1).bit_length() <= 30
+
+
+def row_list(pos, node0, *, n_nodes: int, stride: int = 1,
+             most: int) -> RowList:
+    """The rows that ``pos`` (R,) places at the nodes ``node0 + stride*[0,
+    n_nodes)``, to be scanned as a list where they are ``most`` at the most
+    (else the page is, and the list is not written).  An entry is the node's
+    index above the row's bits, so one sort of the entries (a row elsewhere
+    sorts past every row listed) writes the list grouped by node, rows in
+    order inside a node, and the scan reads a row's node off its entry: the
+    slot's gather a listed row, 20-40 ns of 72-105, is not paid.  The sort
+    stands in a loop of one trip, or of none where the list is empty or too
+    long to be scanned: 10 ms at 10.5M rows, which seven of a tree's
+    eighteen passes would pay for nothing.  PERF.md §6, PR 33, has what the
+    other ways to write the list cost on the chip (a prefix sum + scatter:
+    six times the sort)."""
+    R = pos.shape[0]
+    assert row_list_fits(R, n_nodes), (R, n_nodes)
+    local = pos - node0
+    at = local // stride
+    wanted = (local >= 0) & (local % stride == 0) & (at < n_nodes)
+    n = jnp.sum(wanted, dtype=jnp.int32)
+    entry = jnp.where(
+        wanted, (at << _row_bits(R)) | jnp.arange(R, dtype=jnp.int32),
+        jnp.iinfo(jnp.int32).max)
+    scan = n <= most
+    entries = lax.fori_loop(
+        0, (scan & (n > 0)).astype(jnp.int32),
+        lambda _, e: lax.sort(e, is_stable=False), entry)
+    return RowList(entries=entries, n=n, scan=scan)
+
+
+def _scan_chunks(rows: RowList, n_rows: int, T: int):
+    """(chunks of the list, chunks of the page) that a scan runs, ``T`` rows
+    each: one of the two is nought."""
+    return (jnp.where(rows.scan, -(-rows.n // T), 0),
+            jnp.where(rows.scan, 0, -(-n_rows // T)))
+
+
+def rows_scanned(rows: RowList, n_rows: int, chunk: int = 2048):
+    """How many rows ``build_histogram_listed`` visits: whole chunks of the
+    list, or the page."""
+    T = min(chunk, n_rows)
+    return jnp.where(rows.scan, _scan_chunks(rows, n_rows, T)[0] * T,
+                     n_rows).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("n_nodes", "n_bin", "chunk",
+                                             "stride"))
+def build_histogram_listed(bins, gpair, pos, node0, rows: RowList, *,
+                           n_nodes: int, n_bin: int, chunk: int = 2048,
+                           stride: int = 1):
+    """``build_histogram_at`` at the cost of the rows it is wanted of.
+
+    Two loops whose trip counts are known only on the device, one of them
+    with none: where ``rows.scan`` holds, ``ceil(rows.n / chunk)`` chunks of
+    ``chunk`` listed rows each, gathered from the page with their gradient
+    pair (an empty list: no chunk); else the page straight, its chunks
+    sliced from it inside the loop's body, so that a pass over a short list
+    pays nothing by the page's size.  ``rows`` is ``row_list`` of the same
+    ``pos``, ``node0``, ``n_nodes`` and ``stride``.  The arithmetic is
+    ``_hist_chunk``'s in both loops: only which rows share a chunk differs,
+    and with it the last bits of a sum.  One-hot matmul only (the row-pass kernels of the CPU backend cost little
+    a row as it is), and no ``lax.cond``: around the scan it made the
+    accumulator be copied every chunk (PERF.md §6, PR 32)."""
+    R, F = bins.shape
+    T = min(chunk, R)
+    bits = _row_bits(R)
+    node0 = jnp.asarray(node0, jnp.int32)
+    lane = jnp.arange(T, dtype=jnp.int32)
+    in_list, in_page = _scan_chunks(rows, R, T)
+
+    def window(i):
+        """Start of chunk ``i`` (moved back where it would pass the end) and
+        the places of it that chunk ``i - 1`` has not had."""
+        start = jnp.minimum(i * T, R - T)
+        return start, start + lane >= i * T
+
+    def add(acc, b, g, p, ok):
+        return acc + _hist_chunk(b, g, jnp.where(ok, p, -1), node0, n_nodes,
+                                 n_bin, stride)
+
+    def from_page(i, acc):
+        start, fresh = window(i)
+        return add(acc, lax.dynamic_slice(bins, (start, 0), (T, F)),
+                   lax.dynamic_slice(gpair, (start, 0), (T, gpair.shape[1])),
+                   lax.dynamic_slice(pos, (start,), (T,)), fresh)
+
+    def from_list(i, acc):
+        start, fresh = window(i)
+        ok = fresh & (start + lane < rows.n)
+        entry = lax.dynamic_slice(rows.entries, (start,), (T,))
+        at = jnp.where(ok, entry & ((1 << bits) - 1), 0)
+        return add(acc, bins.at[at].get(mode="promise_in_bounds"),
+                   gpair.at[at].get(mode="promise_in_bounds"),
+                   node0 + stride * (entry >> bits), ok)
+
+    acc = jnp.zeros((n_nodes, F, n_bin, gpair.shape[1]), jnp.float32)
+    acc = lax.fori_loop(0, in_page, from_page, acc)
+    return lax.fori_loop(0, in_list, from_list, acc)
+
+
 def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
-                    stride: int = 1, quantised: bool = False):
+                    stride: int = 1, quantised: bool = False,
+                    rows: Optional[RowList] = None):
     """A level's histogram for nodes ``node0 + stride*[0, n_nodes)``: the
     one way in for the level body and the page step, who branch on nothing.
 
@@ -299,7 +426,15 @@ def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
     ``node0`` a Python int is a constant of the program (a program a depth:
     ``build_histogram``, ``hist_accumulate_q``); a traced scalar is an operand
     of one program for every depth (``build_histogram_at``,
-    ``build_histogram_q``)."""
+    ``build_histogram_q``).  ``rows``: the rows that ``pos`` places among
+    these nodes, listed (the best-first pass, whose nodes may hold a few
+    per cent of the page): ``build_histogram_listed``, float32 and the
+    one-hot matmul only."""
+    if rows is not None:
+        assert not quantised and not hist_is_row_pass()
+        return build_histogram_listed(bins, gpair, pos, node0, rows,
+                                      n_nodes=n_nodes, n_bin=n_bin,
+                                      stride=stride)
     static = isinstance(node0, int)
     if quantised:
         from .quantise import build_histogram_q, hist_accumulate_q

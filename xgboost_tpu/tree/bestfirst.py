@@ -7,41 +7,48 @@ budget or no positive gain remains.  The tree is the serial driver's, node
 ids in pop order, children ``(n, n+1)``, depth bounded only by ``max_depth``
 (0 = unbounded); how it is reached is not serial.
 
-*Evaluate ahead, commit in order.*  The histogram's one-hot matmul costs a
-pass over every row whether it builds one node or sixteen (PERF.md §5), so a
-pass (``level_step_bestfirst``, one jitted program) takes the ``pairs``
-nodes the driver is likeliest to pop next among those whose children are not
-known yet (a node the tree does not hold yet among them: it is popped no
-sooner than its ancestors, so its rank is the least gain on its way down from
-the tree), routes their rows, builds the smaller child of each pair from the
-rows (``level_histogram``, the sibling as parent minus child from the
-histogram kept for every unsplit node) and scans both children for their
-best splits (``evaluate_splits``).  Then the serial driver is replayed on the
-device over what is now known: pop the open leaf of highest gain; if its
-children were evaluated, commit the split (the children take the next two
-ids) and go on; stop at the first popped leaf whose children are not.  The
-best open leaf is always the first of the ``pairs``, so a pass commits at
-least one split, and a node's candidate is a function of its own histogram
-alone, so a pair that was evaluated and never popped costs its columns of
-one pass and nothing else: its rows sit below their leaf and take the
-leaf's value.  A tree needs at least its depth in passes; how many follows
-its shape (the queue stalls wherever a child just made is the next to be
-popped).  A tree that spends its whole budget is given the same number of
-passes whatever its shape (``_SPARE``), so that a round costs the same
-every time.
+*Evaluate ahead, commit in order.*  A chunk of the histogram's one-hot
+matmul costs the same whether it builds one node or thirty-two (PERF.md §5),
+and a pass has a fixed cost besides, so a pass (``level_step_bestfirst``,
+one jitted program) takes the ``pairs`` nodes the driver is likeliest to pop
+next among those whose children are not known yet (a node the tree does not
+hold yet among them: it is popped no sooner than its ancestors, so its rank
+is the least gain on its way down from the tree), routes their rows, builds
+the smaller child of each pair from the rows (``level_histogram``, the
+sibling as parent minus child from the histogram kept for every unsplit
+node) and scans both children for their best splits (``evaluate_splits``).
+*A pass costs the rows of its nodes*: on one chip the rows of the built
+children are written as a list after the route, and the histogram scans the
+list, 2,048 gathered rows a chunk, where it holds at most ``_LIST_SHARE`` of
+the page (most passes of a deep tree hold a few per cent of it, and a pass on
+a finished tree none), and the page itself where it holds more (the root's
+pass always): chosen on the device from the list's length, both loops in the
+one program (ops/histogram.py ``build_histogram_listed``).  Then the serial
+driver is replayed on the device over what is now known: pop the open leaf of
+highest gain; if its children were evaluated, commit the split (the children
+take the next two ids) and go on; stop at the first popped leaf whose
+children are not.  The best open leaf is always the first of the ``pairs``, so
+a pass commits at least one split, and a node's candidate is a function of
+its own histogram alone, so a pair that was evaluated and never popped costs
+its built child's rows in one pass and nothing else: its rows sit below
+their leaf and take the leaf's value.  A tree needs at least its depth in
+passes; how many follows its shape (the queue stalls wherever a child just
+made is the next to be popped).  A tree that spends its whole budget is given
+the same number of passes whatever its shape (``_SPARE``); those beyond its
+own hold no node and, where a pass scans a list, cost its fixed part.
 
 While it grows the tree lives in SLOTS in evaluation order (a pass writes
 its children as one contiguous block, the built child first); ``_finish``
-puts nodes and rows into pop order.  The host reads three integers a pass
-(done, splits, slots in use) and decides nothing but whether the tree needs
-another; it sends the next pass before it reads the last.
+puts nodes and rows into pop order.  The host reads four integers a pass
+(done, splits, slots in use, rows scanned) and decides nothing but whether
+the tree needs another; it sends the next pass before it reads the last.
 """
 from __future__ import annotations
 
 import collections
 import functools
 import math
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -51,7 +58,8 @@ from jax import lax
 
 from ..models.tree import RegTree
 from ..ops.histogram import (combine_sibling_hists, hist_is_row_pass,
-                             level_histogram, node_sums)
+                             level_histogram, node_sums, row_list,
+                             row_list_fits, rows_scanned)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 from ..telemetry.spans import count_in_round
@@ -75,11 +83,23 @@ _WAIT = 8
 # no work that the tree needs: they are there so that a round takes the same
 # time on every tree and seed, which the benchmark's admission of a cell asks
 # (a spread under 0.5% over seeds, where three data-dependent trees a window
-# spread 3%), and they cost 12% of the rate.  A tree that stops short of its
-# budget is given none.  Take this out (the constant, ``_least_passes``,
+# spread 3%); each scans an empty list (2.8 ms on the chip; a whole page
+# where no list is scanned, 12% of the rate before PR 33), and since a pass
+# costs the rows of its nodes a round no longer takes the same time on every
+# tree whatever their number.  A tree that stops short of its budget is given
+# none.  Take this out (the constant, ``_least_passes``,
 # ``BestFirstGrower.passes`` and the second half of the loop's condition)
 # once the benchmark's window can hold a cell whose work follows its data.
 _SPARE = 0.375
+# The largest share of the page's rows for which a pass scans the list of its
+# built children's rows and not the page (ops/histogram.py
+# ``build_histogram_listed``).  On the chip at 10.5M x 28 and 64 columns
+# (scripts/probe_rowlist.py --only shipped; PERF.md §6, PR 33) the page
+# scanned straight costs 201.0 ms, 19.14 ns a row; a listed row, gathered with
+# its pair, 52.3-52.7 ns at any share from 0.1 to 0.5 (55.8 at 0.02), and the
+# list itself 9.85 ms where it is written for 1.3 where it is not: the two
+# meet at 0.35 of the rows (9.85 + 0.35 x 10.5M x 52.4 ns = 202 ms).
+_LIST_SHARE = 0.35
 
 
 def _least_passes(max_leaves: int, pairs: int) -> int:
@@ -126,8 +146,8 @@ class BFState(NamedTuple):
     n_alloc: jnp.ndarray    # () int32 — slots in use (0: the root is to come)
     n_splits: jnp.ndarray   # () int32 — splits committed
     done: jnp.ndarray       # () bool — budget spent or no gain left
-    told: jnp.ndarray       # (3,) int32 — done, n_splits, n_alloc: the
-    #                         host's one read a pass
+    told: jnp.ndarray       # (4,) int32 — done, n_splits, n_alloc, rows the
+    #                         pass scanned: the host's one read a pass
 
 
 class Picked(NamedTuple):
@@ -137,6 +157,7 @@ class Picked(NamedTuple):
     ok: jnp.ndarray          # (k,) bool
     build_left: jnp.ndarray  # (k,) bool — the left child is the built one
     root: jnp.ndarray        # () bool — this pass builds the root
+    scanned: jnp.ndarray     # () int32 — rows its histogram visited
 
 
 class BFTree(NamedTuple):
@@ -188,16 +209,18 @@ def _init_state(pos, root_totals, *, S: int, F: int, B: int, n_sets: int):
         n_alloc=jnp.zeros((), i32),
         n_splits=jnp.zeros((), i32),
         done=jnp.zeros((), bool),
-        told=jnp.zeros(3, i32),
+        told=jnp.zeros(4, i32),
     )
 
 
 def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
-            gamma_eps: float, has_cat: bool):
+            gamma_eps: float, has_cat: bool, list_rows: Optional[int]):
     """First half of a pass: choose the parents, route their rows, build the
     pass's histograms from the rows.  Returns ``(state, picked, built)`` with
     ``built`` (pairs, F, B, 2): of the built child of each pair, or of the
-    root in the first pass (slot 0 is the built child of pair 0 there)."""
+    root in the first pass (slot 0 is the built child of pair 0 there).
+    ``list_rows``: the most rows for which the built children's rows are
+    scanned as a list (``_LIST_SHARE``); None: the page, every pass."""
     k, B = pairs, state.cand_cat_set.shape[1]
     i32 = jnp.int32
     with jax.named_scope("queue"):
@@ -248,10 +271,18 @@ def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
         pos = jnp.where(jr >= 0, state.n_alloc + 2 * jr + (go_right ^ flip),
                         state.pos)
     with jax.named_scope("hist"):
+        rows, scanned = None, jnp.asarray(pos.shape[0], i32)
+        if list_rows is not None:
+            # the built children's rows: the even slots of the block this
+            # pass writes (slot 0, every valid row, in the root's pass)
+            rows = row_list(pos, state.n_alloc, n_nodes=k, stride=2,
+                            most=list_rows)
+            scanned = rows_scanned(rows, pos.shape[0])
         built = level_histogram(bins, gpair, pos, state.n_alloc, n_nodes=k,
-                                n_bin=B, stride=2)
+                                n_bin=B, stride=2, rows=rows)
     return (state._replace(pos=pos),
-            Picked(sel=sel, ok=ok, build_left=build_left, root=root), built)
+            Picked(sel=sel, ok=ok, build_left=build_left, root=root,
+                   scanned=scanned), built)
 
 
 def _settle(state: BFState, picked: Picked, built, n_bins, root_mask,
@@ -262,7 +293,7 @@ def _settle(state: BFState, picked: Picked, built, n_bins, root_mask,
     best split, the block of 2*pairs slots written, the driver replayed."""
     k = pairs
     i32 = jnp.int32
-    sel, ok, bl, root = picked
+    sel, ok, bl, root, scanned = picked
     S = state.fid.shape[0]
     a = state.n_alloc
 
@@ -359,7 +390,9 @@ def _settle(state: BFState, picked: Picked, built, n_bins, root_mask,
             n_alloc=jnp.where(root, 1, a + 2 * jnp.sum(ok).astype(i32)),
         )
     with jax.named_scope("queue"):
-        return _replay(st, max_leaves=max_leaves, gamma_eps=gamma_eps)
+        st = _replay(st, max_leaves=max_leaves, gamma_eps=gamma_eps)
+        # the host's one read: what the replay tells, and what the pass scanned
+        return st._replace(told=jnp.append(st.told, scanned))
 
 
 def _replay(st: BFState, *, max_leaves: int, gamma_eps: float) -> BFState:
@@ -395,20 +428,23 @@ def _replay(st: BFState, *, max_leaves: int, gamma_eps: float) -> BFState:
                        told=jnp.stack([done.astype(i32), n, st.n_alloc]))
 
 
-_EXPAND_STATIC = ("pairs", "max_leaves", "gamma_eps", "has_cat")
-_STATIC = _EXPAND_STATIC + ("max_depth", "params", "monotone")
+_EXPAND_STATIC = ("pairs", "max_leaves", "gamma_eps", "has_cat", "list_rows")
+_SETTLE_STATIC = ("pairs", "max_leaves", "gamma_eps", "has_cat", "max_depth",
+                  "params", "monotone")
+_STATIC = _SETTLE_STATIC + ("list_rows",)  # of the whole pass
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def level_step_bestfirst(state: BFState, bins, gpair, n_bins, root_mask,
                          pair_masks, set_matrix, cat_mask, *, pairs: int,
                          max_leaves: int, max_depth: int, gamma_eps: float,
-                         params: SplitParams, has_cat: bool, monotone: bool):
+                         params: SplitParams, has_cat: bool, monotone: bool,
+                         list_rows: Optional[int] = None):
     """One pass (module docstring), the whole of it one program: a tree is
     this program run until ``state.done``, the root's pass its first run."""
     state, picked, built = _expand(
         state, bins, gpair, pairs=pairs, max_leaves=max_leaves,
-        gamma_eps=gamma_eps, has_cat=has_cat)
+        gamma_eps=gamma_eps, has_cat=has_cat, list_rows=list_rows)
     return _settle(state, picked, built, n_bins, root_mask, pair_masks,
                    set_matrix, cat_mask, pairs=pairs, max_leaves=max_leaves,
                    max_depth=max_depth, gamma_eps=gamma_eps, params=params,
@@ -418,7 +454,7 @@ def level_step_bestfirst(state: BFState, bins, gpair, n_bins, root_mask,
 # the two halves apart, for rows that live in several processes: the built
 # histograms cross them through the host between the halves
 _expand_alone = jax.jit(_expand, static_argnames=_EXPAND_STATIC)
-_settle_alone = jax.jit(_settle, static_argnames=_STATIC)
+_settle_alone = jax.jit(_settle, static_argnames=_SETTLE_STATIC)
 
 
 def _lookup(table, pos):
@@ -537,14 +573,21 @@ class BestFirstGrower:
             root = jnp.asarray(collective.allreduce(np.asarray(root)))
         state = _init_state(pos, root, S=self._grow_slots, F=F, B=B,
                             n_sets=setmat.shape[0])
+        rows, width = int(bins.shape[0]), 2 * self.pairs
+        # a pass scans the rows of the children it builds where they are few
+        # (_LIST_SHARE); under a mesh a trip count that differs by shard
+        # cannot be written without shard_map, and the row-pass kernels of
+        # the CPU backend cost little a row: both scan the page
+        listed = (self.mesh is None and not hist_is_row_pass()
+                  and row_list_fits(rows, self.pairs))
         static = dict(
             pairs=self.pairs, max_leaves=self.max_leaves,
             max_depth=self.max_depth,
             gamma_eps=max(float(self.params.gamma), _EPS),
             params=self.params, has_cat=has_cat,
             monotone=(self.params.monotone is not None
-                      and any(c != 0 for c in self.params.monotone)))
-        rows, width = int(bins.shape[0]), 2 * self.pairs
+                      and any(c != 0 for c in self.params.monotone)),
+            list_rows=int(_LIST_SHARE * rows) if listed else None)
 
         def run(state):
             if not self.distributed:
@@ -555,11 +598,12 @@ class BestFirstGrower:
                 state, bins, gpair,
                 **{name: static[name] for name in _EXPAND_STATIC})
             built = jnp.asarray(collective.allreduce(np.asarray(built)))
-            return _settle_alone(state, picked, built, n_bins, root_mask,
-                                 pair_masks, setmat, cm, **static)
+            return _settle_alone(
+                state, picked, built, n_bins, root_mask, pair_masks, setmat,
+                cm, **{name: static[name] for name in _SETTLE_STATIC})
 
         sent = passes = n_alloc = n_splits = 0
-        told = collections.deque()
+        told, scanned = collections.deque(), []
         done = False
         while not done or (n_splits == self.max_leaves - 1
                            and passes < self.passes):
@@ -576,16 +620,22 @@ class BestFirstGrower:
                     told.append(state.told)
                     sent += 1
                 with span("grow.wait_device"):
-                    done, splits_now, alloc_now = (
+                    done, splits_now, alloc_now, scanned_now = (
                         int(v) for v in np.asarray(told.popleft()))
                 sp.args.update(pairs=(alloc_now - max(n_alloc, 1)) // 2,
-                               committed=splits_now - n_splits)
+                               committed=splits_now - n_splits,
+                               scanned=scanned_now)
             passes, n_alloc, n_splits = passes + 1, alloc_now, splits_now
+            scanned.append(scanned_now)
+        if told:  # the pass sent ahead of a tree that stopped short
+            with span("grow.wait_device"):
+                scanned += [int(np.asarray(unread)[3]) for unread in told]
         count_in_round(**{
             "bestfirst.passes": sent,
             "bestfirst.pairs_evaluated": (n_alloc - 1) // 2,
             "bestfirst.pairs_committed": n_splits,
-            "bestfirst.hist_rows": sent * rows})
+            "bestfirst.hist_rows": sum(scanned),
+            "bestfirst.listed_passes": sum(n < rows for n in scanned)})
         return _finish(state, n_slots=self.n_slots)._replace(
             n_nodes=2 * n_splits + 1)
 
