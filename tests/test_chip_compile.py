@@ -275,11 +275,13 @@ def test_ranking_gradient_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
+@pytest.mark.parametrize("columns", [136, 968])
 @pytest.mark.parametrize("form", ["scan", "listed"])
-def test_hist_onehot_is_built_inside_the_matmul_for_v5e(one_chip,
-                                                        device_paths, form):
+def test_hist_onehot_is_built_inside_the_matmul_for_v5e(one_chip, device_paths,
+                                                        form, columns):
     """The structure PR 29 rests on, at the ranking cell's width (136
-    columns, 16 built nodes): the one-hot is written feature-major, so the
+    columns, 16 built nodes) and at the widest cell's (968 columns, where a
+    stored one-hot would be 507 MB a chunk): the one-hot is written feature-major, so the
     chip's compiler makes the broadcast, the iota and the ``==`` producers
     inside the convolution's fusion.  Row-major it cut them out as arrays of
     their own, `s32[2048,136,256]` (285 MB a chunk: the program's whole temp
@@ -298,7 +300,7 @@ def test_hist_onehot_is_built_inside_the_matmul_for_v5e(one_chip,
     def build(*args):  # a fresh function: a fresh trace under device_paths
         return kernel.__wrapped__(*args, n_nodes=16, n_bin=B, stride=2)
 
-    F_RANK, T = 136, 2048
+    F_RANK, T = columns, 2048
     rows = () if form == "scan" else (RowList(
         entries=_shape((ROWS,), jnp.int32, one_chip),
         n=_shape((), jnp.int32, one_chip), scan=_shape((), bool, one_chip)),)

@@ -17,7 +17,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
 import numpy as np
 
 from ..telemetry import span
-from .ellpack import EllpackPage, build_ellpack, build_ellpack_csr
+from .ellpack import (EllpackPage, build_ellpack, build_ellpack_csr,
+                      count_missing)
 from .quantile import HistogramCuts, sketch_csr, sketch_dense
 
 
@@ -503,9 +504,9 @@ class DMatrix:
                                   weights=sketch_weights,
                                   cat_mask=self.cat_mask(),
                                   distributed=distributed)
-        # trace, compile-or-load and dispatch of the binning program plus the
-        # pad; NOT drained: the page is ready when its first reader waits
-        with span("dmatrix.bin"):
+        # trace, compile-or-load and run of the binning program plus the pad,
+        # drained: the count of the page's absent entries waits for the page
+        with span("dmatrix.bin") as binning:
             if self._kind == "dense":
                 ellpack = build_ellpack(self._device_dense(), cuts,
                                         row_align=row_align)
@@ -515,6 +516,8 @@ class DMatrix:
                 indptr, indices, values, (R, F) = self._csr
                 ellpack = build_ellpack_csr(indptr, indices, values, F, cuts,
                                             row_align=row_align)
+            cells, missing = count_missing(ellpack)
+            binning.args.update({"bins.cells": cells, "bins.missing": missing})
         return ellpack
 
     def slice(self, rindex: Sequence[int]) -> "DMatrix":

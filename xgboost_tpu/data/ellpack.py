@@ -28,6 +28,14 @@ from .quantile import HistogramCuts
 
 MISSING_SENTINEL = "B"  # documented: sentinel == padded width B
 
+# The binning program's search carries two int32 (R, F) arrays and a
+# transposed copy of its float32 input: 15.8 B of temporaries a cell as the
+# chip's compiler counts them, 14.5 GB at 946,997 x 968, more than a v5e holds
+# beside the input (PERF.md section 6, PR 34).  A matrix of more cells than this
+# is binned a block of rows at a time, a quarter of this many cells each, by
+# the same program; one of fewer in one call (10.5M x 28 is 0.29G cells).
+_BIN_CELLS = 1 << 29
+
 
 def _bin_dtype(n_symbols: int):
     import jax.numpy as jnp
@@ -126,11 +134,43 @@ def build_ellpack(
                 Xd, cuts_pad, n_bins)
             return bins.astype(dtype)
 
-    bins = _bin(Xd)
+    bins = _in_row_blocks(_bin, Xd)
     if R_pad != R:
         pad = jnp.full((R_pad - R, F), B, dtype=dtype)
         bins = jnp.concatenate([bins, pad], axis=0)
     return EllpackPage(bins=bins, cuts_pad=cuts_pad, n_bins=n_bins, n_rows=R, cuts=cuts)
+
+
+def _in_row_blocks(bin_rows, Xd):
+    """``bin_rows(Xd)``, a block of rows at a time where ``Xd`` holds more
+    than ``_BIN_CELLS`` cells.  Every block has the same number of rows, so
+    one program bins them all: the last one starts early enough to be whole,
+    and what it holds of its neighbour is dropped."""
+    import jax.numpy as jnp
+
+    R, F = Xd.shape
+    if R * F <= _BIN_CELLS:
+        return bin_rows(Xd)
+    n_blocks = -(-R * F // (_BIN_CELLS // 4))
+    T = -(-R // n_blocks)
+    starts = [min(i * T, R - T) for i in range(n_blocks)]
+    blocks = [bin_rows(Xd[lo:lo + T]) for lo in starts]
+    blocks[-1] = blocks[-1][n_blocks * T - R:]
+    return jnp.concatenate(blocks, axis=0)
+
+
+def count_missing(page: EllpackPage) -> tuple:
+    """(cells, absent) of the page's logical rows: how many hold the
+    sentinel.  Counted a column on the device and summed on the host, where
+    the count may pass 2**31; waits for the page."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, sentinel = page.n_rows, page.bin_width
+    by_column = jax.jit(lambda bins: jnp.sum(
+        bins[:rows] == sentinel, axis=0, dtype=jnp.int32))(page.bins)
+    return (rows * page.n_features,
+            int(np.asarray(by_column).sum(dtype=np.int64)))
 
 
 def build_ellpack_csr(indptr, indices, values, n_features: int, cuts: HistogramCuts,

@@ -38,6 +38,7 @@ from ..ops.histogram import (combine_sibling_hists, hist_is_row_pass,
                              level_histogram, node_sums)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
+from ..telemetry.spans import count_in_round
 
 _EPS = 1e-6
 
@@ -725,8 +726,8 @@ class HistTreeGrower:
         # left of the round's host time is the loop's own
         with span("grow.wait_device"):
             jax.block_until_ready(state)
-        with span("grow.to_host", copies=len(GrownTree._fields)):
-            return GrownTree(
+        with span("grow.to_host", copies=len(GrownTree._fields)) as copying:
+            tree = GrownTree(
                 is_cat=np.asarray(state.is_cat),
                 cat_set=np.asarray(state.cat_set),
                 feat=np.asarray(state.feat),
@@ -740,3 +741,11 @@ class HistTreeGrower:
                 sum_hess=np.asarray(state.sum_hess),
                 totals=np.asarray(state.totals),
             )
+            # how many splits the tree made and how many of them send their
+            # absent rows left: on this span and summed on the round's
+            split = (tree.feat >= 0) & ~tree.is_leaf
+            counts = {"splits": int(split.sum()),
+                      "splits.default_left": int((tree.dleft & split).sum())}
+            copying.args.update(counts)
+            count_in_round(**counts)
+            return tree
