@@ -327,3 +327,115 @@ def test_hist_onehot_is_built_inside_the_matmul_for_v5e(one_chip, device_paths,
             copied.append(line.strip()[:120])
     assert not stored, stored
     assert not copied, copied
+
+
+# bosch-d8.train's signature (946,997 x 968: 483, 137, 174 and 174 columns
+# by the bins they need, each tier's count moved down to whole tiles)
+BOSCH_TIERS = ((32, 480), (64, 128), (128, 176), (256, 184))
+
+
+@pytest.mark.parametrize("form", ["scan", "listed"])
+def test_tiered_onehots_are_built_inside_their_matmuls_for_v5e(
+        one_chip, device_paths, form):
+    """Bin-width tiers at 968 columns hold PR 29's structure a tier: four
+    matmuls a chunk (chunk 0 and the scan's body; the list's loop and the
+    page's), each with its one-hot a producer inside its fusion, so that no
+    ``pred[...]`` or ``s32[2048,F_w,w]`` array of a tier's size is stored;
+    no accumulator of a tier is copied in a loop's body; and what moves the
+    chunk's columns into tier order is one gather of the transposed chunk,
+    int16 as the page is."""
+    import re
+
+    from xgboost_tpu.ops.histogram import (BinTiers, RowList,
+                                           build_histogram_at,
+                                           build_histogram_listed)
+
+    kernel = {"scan": build_histogram_at,
+              "listed": build_histogram_listed}[form]
+
+    def build(*args, tiers):  # a fresh function: a fresh trace
+        return kernel.__wrapped__(*args, n_nodes=16, n_bin=B, stride=2,
+                                  tiers=tiers)
+
+    columns, T = sum(n for _, n in BOSCH_TIERS), 2048
+    assert columns == 968
+    rows = () if form == "scan" else (RowList(
+        entries=_shape((ROWS,), jnp.int32, one_chip),
+        n=_shape((), jnp.int32, one_chip), scan=_shape((), bool, one_chip)),)
+    compiled = jax.jit(build).lower(
+        _shape((ROWS, columns), jnp.int16, one_chip),
+        _shape((ROWS, 2), jnp.float32, one_chip),
+        _shape((ROWS,), jnp.int32, one_chip),
+        _shape((), jnp.int32, one_chip), *rows,
+        tiers=BinTiers(BOSCH_TIERS, _shape((columns,), jnp.int32, one_chip))
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" convolution\(", text)) == 2 * len(BOSCH_TIERS)
+    sizes = "|".join(
+        "%d,%d,%d|%d,%d|%d,%d,%d|%d,%d" % (T, n, w, T, n * w, n, w, T,
+                                           n * w, T)
+        for w, n in BOSCH_TIERS)
+    onehot = re.compile(r" = \w+\[(%s)[\],]" % sizes)
+    accumulator = re.compile(r" = f32\[16,(%s),2\]\S* copy" % "|".join(
+        "%d,%d" % (n, w) for w, n in BOSCH_TIERS))
+    computation, stored, copied = "", [], []
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            computation = line.split()[0]
+        elif onehot.search(line) and "fused_computation" not in computation:
+            stored.append(line.strip()[:120])
+        elif "region" in computation and accumulator.search(line):
+            copied.append(line.strip()[:120])
+    assert not stored, stored
+    assert not copied, copied
+    moved = re.findall(r" = (\w+)\[%d,%d\]\S* gather\(" % (columns, T), text)
+    assert moved == ["s16"] * 2, moved
+
+
+@pytest.mark.parametrize("program", ["level_step", "level_step_padded",
+                                     "level_step_bestfirst"])
+def test_one_tier_lowers_to_the_program_without_tiers(one_chip, device_paths,
+                                                      program):
+    """A page whose every column needs all ``B`` bins (the three HIGGS
+    cells) has the signature of one tier, and its level programs are the
+    parent's text: the cache's key does not move and the cells keep their
+    executables (PERF.md: a reordered text costs each program its 5 min)."""
+    from xgboost_tpu.ops.histogram import BinTiers
+    from xgboost_tpu.tree import bestfirst, grow
+
+    one = BinTiers(((B, F),), None)
+    if program == "level_step_bestfirst":
+        params = _split_params()._replace(min_child_weight=100.0)
+        grower = bestfirst.BestFirstGrower(0, params, max_leaves=255)
+        state = jax.eval_shape(
+            lambda pos, root: bestfirst._init_state(
+                pos, root, S=grower._grow_slots, F=F, B=B, n_sets=1),
+            jax.ShapeDtypeStruct((ROWS,), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.float32))
+        args = (type(state)(*(_shape(s.shape, s.dtype, one_chip)
+                              for s in state)),
+                _shape((ROWS, F), jnp.int16, one_chip),
+                _shape((ROWS, 2), jnp.float32, one_chip),
+                _shape((F,), jnp.int32, one_chip),
+                _shape((1, F), bool, one_chip),
+                _shape((1, 2, F), bool, one_chip),
+                _shape((1, F), bool, one_chip), _shape((F,), bool, one_chip))
+        static = dict(pairs=grower.pairs, max_leaves=255, max_depth=0,
+                      gamma_eps=1e-6, params=params, has_cat=False,
+                      monotone=False,
+                      list_rows=int(bestfirst._LIST_SHARE * ROWS))
+        step = bestfirst.level_step_bestfirst
+    elif program == "level_step":
+        args = _level_args(one_chip, one_chip) + (None, None)
+        static = dict(depth=0, params=_split_params(), last_level=False,
+                      subtract=False)
+        step = grow.level_step
+    else:
+        args = _level_args(one_chip, one_chip) + (
+            _shape((32, F, B, 2), jnp.float32, one_chip), 1, None)
+        static = dict(width=32, params=_split_params(), subtract=True)
+        step = grow.level_step_padded
+    texts = [step.lower(*args, **static, **extra).as_text()
+             for extra in ({}, {"tiers": None}, {"tiers": one})]
+    assert texts[0] == texts[1] == texts[2]
+    assert "dot_general" in texts[0]

@@ -27,6 +27,7 @@ from .data.dmatrix import DMatrix
 from .metric import create_metric
 from .models.tree import RegTree
 from .objective import ObjFunction, create_objective
+from .ops.histogram import hist_is_row_pass
 from .ops.predict import predict_leaf_ids
 from .ops.split import SplitParams
 from .params import TrainParam, canonicalize, split_unknown
@@ -1456,6 +1457,13 @@ class Booster:
                                             grower, cat_mask_np)
         bins_use, cuts_use, nbins_use = cache.bins, ell.cuts_pad, ell.n_bins
         cuts_token_use = ell.cuts.token
+        # the one-hot a column's bins tall (ops/histogram.py bin_tiers): one
+        # chip's float32 matmul over the resident page; a mesh, processes,
+        # the int8 limbs and a page binned anew each round build one tier
+        tiers_use = (ell.tiers if mesh is None and not proc_par and not det
+                     and self.tree_method != "approx"
+                     and not hist_is_row_pass() else None)
+        tiers_arg = {} if tiers_use is None else {"tiers": tiers_use}
         if self.tree_method == "approx":
             # grow_histmaker (updater_approx.cc): fresh hessian-weighted
             # sketch every iteration, then the same hist machinery; cut
@@ -1557,6 +1565,7 @@ class Booster:
                     nbins_use,
                     feature_masks=fmask_fn,
                     cat_mask=cat_mask_np,
+                    **tiers_arg,
                 )
                 pos = state.pos
                 if best_first:

@@ -517,7 +517,16 @@ class DMatrix:
                 ellpack = build_ellpack_csr(indptr, indices, values, F, cuts,
                                             row_align=row_align)
             cells, missing = count_missing(ellpack)
-            binning.args.update({"bins.cells": cells, "bins.missing": missing})
+            # the rows of the one-hot a chunk of the page is multiplied as,
+            # each column as tall as its tier (F*B where there is but one)
+            from ..ops.histogram import onehot_rows, tier_widths
+
+            shape = (ellpack.tiers, ellpack.bin_width, ellpack.n_features)
+            binning.args.update({
+                "bins.cells": cells, "bins.missing": missing,
+                "bins.onehot_rows": onehot_rows(*shape),
+                "bins.tiers": ",".join(f"{w}:{n}"
+                                       for w, n in tier_widths(*shape))})
         return ellpack
 
     def slice(self, rindex: Sequence[int]) -> "DMatrix":

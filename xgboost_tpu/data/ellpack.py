@@ -20,6 +20,7 @@ gradients and never match a node mask.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import numpy as np
@@ -74,6 +75,14 @@ class EllpackPage:
     @property
     def bin_width(self) -> int:
         return int(self.cuts_pad.shape[1])
+
+    @functools.cached_property
+    def tiers(self):
+        """The columns by the height their one-hot needs, from the ragged
+        cuts (ops/histogram.py ``bin_tiers``); None: one tier of B bins."""
+        from ..ops.histogram import bin_tiers
+
+        return bin_tiers(self.cuts.n_bins_array(), self.bin_width)
 
 
 def build_ellpack(

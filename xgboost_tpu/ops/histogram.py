@@ -33,6 +33,29 @@ known only on the device: over a list of rows, gathered 2,048 at a time, so
 that the histogram of nodes that hold a few per cent of the page costs
 their rows and not the page (the best-first pass, tree/bestfirst.py).
 
+**Bin-width tiers.**  The matmul costs what its one-hot is tall (PERF.md §5:
+linear in rows x ``F*B``), and a column whose sketch made 20 cuts fills 20
+of its ``B = 256`` one-hot rows: the others are all-zero by construction,
+multiplied in every chunk of every level, and thrown away by the split scan
+(ops/split.py masks ``bin >= n_bins[f]``).  So a chunk's one-hot is built a
+*tier* at a time: the columns that need at most 32 bins 32 rows tall, then
+those within 64, 128 and ``B``, one dot a tier against the chunk's one
+gradient operand, one accumulator a tier under the scan.  After the scan,
+once a level, ``_untier`` pads each tier with zeros to ``B`` and puts the
+columns back in their order: callers see the (N, F, B, C) histogram they
+always saw, to float32's last bits.  ``bin_tiers`` reads the tiers off the
+sketch's ragged cuts.  How many columns each tier holds (``BinTiers.widths``)
+is static to the level programs, which columns (``BinTiers.order``) an
+operand, so the counts are rounded: each tier keeps a multiple of 16 columns
+(a packed int16 sublane tile of the transposed chunk, so a tier's slice of it
+is aligned) and hands the rest, those with the most bins, to the next; the
+few columns a sample's noise moves across a boundary then move no count, and
+the seeds of one data set share their executables.  The chunk's columns are
+gathered into tier order inside the scan and the page stays in column order
+for every other reader (stored in tier order the level is 3% faster on the
+chip: PERF.md §6, PR 35).  One tier is the program without tiers, text and
+cache key.
+
 Which of these a level gets is decided here and nowhere else:
 ``level_histogram`` is what the level body (tree/grow.py), the page step
 (tree/stream.py) and the best-first pass (tree/bestfirst.py) call.  It picks
@@ -55,7 +78,9 @@ histograms with integer reductions — see ops/quantise.py.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import itertools
 from typing import NamedTuple, Optional
 
 import jax
@@ -81,28 +106,130 @@ def _onehot_feature_major(bins_c, n_bin: int, dtype):
     return onehot.astype(dtype).reshape(F * n_bin, T)
 
 
+# The heights a column's one-hot is built at, under the page's own B: the
+# narrowest that holds the column's bins (module docstring: bin-width tiers).
+_TIER_WIDTHS = (32, 64, 128)
+# A tier's columns come in whole tiles of the transposed chunk: an int16
+# chunk packs 16 columns to a sublane tile, so a tier that starts and ends on
+# a multiple of 16 is sliced out of ``bins_c.T`` without a relayout.
+_TIER_COLUMNS = 16
+
+
+@functools.partial(jax.tree_util.register_dataclass, data_fields=["order"],
+                   meta_fields=["widths"])
+@dataclasses.dataclass(frozen=True)
+class BinTiers:
+    """A page's columns by the height their one-hot needs (``bin_tiers``).
+    To a jitted program ``widths`` is static and ``order`` an operand: one
+    program a signature, whichever columns fill it."""
+
+    widths: tuple         # ((bins, columns), ...), narrowest first
+    order: "jnp.ndarray"  # (F,) int32 - the columns, tier after tier
+
+
+def bin_tiers(n_bins, n_bin: int) -> Optional[BinTiers]:
+    """The tiers of a page whose column ``f`` holds bins ``[0, n_bins[f])``
+    under the common width ``n_bin``; None where one tier of ``n_bin`` is all
+    there is (every column needs it, or there are fewer than 16 that do
+    not).  The columns sorted by their bins are cut where the narrowest
+    width that holds them changes, each cut moved down to a multiple of
+    ``_TIER_COLUMNS``: a tier keeps whole tiles and hands the columns over,
+    those with the most bins, to the next; a tier left with none is not
+    listed.  ``widths`` is a static argument of the level programs, so it
+    should not follow the few columns that a sample's noise moves across a
+    boundary: the rounding is what two seeds of one data set agree on."""
+    import numpy as np
+
+    n_bins = np.asarray(n_bins)
+    by_bins = np.argsort(n_bins, kind="stable")
+    heights = [w for w in _TIER_WIDTHS if w < n_bin] + [n_bin]
+    ends = [int(np.sum(n_bins <= w)) // _TIER_COLUMNS * _TIER_COLUMNS
+            for w in heights[:-1]] + [len(n_bins)]
+    widths, order, lo = [], [], 0
+    for w, hi in zip(heights, ends):
+        if hi > lo:
+            widths.append((w, hi - lo))
+            order.append(np.sort(by_bins[lo:hi]))
+            lo = hi
+    if len(widths) <= 1:
+        return None
+    return BinTiers(tuple(widths),
+                    jnp.asarray(np.concatenate(order), jnp.int32))
+
+
+def tier_widths(tiers: Optional[BinTiers], n_bin: int, F: int):
+    """((bins, columns), ...) of the tiers; of the one that no tiers are."""
+    return ((n_bin, F),) if tiers is None else tiers.widths
+
+
+def onehot_rows(tiers: Optional[BinTiers], n_bin: int, F: int) -> int:
+    """Rows of the one-hot operand a chunk is multiplied as: ``F * n_bin``
+    without tiers."""
+    return sum(w * n for w, n in tier_widths(tiers, n_bin, F))
+
+
+def _by_tier(parts, tiers: Optional[BinTiers]):
+    """A chunk's or a level's sums as they are carried: one array a tier,
+    the array itself where there are no tiers."""
+    return parts[0] if tiers is None else tuple(parts)
+
+
 def _hist_chunk(bins_c, gpair_c, pos_c, node0: int, n_nodes: int, n_bin: int,
-                stride: int = 1):
-    """One row-chunk's contribution: (T,F) bins -> (N,F,B,C) partial histogram."""
+                stride: int = 1, tiers: Optional[BinTiers] = None):
+    """One row-chunk's contribution: (T,F) bins -> (N,F,B,C) partial
+    histogram; under ``tiers`` one (N,F_w,w,C) a tier, columns in tier order
+    (``_untier`` is their way back)."""
     T, F = bins_c.shape
     C = gpair_c.shape[1]
-    onehot = _onehot_feature_major(bins_c, n_bin, jnp.float32)
+    widths = tier_widths(tiers, n_bin, F)
+    if tiers is None:
+        columns = [bins_c]
+    else:
+        # the chunk's columns brought into tier order, then each tier's
+        # one-hot only as tall as its bins: a bin at or above ``w`` (the
+        # sentinel too) compares false everywhere, and no column of a tier
+        # has a row in one
+        ordered = bins_c.T[tiers.order].T
+        ends = list(itertools.accumulate(n for _, n in widths))
+        columns = [ordered[:, hi - n:hi] for (_, n), hi in zip(widths, ends)]
+    onehots = [_onehot_feature_major(cols, w, jnp.float32)
+               for cols, (w, _) in zip(columns, widths)]
     nodemask = (
         pos_c[:, None] == (node0 + stride * jnp.arange(n_nodes, dtype=pos_c.dtype))
     ).astype(jnp.float32)  # (T, N)
     gm = (nodemask[:, :, None] * gpair_c[:, None, :]).reshape(T, n_nodes * C)
-    out = jnp.dot(
-        onehot, gm, preferred_element_type=jnp.float32,
-        precision=_EXACT_F32,
-    )  # (F*B, N*C)
-    return out.reshape(F, n_bin, n_nodes, C).transpose(2, 0, 1, 3)
+    return _by_tier([
+        jnp.dot(
+            onehot, gm, preferred_element_type=jnp.float32,
+            precision=_EXACT_F32,
+        )  # (F*B, N*C)
+        .reshape(n, w, n_nodes, C).transpose(2, 0, 1, 3)
+        for onehot, (w, n) in zip(onehots, widths)], tiers)
+
+
+def _untier(acc, tiers: Optional[BinTiers], n_bin: int):
+    """A level's sums by tier -> the (N, F, B, C) histogram in column order,
+    each tier padded with the zeros its missing bins would have summed to:
+    once a level, after the scan."""
+    if tiers is None:
+        return acc
+    padded = [jnp.pad(a, ((0, 0), (0, 0), (0, n_bin - w), (0, 0)))
+              for a, (w, _) in zip(acc, tiers.widths)]
+    back = jnp.zeros_like(tiers.order).at[tiers.order].set(
+        jnp.arange(tiers.order.shape[0], dtype=tiers.order.dtype))
+    return jnp.concatenate(padded, axis=1)[:, back]
+
+
+def _add(acc, part):
+    """``acc + part``, a tier at a time where they come in tiers."""
+    return jax.tree.map(jnp.add, acc, part)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("node0", "n_nodes", "n_bin", "chunk", "stride"))
 def build_histogram(
     bins, gpair, pos, *, node0: int, n_nodes: int, n_bin: int, chunk: int = 2048,
-    stride: int = 1
+    stride: int = 1, tiers: Optional[BinTiers] = None
 ):
     """hist (n_nodes, F, B, C) for nodes node0 + stride*[0, n_nodes).
 
@@ -111,9 +238,11 @@ def build_histogram(
     pos   : (R_pad,) int32   — per-row node id (-1 for padded rows)
     stride: 2 selects every other heap slot — the left-children of a level,
             for the subtraction trick (right sibling = parent - left).
+    tiers : ``bin_tiers`` of the page: the same sums (to float32's last
+            bits) over a one-hot as tall as each column's bins.
     """
     return _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk,
-                            stride)
+                            stride, tiers)
 
 
 def hist_impl_override():
@@ -241,9 +370,12 @@ def scatter_hist_driver(bins, values, pos, node0, n_nodes, n_bin, stride,
     return flat.reshape(n_nodes, F, n_bin, out_cols)
 
 
-def _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk, stride):
+def _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk, stride,
+                     tiers: Optional[BinTiers] = None):
     """Fixed-order chunked accumulation shared by the static- and
-    traced-node0 entry points (node0 may be an int or a traced scalar)."""
+    traced-node0 entry points (node0 may be an int or a traced scalar).
+    The row-pass kernels add a row where its bin is and build no one-hot:
+    ``tiers`` are the matmul's alone."""
     impl = _host_impl()
     if impl == "native":
         return _native_hist(bins, gpair, pos, node0, n_nodes, n_bin, stride)
@@ -252,20 +384,22 @@ def _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk, stride):
                                    stride, gpair.shape[1], jnp.float32)
     R, F = bins.shape
     C = gpair.shape[1]
+
+    def part(b, g, p):
+        return _hist_chunk(b, g, p, node0, n_nodes, n_bin, stride, tiers)
+
     if R <= chunk:
-        return _hist_chunk(bins, gpair, pos, node0, n_nodes, n_bin, stride)
+        return _untier(part(bins, gpair, pos), tiers, n_bin)
     n_chunks = R // chunk
     rem = R - n_chunks * chunk
 
     def body(acc, xs):
-        b, g, p = xs
-        return acc + _hist_chunk(b, g, p, node0, n_nodes, n_bin, stride), None
+        return _add(acc, part(*xs)), None
 
     # seed the carry with chunk 0 (not zeros): under shard_map the chunk
     # contributions vary over the data axis, and a scan carry must enter
     # with the same varying type it leaves with
-    acc0 = _hist_chunk(bins[:chunk], gpair[:chunk], pos[:chunk], node0,
-                       n_nodes, n_bin, stride)
+    acc0 = part(bins[:chunk], gpair[:chunk], pos[:chunk])
     xs = (
         bins[chunk: n_chunks * chunk].reshape(n_chunks - 1, chunk, F),
         gpair[chunk: n_chunks * chunk].reshape(n_chunks - 1, chunk, C),
@@ -273,15 +407,15 @@ def _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk, stride):
     )
     acc, _ = lax.scan(body, acc0, xs)
     if rem:
-        acc = acc + _hist_chunk(bins[-rem:], gpair[-rem:], pos[-rem:], node0,
-                                n_nodes, n_bin, stride)
-    return acc
+        acc = _add(acc, part(bins[-rem:], gpair[-rem:], pos[-rem:]))
+    return _untier(acc, tiers, n_bin)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bin", "chunk",
                                              "stride"))
 def build_histogram_at(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
-                       chunk: int = 2048, stride: int = 1):
+                       chunk: int = 2048, stride: int = 1,
+                       tiers: Optional[BinTiers] = None):
     """build_histogram with a TRACED starting node id.
 
     The best-first grower expands one node pair at a time with fresh ids,
@@ -292,7 +426,7 @@ def build_histogram_at(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
     """
     node0 = jnp.asarray(node0, jnp.int32)
     return _hist_accumulate(bins, gpair, pos, node0, n_nodes, n_bin, chunk,
-                            stride)
+                            stride, tiers)
 
 
 class RowList(NamedTuple):
@@ -364,7 +498,8 @@ def rows_scanned(rows: RowList, n_rows: int, chunk: int = 2048):
                                              "stride"))
 def build_histogram_listed(bins, gpair, pos, node0, rows: RowList, *,
                            n_nodes: int, n_bin: int, chunk: int = 2048,
-                           stride: int = 1):
+                           stride: int = 1,
+                           tiers: Optional[BinTiers] = None):
     """``build_histogram_at`` at the cost of the rows it is wanted of.
 
     Two loops whose trip counts are known only on the device, one of them
@@ -392,8 +527,8 @@ def build_histogram_listed(bins, gpair, pos, node0, rows: RowList, *,
         return start, start + lane >= i * T
 
     def add(acc, b, g, p, ok):
-        return acc + _hist_chunk(b, g, jnp.where(ok, p, -1), node0, n_nodes,
-                                 n_bin, stride)
+        return _add(acc, _hist_chunk(b, g, jnp.where(ok, p, -1), node0,
+                                     n_nodes, n_bin, stride, tiers))
 
     def from_page(i, acc):
         start, fresh = window(i)
@@ -410,14 +545,16 @@ def build_histogram_listed(bins, gpair, pos, node0, rows: RowList, *,
                    gpair.at[at].get(mode="promise_in_bounds"),
                    node0 + stride * (entry >> bits), ok)
 
-    acc = jnp.zeros((n_nodes, F, n_bin, gpair.shape[1]), jnp.float32)
+    acc = _by_tier([jnp.zeros((n_nodes, n, w, gpair.shape[1]), jnp.float32)
+                    for w, n in tier_widths(tiers, n_bin, F)], tiers)
     acc = lax.fori_loop(0, in_page, from_page, acc)
-    return lax.fori_loop(0, in_list, from_list, acc)
+    return _untier(lax.fori_loop(0, in_list, from_list, acc), tiers, n_bin)
 
 
 def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
                     stride: int = 1, quantised: bool = False,
-                    rows: Optional[RowList] = None):
+                    rows: Optional[RowList] = None,
+                    tiers: Optional[BinTiers] = None):
     """A level's histogram for nodes ``node0 + stride*[0, n_nodes)``: the
     one way in for the level body and the page step, who branch on nothing.
 
@@ -429,15 +566,23 @@ def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
     ``build_histogram_q``).  ``rows``: the rows that ``pos`` places among
     these nodes, listed (the best-first pass, whose nodes may hold a few
     per cent of the page): ``build_histogram_listed``, float32 and the
-    one-hot matmul only."""
+    one-hot matmul only.  ``tiers``: ``bin_tiers`` of the page, for the
+    float32 one-hot (the int8-limb sums take none yet; the row-pass kernels
+    have no one-hot to shorten).  One tier is none: the program of a page
+    whose every column needs ``n_bin`` bins is the program without tiers,
+    text and cache key."""
+    if tiers is not None and len(tiers.widths) == 1:
+        tiers = None
     if rows is not None:
         assert not quantised and not hist_is_row_pass()
         return build_histogram_listed(bins, gpair, pos, node0, rows,
                                       n_nodes=n_nodes, n_bin=n_bin,
-                                      stride=stride)
+                                      stride=stride, tiers=tiers)
     static = isinstance(node0, int)
     if quantised:
         from .quantise import build_histogram_q, hist_accumulate_q
+
+        assert tiers is None
 
         if static:
             return hist_accumulate_q(bins, gpair, pos, node0, n_nodes, n_bin,
@@ -446,9 +591,9 @@ def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
                                  n_bin=n_bin, stride=stride)
     if static:
         return build_histogram(bins, gpair, pos, node0=node0, n_nodes=n_nodes,
-                               n_bin=n_bin, stride=stride)
+                               n_bin=n_bin, stride=stride, tiers=tiers)
     return build_histogram_at(bins, gpair, pos, node0, n_nodes=n_nodes,
-                              n_bin=n_bin, stride=stride)
+                              n_bin=n_bin, stride=stride, tiers=tiers)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bin", "stride"))

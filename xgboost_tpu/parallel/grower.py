@@ -110,7 +110,8 @@ class ShardedHistTreeGrower(HistTreeGrower):
         return self._init_fn(gpair, valid)
 
     def _run_level(self, d: int, width, state, page, fm, setmat, cm,
-                   hist_prev, rho, has_cat: bool):
+                   hist_prev, rho, has_cat: bool, tiers=None):
+        assert tiers is None  # a mesh's levels build one tier (core.py)
         rho_args = () if rho is None else (rho,)
         if width is not None:
             return self._interior_fns[width](

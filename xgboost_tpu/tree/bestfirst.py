@@ -57,9 +57,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.tree import RegTree
-from ..ops.histogram import (combine_sibling_hists, hist_is_row_pass,
-                             level_histogram, node_sums, row_list,
-                             row_list_fits, rows_scanned)
+from ..ops.histogram import (BinTiers, combine_sibling_hists,
+                             hist_is_row_pass, level_histogram, node_sums,
+                             row_list, row_list_fits, rows_scanned)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 from ..telemetry.spans import count_in_round
@@ -214,13 +214,15 @@ def _init_state(pos, root_totals, *, S: int, F: int, B: int, n_sets: int):
 
 
 def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
-            gamma_eps: float, has_cat: bool, list_rows: Optional[int]):
+            gamma_eps: float, has_cat: bool, list_rows: Optional[int],
+            tiers: Optional[BinTiers] = None):
     """First half of a pass: choose the parents, route their rows, build the
     pass's histograms from the rows.  Returns ``(state, picked, built)`` with
     ``built`` (pairs, F, B, 2): of the built child of each pair, or of the
     root in the first pass (slot 0 is the built child of pair 0 there).
     ``list_rows``: the most rows for which the built children's rows are
-    scanned as a list (``_LIST_SHARE``); None: the page, every pass."""
+    scanned as a list (``_LIST_SHARE``); None: the page, every pass.
+    ``tiers``: the page's ``bin_tiers``, for the chunk's one-hot."""
     k, B = pairs, state.cand_cat_set.shape[1]
     i32 = jnp.int32
     with jax.named_scope("queue"):
@@ -279,7 +281,7 @@ def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
                             most=list_rows)
             scanned = rows_scanned(rows, pos.shape[0])
         built = level_histogram(bins, gpair, pos, state.n_alloc, n_nodes=k,
-                                n_bin=B, stride=2, rows=rows)
+                                n_bin=B, stride=2, rows=rows, tiers=tiers)
     return (state._replace(pos=pos),
             Picked(sel=sel, ok=ok, build_left=build_left, root=root,
                    scanned=scanned), built)
@@ -439,12 +441,14 @@ def level_step_bestfirst(state: BFState, bins, gpair, n_bins, root_mask,
                          pair_masks, set_matrix, cat_mask, *, pairs: int,
                          max_leaves: int, max_depth: int, gamma_eps: float,
                          params: SplitParams, has_cat: bool, monotone: bool,
-                         list_rows: Optional[int] = None):
+                         list_rows: Optional[int] = None,
+                         tiers: Optional[BinTiers] = None):
     """One pass (module docstring), the whole of it one program: a tree is
     this program run until ``state.done``, the root's pass its first run."""
     state, picked, built = _expand(
         state, bins, gpair, pairs=pairs, max_leaves=max_leaves,
-        gamma_eps=gamma_eps, has_cat=has_cat, list_rows=list_rows)
+        gamma_eps=gamma_eps, has_cat=has_cat, list_rows=list_rows,
+        tiers=tiers)
     return _settle(state, picked, built, n_bins, root_mask, pair_masks,
                    set_matrix, cat_mask, pairs=pairs, max_leaves=max_leaves,
                    max_depth=max_depth, gamma_eps=gamma_eps, params=params,
@@ -553,7 +557,11 @@ class BestFirstGrower:
         return root, jnp.asarray(draws)
 
     def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None,
-             cat_mask=None) -> BFTree:
+             cat_mask=None, tiers: Optional[BinTiers] = None) -> BFTree:
+        """``tiers``: the page's ``bin_tiers``, for one chip's one program;
+        the halves that rows in several processes run apart build one."""
+        assert tiers is None or not (self.distributed
+                                     or self.mesh is not None)
         F = bins.shape[1]
         B = cuts_pad.shape[1]
         has_cat = cat_mask is not None
@@ -593,7 +601,7 @@ class BestFirstGrower:
             if not self.distributed:
                 return level_step_bestfirst(
                     state, bins, gpair, n_bins, root_mask, pair_masks,
-                    setmat, cm, **static)
+                    setmat, cm, tiers=tiers, **static)
             state, picked, built = _expand_alone(
                 state, bins, gpair,
                 **{name: static[name] for name in _EXPAND_STATIC})

@@ -34,8 +34,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.histogram import (combine_sibling_hists, hist_is_row_pass,
-                             level_histogram, node_sums)
+from ..ops.histogram import (BinTiers, combine_sibling_hists,
+                             hist_is_row_pass, level_histogram, node_sums,
+                             onehot_rows)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 from ..telemetry.spans import count_in_round
@@ -370,7 +371,8 @@ def decide_level(state: TreeState, hist_of, cuts_pad, n_bins, feature_mask,
 def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
            set_matrix, cat_mask, hist_prev, rho, node0, N: int, *,
            params: SplitParams, last_level: bool, axis_name: Optional[str],
-           lossguide: bool, has_cat: bool, subtract: bool, quantised: bool):
+           lossguide: bool, has_cat: bool, subtract: bool, quantised: bool,
+           tiers: Optional[BinTiers] = None):
     """One level, the only place it is written: histogram -> decide -> route
     over the ``N`` heap slots from ``node0``, a Python int (``level_step``: a
     program a depth) or a traced scalar (``level_step_padded``: one program
@@ -386,6 +388,9 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
     left children (even level offsets) are built by matmul and each right
     sibling is derived as ``parent - left`` — halving both the hist FLOPs and
     (multi-chip) the psum payload.  ``hist`` is None on the last level.
+    ``tiers`` (ops/histogram.py ``bin_tiers``) shorten the one-hot inside
+    ``level_histogram`` and nothing else: what comes back is the (N, F, B, C)
+    histogram in column order.
     """
     B = cuts_pad.shape[1]
 
@@ -400,7 +405,7 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
                 # parent j of the previous level maps to offsets (2j, 2j+1)
                 left = level_histogram(bins, gpair, state.pos, node0,
                                        n_nodes=half, n_bin=B, stride=2,
-                                       quantised=quantised)
+                                       quantised=quantised, tiers=tiers)
                 if axis_name is not None:
                     left = lax.psum(left, axis_name)
                 # a parent level handed over at this level's width has its
@@ -408,7 +413,8 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
                 hist = combine_sibling_hists(left, hist_prev[:half], alive_lvl)
             else:
                 hist = level_histogram(bins, gpair, state.pos, node0,
-                                       n_nodes=N, n_bin=B, quantised=quantised)
+                                       n_nodes=N, n_bin=B, quantised=quantised,
+                                       tiers=tiers)
                 if axis_name is not None:
                     # the distributed cost (SURVEY §3.1)
                     hist = lax.psum(hist, axis_name)
@@ -455,6 +461,7 @@ def level_step(
     has_cat: bool = False,
     subtract: bool = False,
     quantised: bool = False,
+    tiers: Optional[BinTiers] = None,
 ):
     """Expand every alive node at ``depth`` (``_level``): a program a depth,
     ``node0`` and the width ``2**depth`` its constants."""
@@ -462,7 +469,7 @@ def level_step(
                   set_matrix, cat_mask, hist_prev, rho, (1 << depth) - 1,
                   1 << depth, params=params, last_level=last_level,
                   axis_name=axis_name, lossguide=lossguide, has_cat=has_cat,
-                  subtract=subtract, quantised=quantised)
+                  subtract=subtract, quantised=quantised, tiers=tiers)
 
 
 @functools.partial(
@@ -490,6 +497,7 @@ def level_step_padded(
     has_cat: bool = False,
     subtract: bool = True,
     quantised: bool = False,
+    tiers: Optional[BinTiers] = None,
 ):
     """``_level`` with the node dimension PADDED to a fixed ``width`` and
     a TRACED ``node0`` — ONE compiled program serves every interior depth
@@ -522,7 +530,8 @@ def level_step_padded(
                   set_matrix, cat_mask, hist_prev, rho,
                   jnp.asarray(node0, jnp.int32), width, params=params,
                   last_level=False, axis_name=axis_name, lossguide=lossguide,
-                  has_cat=has_cat, subtract=subtract, quantised=quantised)
+                  has_cat=has_cat, subtract=subtract, quantised=quantised,
+                  tiers=tiers)
 
 
 @jax.jit
@@ -644,7 +653,8 @@ class HistTreeGrower:
         )
 
     def _run_level(self, d: int, width: Optional[int], state, page, fm,
-                   setmat, cm, hist_prev, rho, has_cat: bool):
+                   setmat, cm, hist_prev, rho, has_cat: bool,
+                   tiers: Optional[BinTiers] = None):
         """Dispatch depth ``d``'s program: ``(state, hist)``.  With a
         ``width``, the shared padded interior program of that width with a
         traced node0: root, leaf finalize and one program a tier of
@@ -652,7 +662,7 @@ class HistTreeGrower:
         deep the tree.  With None, a program a depth."""
         md = self.max_depth
         common = dict(params=self.params, lossguide=self.lossguide,
-                      has_cat=has_cat, quantised=self.quantised)
+                      has_cat=has_cat, quantised=self.quantised, tiers=tiers)
         if width is not None:
             return level_step_padded(
                 state, *page, fm, setmat, cm, hist_prev, (1 << d) - 1, rho,
@@ -663,10 +673,12 @@ class HistTreeGrower:
             **common)
 
     def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None,
-             cat_mask=None) -> TreeState:
+             cat_mask=None, tiers: Optional[BinTiers] = None) -> TreeState:
         """feature_masks: None, or callable (depth, n_nodes) -> (1|N, F) bool mask
         (the ColumnSampler hook: bytree/bylevel/bynode, src/common/random.h).
-        cat_mask: optional (F,) bool marking categorical features."""
+        cat_mask: optional (F,) bool marking categorical features.
+        tiers: the page's ``bin_tiers`` (``EllpackPage.tiers``), for the
+        float32 one-hot on one chip; None is one tier of ``B`` bins."""
         F = bins.shape[1]
         ones = jnp.ones((1, F), dtype=bool)
         setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
@@ -680,6 +692,7 @@ class HistTreeGrower:
             gpair, rho, state = prepare_quantised(gpair, valid, state)
         md = self.max_depth
         page = (bins, gpair, cuts_pad, n_bins)
+        tall = onehot_rows(tiers, cuts_pad.shape[1], F)
         hist = None
         for d in range(md + 1):
             # the root and the leaf level have programs of their own; the
@@ -700,12 +713,13 @@ class HistTreeGrower:
             # eval_split + the position rewrite, so the bracket necessarily
             # covers all three — the name keeps the reference phase vocabulary
             # greppable in traces (bestfirst.py's pass has a span of its own);
-            # width = the slots the level was dispatched at
+            # width = the slots the level was dispatched at, onehot_rows
+            # the height of its chunks' one-hot operand
             with span("grow.build_hist+eval_split", depth=d,
-                      width=width or (1 << d)):
+                      width=width or (1 << d), onehot_rows=tall):
                 state, hist = self._run_level(
                     d, width, state, page, fm, setmat, cm,
-                    None if d == md else hist, rho, has_cat)
+                    None if d == md else hist, rho, has_cat, tiers)
         return state
 
     @staticmethod
