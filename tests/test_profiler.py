@@ -113,6 +113,80 @@ def test_clear_resets_but_keeps_sampler():
 
 
 # =========================================================================
+# the last ticks: the sampler as a witness of what held the lock
+
+
+def _wait_for_ticks(n, seconds=10):
+    deadline = time.monotonic() + seconds
+    while len(profiler.ticks()) < n and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return profiler.ticks()
+
+
+def test_ticks_are_bounded_and_carry_the_arming_threads_frames():
+    def distinctive_armer_fn():
+        assert profiler.start(hz=500)  # the thread that arms is the watched
+        return _wait_for_ticks(profiler._MAX_TICKS + 8)
+
+    try:
+        got = distinctive_armer_fn()
+        assert profiler.samples() > profiler._MAX_TICKS
+    finally:
+        profiler.stop()
+    assert len(got) == profiler._MAX_TICKS == 64
+    woke = [t[0] for t in got]
+    assert woke == sorted(woke) and woke[-1] <= time.perf_counter_ns()
+    assert all(late >= 0 for _, late, _ in got)
+    assert all(len(frames) <= profiler._TICK_FRAMES for _, _, frames in got)
+    # innermost first: the waiting helper, then its caller
+    seen = [frames for _, _, frames in got if frames]
+    assert seen and all(f[0].startswith("test_profiler:") for f in seen)
+    assert any(f[:2] == ("test_profiler:_wait_for_ticks",
+                         "test_profiler:distinctive_armer_fn") for f in seen)
+    mid = woke[len(woke) // 2]
+    assert [t[0] for t in profiler.ticks(since_ns=mid)] == [w for w in woke if w >= mid]
+    assert [t[0] for t in profiler.ticks(until_ns=mid)] == [w for w in woke if w < mid]
+
+
+def test_a_held_interpreter_lock_shows_as_a_late_tick():
+    profiler.start(hz=100)
+    try:
+        _wait_for_ticks(3)
+        profiler.clear()
+        t0 = time.perf_counter_ns()
+        sum(range(20_000_000))  # one C call: the lock is not let go inside it
+        held = time.perf_counter_ns() - t0
+        late = max(t[1] for t in _wait_for_ticks(2))
+    finally:
+        profiler.stop()
+    assert held > 50_000_000
+    assert late >= held // 2  # the tick that was due inside it woke after it
+
+
+def test_ticks_survive_clear_and_fork_as_the_stacks_do():
+    profiler.start(hz=200)
+    try:
+        assert _wait_for_ticks(3)
+        profiler.clear()  # empties both; the sampler keeps running and refills
+        assert profiler.running()
+        assert _wait_for_ticks(3) and profiler.samples() >= 3
+    finally:
+        profiler.stop()
+    kept = profiler.ticks()
+    # the fork handlers: the lock is held across a fork and released on both
+    # sides, the child drops the sampler's handle and keeps the module state
+    profiler._lock.acquire()
+    profiler._after_fork_child()
+    assert not profiler.running()
+    assert profiler.ticks() == kept and len(kept) <= profiler._MAX_TICKS
+    assert profiler.start(hz=200)  # the child's own sampler appends to it
+    try:
+        assert len(_wait_for_ticks(len(kept) + 1)) > len(kept) or len(kept) == 64
+    finally:
+        profiler.stop()
+
+
+# =========================================================================
 # merged flame view
 
 

@@ -155,6 +155,35 @@ def test_span_flag_off_ring_and_annotation_only(tmp_path, monkeypatch):
     assert inner["dur_ns"] <= outer["dur_ns"]
     assert [e["name"] for e in flight.events()[-2:]] == ["guard.phase",
                                                          "guard.outer"]
+    # the hooks that ride the loop's top-level spans with no switch: the
+    # clocks and the collector's totals (compile.counting), the collector's
+    # own record of an old collection, the account and the loop's watch
+    import gc
+
+    from xgboost_tpu.telemetry import compile as _compile, pauses
+    from xgboost_tpu.telemetry.registry import get_registry
+
+    pauses.install()
+    families = [f.name for f in get_registry().families()]
+    watch = pauses.RoundWatch()
+    for i in range(100, 104):  # rounds no earlier test has left in the ring
+        with _compile.counting(watch.top(_spans.step_span("train.round", i))) as sp:
+            watch.opened(sp)
+            if i == 102:
+                gc.collect()
+        with _compile.counting(watch.top(_spans.span("train.after_iteration",
+                                                      round=i))):
+            pass
+    watch.finished()
+    watch.slow = (102, 0, 90, 30)
+    watch.tell()  # the slow round's event and its one line
+    assert [e["name"] for e in flight.events()[-1:]] == ["train.slow_round"]
+    assert [a["round"] for a in _spans.round_account(100)] == [100, 101, 102, 103]
+    assert _spans.recent("train.round")[-1]["cpu_ns"] >= 0
+    assert any(r["generation"] == 2 and r.get("round") == 102
+               for r in _spans.recent("host.gc"))
+    assert ["host.gc", {"generation": 2}, "exited"] in opened  # on a profile too
+    assert [f.name for f in get_registry().families()] == families
     assert _spans.phase_totals() == before
     assert _spans._children == children
     assert not path.exists()

@@ -77,9 +77,9 @@ class CallbackContainer:
 
     def after_iteration(self, model, epoch, dtrain, evals) -> bool:
         if evals:
-            from .telemetry import span
+            from .telemetry.spans import wait_span
 
-            with span("eval.eval_set"):
+            with wait_span("eval.eval_set"):
                 msg = model.eval_set(evals, epoch, feval=self.metric)
             self.update_history(msg)
         return any(cb.after_iteration(model, epoch, self.history) for cb in self.callbacks)

@@ -32,6 +32,7 @@ from .ops.predict import predict_leaf_ids
 from .ops.split import SplitParams
 from .params import TrainParam, canonicalize, split_unknown
 from .telemetry import span
+from .telemetry.spans import wait_span
 from .tree.grow import HistTreeGrower, leaf_margin_delta
 
 __all__ = ["Booster"]
@@ -442,10 +443,10 @@ class Booster:
         return self._base_margin_value
 
     # ------------------------------------------------------------------ train
-    def update(self, dtrain: DMatrix, iteration: int, fobj=None) -> None:
-        """One boosting iteration (learner.cc:1108 UpdateOneIter)."""
-        import jax.numpy as jnp
-
+    def _prepare_update(self, dtrain: DMatrix) -> _Cache:
+        """What a round needs before its margin: the configuration, the
+        training cache of ``dtrain``, and the objective's and the booster's
+        bindings to the matrix (bounds, query groups, categories, names)."""
         self._configure()
         cache = self._get_cache(dtrain)
         if self.tree_method == "exact" and not cache.is_extmem:
@@ -488,6 +489,12 @@ class Booster:
             # package: train() carries dtrain.feature_names onto the booster)
             # so dumps, importance and get_categories key by name
             self.feature_names = list(dtrain.feature_names)
+        return cache
+
+    def update(self, dtrain: DMatrix, iteration: int, fobj=None) -> None:
+        """One boosting iteration (learner.cc:1108 UpdateOneIter)."""
+        with span("update.prepare"):
+            cache = self._prepare_update(dtrain)
         if self.process_type == "update":
             # the update flow keeps its own running margin over the already-
             # updated prefix; the full-model margin/gradient pass below would
@@ -499,14 +506,17 @@ class Booster:
             self._ensure_base_margin(cache)
             self._update_existing_trees(cache, iteration)
             return
-        self._sync_margin(cache)
-        drop_idx = self._select_dart_drops(iteration)
+        with span("update.sync_margin"):
+            self._sync_margin(cache)
+            drop_idx = self._select_dart_drops(iteration)
         if drop_idx:
             # DART drop round: the gradient must be evaluated on the reduced
             # margin, which _boost_trees builds — skip the full-margin pass
             # so a custom fobj is invoked exactly once
             gpair = None
         else:
+            # the objective's programs (eager ones for the elementwise
+            # objectives) and the product with the rows' validity mask
             with span("update.gradient"):
                 if fobj is not None:
                     # custom objectives receive RAW margins (reference:
@@ -518,8 +528,7 @@ class Booster:
                     gpair = self.objective.get_gradient(
                         cache.margin, cache.labels, cache.weights, iteration
                     )  # (R_pad, K, 2)
-        if gpair is not None:
-            gpair = gpair * cache.valid[:, None, None]
+                gpair = gpair * cache.valid[:, None, None]
         from .utils import observer
 
         if observer.enabled():
@@ -1524,11 +1533,13 @@ class Booster:
             and str(self.params.get("_lockstep", "0")).lower()
             in ("1", "true"))
         for p_idx in range(max(self.num_parallel_tree, 1)):
-            fmask_fn = self._feature_masks(iteration * 131 + p_idx, p_idx, ell.n_features,
-                                           cache.dmat.info.feature_weights)
-            # one independent subsample per parallel tree (reference: each
-            # member of the forest draws its own rows)
-            gp = self._subsample_mask(gpair, iteration * 131 + p_idx)
+            with span("update.sample"):
+                fmask_fn = self._feature_masks(
+                    iteration * 131 + p_idx, p_idx, ell.n_features,
+                    cache.dmat.info.feature_weights)
+                # one independent subsample per parallel tree (reference:
+                # each member of the forest draws its own rows)
+                gp = self._subsample_mask(gpair, iteration * 131 + p_idx)
             if lockstep_ok and fmask_fn is None:
                 from .tree.grow_lockstep import (LockstepHistGrower,
                                                  leaf_margin_delta_k)
@@ -1557,9 +1568,11 @@ class Booster:
                     n_new += 1
                 continue
             for k in range(K):
+                with span("update.class_gradient"):  # an eager slice
+                    gp_k = gp[:, k, :]
                 state = grower.grow(
                     bins_use,
-                    gp[:, k, :],
+                    gp_k,
                     cache.valid,
                     cuts_use,
                     nbins_use,
@@ -1698,7 +1711,7 @@ class Booster:
         metrics = self._eval_metric_list()
         proc_par = self._process_parallel()
         for dmat, name in evals:
-            with span("eval.predict"):
+            with wait_span("eval.predict"):
                 margin = self._eval_margin(dmat)
             preds = np.asarray(self.objective.pred_transform(margin))
             if self.n_groups == 1:

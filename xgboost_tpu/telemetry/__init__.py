@@ -38,7 +38,9 @@ serving/metrics counters with no export format):
 - **Sampling profiler** (profiler.py): default-on wall sampler
   (``XGBOOST_TPU_PROF_HZ``, a few Hz) whose folded stacks ship with
   every telemetry payload into a driver-side merged flame view
-  (``profiler.render_folded()`` — collapsed-stack format).
+  (``profiler.render_folded()`` — collapsed-stack format), and whose last
+  ticks (``profiler.ticks()``: how late each woke, what the training thread
+  was in) a slow round's line quotes.
 
 Quick start::
 
@@ -61,7 +63,8 @@ from .spans import (PHASE_HISTOGRAM, Span, disable, enable, enabled,
                     phase_totals, record_phase, span, step_span)
 from .compile import (COMPILE_EVENT, compile_delta, compiles_total,
                       loads_total, traces_total)
-from . import distributed, flight, native_pool, profiler, trace, xplane
+from . import (distributed, flight, native_pool, pauses, profiler, trace,
+               xplane)
 from .distributed import (MergedRegistry, get_merged, snapshot_payload,
                           start_metrics_server, stop_metrics_server)
 from .callback import TelemetryCallback
@@ -73,7 +76,8 @@ __all__ = [
     "record_phase", "phase_totals", "PHASE_HISTOGRAM",
     "compiles_total", "loads_total", "traces_total", "compile_delta",
     "COMPILE_EVENT",
-    "trace", "native_pool", "distributed", "flight", "profiler", "xplane",
+    "trace", "native_pool", "distributed", "flight", "pauses", "profiler",
+    "xplane",
     "MergedRegistry", "get_merged", "snapshot_payload",
     "start_metrics_server", "stop_metrics_server",
     "TelemetryCallback",

@@ -13,7 +13,14 @@ retraced, without touching the training loop itself::
     cb.history[3]["dispatches"]                    # device programs launched
     cb.history[3]["host_syncs"]                    # waits and copies to host
     cb.history[3]["trees"][0]["leaves"]
+    cb.history[3]["account"]["self_ns"]            # the period, partitioned
     cb.compiles_steady                             # SLO: 0 after round 0
+
+``account`` is ``spans.round_account``'s entry of the round: its period (to
+the next round's opening) split into self times by span that sum to it, with
+the collector's and the clocks' shares beside them.  A round's period is over
+only when the next round has run, so ``history[i]["account"]`` appears one
+round late (the last round's in ``after_training``).
 
 Round 0 is the warm-up round (every level program traces there); compiles
 in later rounds are steady-state retraces and feed the registry counter
@@ -73,6 +80,7 @@ class TelemetryCallback(TrainingCallback):
         self._t0 = 0.0
         self._ntrees0 = 0
         self._warm_round: Optional[int] = None  # first round of current run
+        self._run0 = 0  # where the current run's rounds begin in history
         self._steady_counter = None
 
     # ------------------------------------------------- TrainingCallback API
@@ -87,6 +95,7 @@ class TelemetryCallback(TrainingCallback):
         return model
 
     def after_training(self, model):
+        self._account()
         return model
 
     def before_iteration(self, model, epoch: int, evals_log) -> bool:
@@ -128,6 +137,7 @@ class TelemetryCallback(TrainingCallback):
         self._round_boundary(rec, seconds, coll)
         if self._warm_round is None:
             self._warm_round = epoch
+            self._run0 = len(self.history)
         if compiles:
             if epoch == self._warm_round:  # first round of THIS run
                 self.compiles_warmup += compiles
@@ -140,9 +150,21 @@ class TelemetryCallback(TrainingCallback):
                         ("scope",)).labels("train")
                 self._steady_counter.inc(compiles)
         self.history.append(rec)
+        self._account()
         return False
 
     # ------------------------------------------------------------ internals
+    def _account(self) -> None:
+        """Give this run's rounds whose period is over their account (a
+        period is over one round late: the last two entries can wait)."""
+        first = max(self._run0, len(self.history) - 2)
+        waiting = {rec["round"]: rec for rec in self.history[first:]
+                   if "account" not in rec}
+        if waiting:
+            for acct in spans.round_account(min(waiting)):
+                if acct["round"] in waiting:
+                    waiting[acct["round"]]["account"] = acct
+
     @staticmethod
     def _coll_sums() -> Dict[Any, Any]:
         """Current (op, rank) -> (count, seconds) of the collective-wait
@@ -163,13 +185,12 @@ class TelemetryCallback(TrainingCallback):
 
     def _round_boundary(self, rec: Dict[str, Any], seconds: float,
                         coll: Dict[str, float]) -> None:
-        """Distributed observability at the round boundary: flight-ring
-        breadcrumb, rate-limited snapshot ship to the tracker, and the
-        optional cross-rank straggler report (one extra allgather)."""
-        from . import distributed, flight
+        """Distributed observability at the round boundary: rate-limited
+        snapshot ship to the tracker, and the optional cross-rank straggler
+        report (one extra allgather).  (The ring has the round as its
+        ``train.round`` span record.)"""
+        from . import distributed
 
-        flight.record("event", "train.round", round=rec["round"],
-                      seconds=seconds)
         try:
             distributed.ship_to_tracker()
         except Exception:  # pragma: no cover - shipping is best-effort

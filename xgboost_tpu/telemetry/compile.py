@@ -36,8 +36,10 @@ feed:
   for the ring (435 in a three-round toy train, against a ring of 512), so
   they are counted only;
 - :func:`counting`, which puts the three counts of a span's lifetime into
-  its ring record: training wraps the round's two spans in it, so that a
-  window's rounds can be shown to hold no compile, no load and no trace.
+  its ring record, and with them what the clocks and the collector did while
+  it was open (pauses.py): training wraps the loop's three top-level spans
+  in it, so that a window's rounds can be shown to hold no compile, no load
+  and no trace, and a round's period to split into waits, CPU and neither.
 
 ``jax.monitoring`` listeners cannot be unregistered individually, so this
 must never be registered twice (the module guard) and must stay cheap
@@ -49,7 +51,7 @@ import contextlib
 import threading
 from typing import Dict, Iterator, Optional
 
-from . import flight, spans
+from . import flight, pauses, spans
 from .registry import get_registry
 
 __all__ = ["compiles_total", "loads_total", "traces_total", "compile_delta",
@@ -148,13 +150,17 @@ def traces_total() -> int:
 def counting(sp: spans.Span) -> Iterator[spans.Span]:
     """``with counting(span(...)):`` is ``with span(...):`` whose ring record
     also says how many programs were ``compiled`` and ``loaded`` and how many
-    functions ``traced`` while it was open."""
+    functions ``traced`` while it was open, and what the thread's and the
+    process's clocks and the collector's totals grew by (``pauses.since``:
+    ``cpu_ns``, ``proc_cpu_ns``, ``ctx_invol``, ``majflt``, ``gc.ns``, ...)."""
     before = dict(_totals)
     with sp:
+        meter = pauses.read()
         try:
             yield sp
         finally:
             sp.args.update((k, _totals[k] - before[k]) for k in KINDS)
+            sp.args.update(pauses.since(meter))
 
 
 class compile_delta:

@@ -26,23 +26,33 @@ label — into one flame view: :func:`merged_folded` returns the combined
 dict, :func:`render_folded` writes the collapsed-stack file any
 FlameGraph tool consumes plus a human-readable top-stacks text.
 
+The sampler is also a witness of what no span can bracket.  It needs the
+interpreter lock to wake, so **how late a tick woke** is how long somebody
+held the lock (a collection of the oldest generation, a C call that kept
+it) or how long the whole process did not run.  Beside the folded counts it
+keeps its last :data:`_MAX_TICKS` ticks (:func:`ticks`): the tick's time on
+the spans' clock, its lateness, and the innermost frames of the thread that
+last armed the sampler (``xtb.train``'s); a slow round's one log line
+(pauses.py) quotes the ticks that fell into it.
+
 Clock discipline: pacing uses ``time.monotonic`` deadlines only
 (xtblint XTB501 — no wall clock anywhere in the sampler).
 """
 from __future__ import annotations
 
+import collections
 import os
 import sys
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 from .registry import get_registry
 
 __all__ = [
     "ENV_HZ", "DEFAULT_HZ", "configured_hz", "start", "maybe_start",
-    "stop", "running", "samples", "folded_snapshot", "merged_folded",
-    "render_folded", "clear",
+    "stop", "running", "samples", "ticks", "folded_snapshot",
+    "merged_folded", "render_folded", "clear",
 ]
 
 ENV_HZ = "XGBOOST_TPU_PROF_HZ"
@@ -50,6 +60,8 @@ DEFAULT_HZ = 5.0      # a few Hz: ~200ms between ticks, invisible in walls
 _MAX_DEPTH = 64       # frames kept per stack (deepest dropped beyond this)
 _MAX_STACKS = 4096    # distinct folded keys kept (overflow folds to a bin)
 _OVERFLOW_KEY = "overflow;stacks_truncated"
+_MAX_TICKS = 64       # last ticks kept: 13 s at the default rate
+_TICK_FRAMES = 5      # innermost frames of the watched thread kept a tick
 
 _lock = threading.Lock()
 _thread: Optional[threading.Thread] = None
@@ -58,6 +70,10 @@ _hz = 0.0
 _label = ""
 _samples = 0
 _stacks: Dict[str, int] = {}
+_watched = 0  # ident of the thread that armed the sampler last
+# (perf_counter_ns as the tick woke, ns past its deadline, watched frames)
+_ticks: Deque[Tuple[int, int, Tuple[str, ...]]] = collections.deque(
+    maxlen=_MAX_TICKS)
 
 
 def _after_fork_child() -> None:
@@ -102,11 +118,13 @@ def _frame_entry(code) -> str:
     return f"{base}:{code.co_name}"
 
 
-def _sample_once(own_ident: int) -> List[str]:
+def _sample_once(own_ident: int) -> Tuple[List[str], Tuple[str, ...]]:
     """One tick: every live thread's stack as a folded key (root-first),
-    excluding the sampler's own thread."""
+    excluding the sampler's own thread, and the innermost frames of the
+    watched thread, innermost first."""
     names = {t.ident: t.name for t in threading.enumerate()}
     keys: List[str] = []
+    inner: Tuple[str, ...] = ()
     for ident, frame in sys._current_frames().items():
         if ident == own_ident:
             continue
@@ -115,10 +133,12 @@ def _sample_once(own_ident: int) -> List[str]:
         while f is not None and len(parts) < _MAX_DEPTH:
             parts.append(_frame_entry(f.f_code))
             f = f.f_back
+        if ident == _watched:
+            inner = tuple(parts[:_TICK_FRAMES])
         parts.reverse()
         thread = names.get(ident) or f"tid-{ident}"
         keys.append(thread + ";" + ";".join(parts))
-    return keys
+    return keys, inner
 
 
 def _run(stop_evt: threading.Event, period: float) -> None:
@@ -135,13 +155,19 @@ def _run(stop_evt: threading.Event, period: float) -> None:
             # fell behind (suspended / heavily loaded): skip missed ticks
             # instead of bursting to catch up
             next_t = time.monotonic()
+        # past the deadline by this much: the lock was held or the process
+        # did not run (a tick that fell behind by whole periods reads 0 and
+        # the one before it the stall)
+        late_ns = max(0, int((time.monotonic() - next_t) * 1e9))
+        woke_ns = time.perf_counter_ns()
         next_t += period
         try:
-            keys = _sample_once(own)
+            keys, inner = _sample_once(own)
         except Exception:
             continue  # a racing thread teardown must not kill the sampler
         with _lock:
             _samples += 1
+            _ticks.append((woke_ns, late_ns, inner))
             for k in keys:
                 if k in _stacks:
                     _stacks[k] += 1
@@ -157,11 +183,12 @@ def start(hz: Optional[float] = None, label: str = "") -> bool:
     """Start the sampler (idempotent).  ``hz=None`` reads the env knob;
     ``hz<=0`` is a no-op returning False.  A second ``start`` while
     running just returns True — the first rate wins until :func:`stop`."""
-    global _thread, _stop_evt, _hz, _label
+    global _thread, _stop_evt, _hz, _label, _watched
     rate = configured_hz() if hz is None else max(0.0, float(hz))
     if rate <= 0.0:
         return False
     with _lock:
+        _watched = threading.get_ident()
         if _thread is not None and _thread.is_alive():
             if label:
                 _label = str(label)
@@ -212,6 +239,21 @@ def clear() -> None:
     with _lock:
         _samples = 0
         _stacks.clear()
+        _ticks.clear()
+
+
+def ticks(since_ns: int = 0, until_ns: Optional[int] = None
+          ) -> List[Tuple[int, int, Tuple[str, ...]]]:
+    """The last ticks (at most :data:`_MAX_TICKS`), oldest first, each
+    ``(woke_ns, late_ns, frames)``: ``time.perf_counter_ns()`` as the tick
+    woke (the spans' clock), the nanoseconds it woke past its deadline, and
+    the innermost frames (``module:function``, innermost first) of the
+    thread that last armed the sampler.  ``since_ns`` / ``until_ns`` keep
+    the ticks that woke in that stretch."""
+    with _lock:
+        kept = list(_ticks)
+    return [t for t in kept if t[0] >= since_ns
+            and (until_ns is None or t[0] < until_ns)]
 
 
 def folded_snapshot() -> Optional[dict]:

@@ -116,26 +116,29 @@ def busy_intervals(events: Iterable[Tuple]) -> List[Tuple[float, float]]:
     return [(s, e) for s, e in out]
 
 
-def self_seconds(spans: Iterable[Span]) -> Dict[str, Dict[str, float]]:
-    """{name: {"count", "total_s", "self_s"}} of the spans of ONE thread: a
-    span's self time is its duration less the part its direct children
-    cover (overlapping siblings cover their union once)."""
-    out: Dict[str, Dict[str, float]] = {}
+def self_time(spans: Iterable[Span]) -> Dict[str, List[float]]:
+    """{name: [count, total, self]} of the spans of ONE thread, in the unit
+    of their times (integer nanoseconds stay integers, so the self times of
+    a span and of everything under it sum to its duration exactly): a span's
+    self time is its duration less the part its direct children cover
+    (overlapping siblings cover their union once).  ``spans.round_account``
+    partitions a round's period with it."""
+    out: Dict[str, List[float]] = {}
     stack: List[list] = []  # [name, start, end, children]
 
     def close(frame) -> None:
         name, s, e, children = frame
         covered = sum(b - a for a, b in busy_intervals(
             (None, max(cs, s), min(ce, e) - max(cs, s)) for cs, ce in children))
-        rec = out.setdefault(name, {"count": 0, "total_s": 0.0, "self_s": 0.0})
-        rec["count"] += 1
-        rec["total_s"] += (e - s) * 1e-9
-        rec["self_s"] += (e - s - covered) * 1e-9
+        rec = out.setdefault(name, [0, 0, 0])
+        rec[0] += 1
+        rec[1] += e - s
+        rec[2] += e - s - covered
 
     for name, s, d in sorted(spans, key=lambda x: (x[1], -x[2])):
         # a span that outlasts the open one (by over a ns of rounding) is
         # its sibling, not its child
-        while stack and (stack[-1][2] <= s or s + d > stack[-1][2] + 1.0):
+        while stack and (stack[-1][2] <= s or s + d > stack[-1][2] + 1):
             close(stack.pop())
         if stack:
             stack[-1][3].append((s, s + d))
@@ -143,6 +146,13 @@ def self_seconds(spans: Iterable[Span]) -> Dict[str, Dict[str, float]]:
     while stack:
         close(stack.pop())
     return out
+
+
+def self_seconds(spans: Iterable[Span]) -> Dict[str, Dict[str, float]]:
+    """:func:`self_time` of spans in nanoseconds, as {name: {"count",
+    "total_s", "self_s"}}."""
+    return {name: {"count": n, "total_s": total * 1e-9, "self_s": own * 1e-9}
+            for name, (n, total, own) in self_time(spans).items()}
 
 
 def idle_gaps(ops: Iterable[Tuple], start_ns: float, end_ns: float,

@@ -176,12 +176,15 @@ def train(
     elastic mode.  Requires an elastic-capable collective backend
     (tracker relay or in-memory) — docs/reliability.md § Elastic
     training."""
-    from .telemetry import profiler
+    from .telemetry import pauses, profiler
 
     # default-on wall sampler (XGBOOST_TPU_PROF_HZ=0 disables): training
     # rounds show up in the merged flame view; sampling only reads
-    # frames, so the trained model is bitwise-identical either way
+    # frames, so the trained model is bitwise-identical either way.  It
+    # watches this thread: its ticks are what a slow round's line quotes
     profiler.maybe_start("train")
+    # the collector's pauses, counted from here on (telemetry/pauses.py)
+    pauses.install()
     callbacks = list(callbacks) if callbacks else []
     evals = list(evals) if evals else []
     if isinstance(dtrain, ExtMemConfig):
@@ -319,51 +322,62 @@ def train(
     from .telemetry.compile import counting
     from .telemetry.distributed import ship_to_tracker
 
+    # the loop's three top-level spans (train.boundary, train.round,
+    # train.after_iteration) tile a round's period; the watch keeps the last
+    # periods and says once, in the ring and the log, why one ran long (at
+    # the boundary after the round that followed it)
+    watch = pauses.RoundWatch()
+    closed = {}  # the boundary's round: the one whose period it lies in
     i = start
     while i < end:
-        if elastic is not None and collective.regroup_pending():
-            # round-boundary absorption/shrink: membership changed while
-            # this worker was between rounds
-            bst, dtrain, evals, i = _elastic_regroup(
-                params, elastic, cbs, callbacks, ckpt_cb, evals,
-                bst.num_boosted_rounds())
-            _wd.progress("shard_map", map=ckpt_cb.shard_map)
-            continue
         try:
-            # liveness marker + (tracker mode) a rate-limited snapshot
-            # ship: the tracker's stall watchdog distinguishes a slow
-            # round from a frozen one by whether this marker advances,
-            # and its journal tracks the per-rank resume round from it
-            _wd.progress("train.round", round=i)
-            ship_to_tracker()
-            # fault seam (kill/exception/delay; no-op without a plan): the
-            # round boundary is where a worker death is injected for the
-            # kill->resume parity tests
-            maybe_inject("train.round", round=i, rank=collective.get_rank)
+            with counting(watch.top(span("train.boundary", next=i, **closed))):
+                watch.tell()
+                if elastic is not None and collective.regroup_pending():
+                    # round-boundary absorption/shrink: membership changed
+                    # while this worker was between rounds
+                    raise RegroupRequired("membership changed between rounds")
+                # liveness marker + (tracker mode) a rate-limited snapshot
+                # ship: the tracker's stall watchdog distinguishes a slow
+                # round from a frozen one by whether this marker advances,
+                # and its journal tracks the per-rank resume round from it
+                _wd.progress("train.round", round=i)
+                ship_to_tracker()
+                # fault seam (kill/exception/delay; no-op without a plan):
+                # the round boundary is where a worker death is injected for
+                # the kill->resume parity tests
+                maybe_inject("train.round", round=i, rank=collective.get_rank)
             # the round span closes before after_iteration on purpose:
             # callbacks start and stop profiler sessions there, and an
             # annotation that straddles a session's edge is lost
-            with counting(step_span("train.round", i)):
-                stop = cbs.before_iteration(bst, i, dtrain, evals)
+            with counting(watch.top(step_span("train.round", i))) as opened:
+                watch.opened(opened)
+                with span("train.before_iteration"):
+                    stop = cbs.before_iteration(bst, i, dtrain, evals)
                 if not stop:
                     bst.update(dtrain, i, fobj=obj)
             if stop:
                 break
-            with counting(span("train.after_iteration", round=i)):
+            with counting(watch.top(span("train.after_iteration", round=i))):
                 stop = cbs.after_iteration(bst, i, dtrain, evals)
         except RegroupRequired:
             if elastic is None:
                 raise
-            # a peer died (or a replacement arrived) mid-round: abandon the
-            # partial round, regroup, and re-enter from the last checkpoint
+            # a peer died (or a replacement arrived) mid-round or between
+            # rounds: abandon the partial round, regroup, and re-enter from
+            # the last checkpoint
             bst, dtrain, evals, i = _elastic_regroup(
                 params, elastic, cbs, callbacks, ckpt_cb, evals,
                 bst.num_boosted_rounds())
             _wd.progress("shard_map", map=ckpt_cb.shard_map)
+            watch.reset()
+            closed = {}
             continue
         if stop:
             break
+        closed = {"round": i}
         i += 1
+    watch.finished()
     bst = cbs.after_training(bst)
 
     if evals_result is not None:

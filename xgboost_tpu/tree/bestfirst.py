@@ -62,7 +62,7 @@ from ..ops.histogram import (BinTiers, combine_sibling_hists,
                              row_list, row_list_fits, rows_scanned)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
-from ..telemetry.spans import count_in_round
+from ..telemetry.spans import count_in_round, wait_span
 from .grow import _update_positions, make_set_matrix
 
 _EPS = 1e-6
@@ -565,22 +565,23 @@ class BestFirstGrower:
         F = bins.shape[1]
         B = cuts_pad.shape[1]
         has_cat = cat_mask is not None
-        cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
-        setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
-        root_mask, pair_masks = self._masks(feature_masks, F)
+        with span("grow.setup"):  # the tree's state and masks, before a pass
+            cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
+            setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
+            root_mask, pair_masks = self._masks(feature_masks, F)
 
-        if self.mesh is not None:
-            from ..parallel import shard_rows
+            if self.mesh is not None:
+                from ..parallel import shard_rows
 
-            bins, gpair, valid = shard_rows(self.mesh, bins, gpair, valid)
-        pos = jnp.where(valid, 0, -1).astype(jnp.int32)
-        root = node_sums(gpair, pos, node0=0, n_nodes=1)[0]
-        if self.distributed:
-            from .. import collective  # the loop's passes use it too
+                bins, gpair, valid = shard_rows(self.mesh, bins, gpair, valid)
+            pos = jnp.where(valid, 0, -1).astype(jnp.int32)
+            root = node_sums(gpair, pos, node0=0, n_nodes=1)[0]
+            if self.distributed:
+                from .. import collective  # the loop's passes use it too
 
-            root = jnp.asarray(collective.allreduce(np.asarray(root)))
-        state = _init_state(pos, root, S=self._grow_slots, F=F, B=B,
-                            n_sets=setmat.shape[0])
+                root = jnp.asarray(collective.allreduce(np.asarray(root)))
+            state = _init_state(pos, root, S=self._grow_slots, F=F, B=B,
+                                n_sets=setmat.shape[0])
         rows, width = int(bins.shape[0]), 2 * self.pairs
         # a pass scans the rows of the children it builds where they are few
         # (_LIST_SHARE); under a mesh a trip count that differs by shard
@@ -627,7 +628,7 @@ class BestFirstGrower:
                     state = run(state)
                     told.append(state.told)
                     sent += 1
-                with span("grow.wait_device"):
+                with wait_span("grow.wait_device"):
                     done, splits_now, alloc_now, scanned_now = (
                         int(v) for v in np.asarray(told.popleft()))
                 sp.args.update(pairs=(alloc_now - max(n_alloc, 1)) // 2,
@@ -636,7 +637,7 @@ class BestFirstGrower:
             passes, n_alloc, n_splits = passes + 1, alloc_now, splits_now
             scanned.append(scanned_now)
         if told:  # the pass sent ahead of a tree that stopped short
-            with span("grow.wait_device"):
+            with wait_span("grow.wait_device"):
                 scanned += [int(np.asarray(unread)[3]) for unread in told]
         count_in_round(**{
             "bestfirst.passes": sent,
@@ -644,8 +645,9 @@ class BestFirstGrower:
             "bestfirst.pairs_committed": n_splits,
             "bestfirst.hist_rows": sum(scanned),
             "bestfirst.listed_passes": sum(n < rows for n in scanned)})
-        return _finish(state, n_slots=self.n_slots)._replace(
-            n_nodes=2 * n_splits + 1)
+        with span("grow.finish"):  # the dispatch of _finish, not its run
+            return _finish(state, n_slots=self.n_slots)._replace(
+                n_nodes=2 * n_splits + 1)
 
     def to_regtree(self, tree: BFTree, cuts_pad) -> "tuple[RegTree, np.ndarray]":
         """(RegTree in pop order, leaf_val array for the margin update)."""
@@ -653,42 +655,44 @@ class BestFirstGrower:
         fields = ("left", "right", "parent", "feat", "sbin", "dleft", "gain",
                   "totals", "lower", "upper", "is_cat", "cat_set")
         # every pass has been waited for; what is left is _finish
-        with span("grow.wait_device"):
+        with wait_span("grow.wait_device"):
             jax.block_until_ready(tree)
-        with span("grow.to_host", copies=len(fields) + 1):
+        with wait_span("grow.to_host", copies=len(fields) + 1):
             (left, right, parent, feat, sbin, dleft, gain, totals, lower,
              upper, is_cat, cat_set) = (
                 np.asarray(getattr(tree, name))[:n] for name in fields)
             cuts_np = np.asarray(cuts_pad)
-        B = cuts_np.shape[1]
+        # host only, but for calc_weight's trip to the device and back
+        with span("tree.to_regtree"):
+            B = cuts_np.shape[1]
 
-        p = self.params
-        w = np.asarray(calc_weight(jnp.asarray(totals[:, 0]),
-                                   jnp.asarray(totals[:, 1]), p,
-                                   jnp.asarray(lower), jnp.asarray(upper)))
-        leaf_mask = left == -1
-        thr = np.where(leaf_mask, 0.0,
-                       cuts_np[np.clip(feat, 0, None),
-                               np.minimum(sbin, B - 1)]).astype(np.float32)
-        leaf_val_full = np.zeros(self.n_slots, np.float32)
-        leaf_val_full[:n] = np.where(leaf_mask, p.eta * w, 0.0)
+            p = self.params
+            w = np.asarray(calc_weight(jnp.asarray(totals[:, 0]),
+                                       jnp.asarray(totals[:, 1]), p,
+                                       jnp.asarray(lower), jnp.asarray(upper)))
+            leaf_mask = left == -1
+            thr = np.where(leaf_mask, 0.0,
+                           cuts_np[np.clip(feat, 0, None),
+                                   np.minimum(sbin, B - 1)]).astype(np.float32)
+            leaf_val_full = np.zeros(self.n_slots, np.float32)
+            leaf_val_full[:n] = np.where(leaf_mask, p.eta * w, 0.0)
 
-        cats = {}
-        for i in np.nonzero(~leaf_mask)[0]:
-            if is_cat[i]:
-                cats[int(i)] = np.nonzero(cat_set[i])[0].astype(np.int32)
-        regtree = RegTree(
-            left_children=left.astype(np.int32),
-            right_children=right.astype(np.int32),
-            parents=parent.astype(np.int32),
-            split_indices=np.where(leaf_mask, 0, feat).astype(np.int32),
-            split_conditions=np.where(leaf_mask, p.eta * w, thr).astype(np.float32),
-            default_left=dleft.astype(bool),
-            base_weights=w.astype(np.float32),
-            loss_changes=np.where(leaf_mask, 0.0, gain).astype(np.float32),
-            sum_hessian=totals[:, 1].astype(np.float32),
-            split_bins=np.where(leaf_mask, 0, sbin).astype(np.int32),
-            split_type=is_cat.astype(np.int32),
-            categories=cats or {},
-        )
-        return regtree, jnp.asarray(leaf_val_full)
+            cats = {}
+            for i in np.nonzero(~leaf_mask)[0]:
+                if is_cat[i]:
+                    cats[int(i)] = np.nonzero(cat_set[i])[0].astype(np.int32)
+            regtree = RegTree(
+                left_children=left.astype(np.int32),
+                right_children=right.astype(np.int32),
+                parents=parent.astype(np.int32),
+                split_indices=np.where(leaf_mask, 0, feat).astype(np.int32),
+                split_conditions=np.where(leaf_mask, p.eta * w, thr).astype(np.float32),
+                default_left=dleft.astype(bool),
+                base_weights=w.astype(np.float32),
+                loss_changes=np.where(leaf_mask, 0.0, gain).astype(np.float32),
+                sum_hessian=totals[:, 1].astype(np.float32),
+                split_bins=np.where(leaf_mask, 0, sbin).astype(np.int32),
+                split_type=is_cat.astype(np.int32),
+                categories=cats or {},
+            )
+            return regtree, jnp.asarray(leaf_val_full)

@@ -39,7 +39,7 @@ from ..ops.histogram import (BinTiers, combine_sibling_hists,
                              onehot_rows)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
-from ..telemetry.spans import count_in_round
+from ..telemetry.spans import count_in_round, wait_span
 
 _EPS = 1e-6
 
@@ -680,16 +680,17 @@ class HistTreeGrower:
         tiers: the page's ``bin_tiers`` (``EllpackPage.tiers``), for the
         float32 one-hot on one chip; None is one tier of ``B`` bins."""
         F = bins.shape[1]
-        ones = jnp.ones((1, F), dtype=bool)
-        setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
-        has_cat = cat_mask is not None
-        cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
-        state = self._init_state(gpair, valid, setmat, cuts_pad, has_cat)
-        rho = None
-        if self.quantised:
-            from ..ops.quantise import prepare_quantised
+        with span("grow.setup"):  # the tree's state and masks, before a level
+            ones = jnp.ones((1, F), dtype=bool)
+            setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
+            has_cat = cat_mask is not None
+            cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
+            state = self._init_state(gpair, valid, setmat, cuts_pad, has_cat)
+            rho = None
+            if self.quantised:
+                from ..ops.quantise import prepare_quantised
 
-            gpair, rho, state = prepare_quantised(gpair, valid, state)
+                gpair, rho, state = prepare_quantised(gpair, valid, state)
         md = self.max_depth
         page = (bins, gpair, cuts_pad, n_bins)
         tall = onehot_rows(tiers, cuts_pad.shape[1], F)
@@ -700,23 +701,27 @@ class HistTreeGrower:
             # their tier
             width = (level_width(d, md)
                      if self.padded_levels and 0 < d < md else None)
-            fm = ones if feature_masks is None else feature_masks(d, 1 << d)
-            if width is not None:
-                fm = self._pad_mask(fm, width)
-                if hist.shape[0] != width:
-                    # the parent level's histogram (the root's, or a
-                    # narrower tier's), handed over at this level's width:
-                    # its real rows first, zero rows after
-                    hist = jnp.zeros((width,) + hist.shape[1:],
-                                     hist.dtype).at[:hist.shape[0]].set(hist)
             # one span per level: the compiled program fuses build_hist +
             # eval_split + the position rewrite, so the bracket necessarily
             # covers all three — the name keeps the reference phase vocabulary
             # greppable in traces (bestfirst.py's pass has a span of its own);
             # width = the slots the level was dispatched at, onehot_rows
-            # the height of its chunks' one-hot operand
+            # the height of its chunks' one-hot operand.  The level's inputs
+            # are made inside it (eager programs of their own, a millisecond
+            # a tree): its column mask and its parent's histogram
             with span("grow.build_hist+eval_split", depth=d,
                       width=width or (1 << d), onehot_rows=tall):
+                fm = (ones if feature_masks is None
+                      else feature_masks(d, 1 << d))
+                if width is not None:
+                    fm = self._pad_mask(fm, width)
+                    if hist.shape[0] != width:
+                        # the parent level's histogram (the root's, or a
+                        # narrower tier's), handed over at this level's
+                        # width: its real rows first, zero rows after
+                        hist = jnp.zeros(
+                            (width,) + hist.shape[1:],
+                            hist.dtype).at[:hist.shape[0]].set(hist)
                 state, hist = self._run_level(
                     d, width, state, page, fm, setmat, cm,
                     None if d == md else hist, rho, has_cat, tiers)
@@ -738,9 +743,10 @@ class HistTreeGrower:
 
         # the one place a round with no evals blocks on the device: what is
         # left of the round's host time is the loop's own
-        with span("grow.wait_device"):
+        with wait_span("grow.wait_device"):
             jax.block_until_ready(state)
-        with span("grow.to_host", copies=len(GrownTree._fields)) as copying:
+        with wait_span("grow.to_host",
+                       copies=len(GrownTree._fields)) as copying:
             tree = GrownTree(
                 is_cat=np.asarray(state.is_cat),
                 cat_set=np.asarray(state.cat_set),
