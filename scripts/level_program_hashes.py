@@ -12,7 +12,9 @@ default  what HistTreeGrower.grow really dispatches, each program hashed at
 --mesh   the same for ShardedHistTreeGrower on four forced host devices.
 --v5e    root, shared interior (one a width tier) and leaf program of each
          cell at its real shape, lowered for a described v5e (nothing
-         compiles, nothing runs): the text the chip's cache key is made of.
+         compiles, nothing runs): the text the chip's cache key is made of,
+         the levels that ``ops/histogram.py:hist_form`` gives the one-pass
+         kernel handed the transposed page, as the grower hands it.
 
 In every mode the best-first pass of the cell higgs-leafwise-255.train
 (tree/bestfirst.py ``level_step_bestfirst``) comes last, where the checkout
@@ -127,14 +129,29 @@ def for_v5e(F, depth, rows):
             shape((1, F), bool), shape((1, F), bool), shape((F,), bool))
     common = dict(params=PARAMS, axis_name=None, lossguide=False,
                   has_cat=False, quantised=False)
+
+    def page_t(built):
+        """The transposed page, where the checkout's grower would hand it to
+        a level of ``built`` nodes (PR 37: the one-pass kernel; the backend
+        here is the CPU, so the rule is told that the programs are a
+        chip's)."""
+        from xgboost_tpu.ops import hist_pallas, histogram
+
+        if not hasattr(histogram, "hist_form"):
+            return {}
+        histogram._on_tpu = lambda: True
+        hist_pallas._resolve_interpret = bool  # None: compiled, as on a chip
+        return ({"bins_t": (shape((F, rows), jnp.int16),)}
+                if histogram.hist_form(built) == "onepass" else {})
+
     show("root", grow.level_step.lower(
         *head, None, None, depth=0, last_level=False, subtract=False,
-        **common))
+        **common, **page_t(1)))
     # node0 as HistTreeGrower.grow passes it: a Python int, weakly typed
     for W in sorted({grow.level_width(d, depth) for d in range(1, depth)}):
         show(f"shared interior width={W}", grow.level_step_padded.lower(
             *head, shape((W, F, B, 2), jnp.float32), 1, None, width=W,
-            subtract=True, **common))
+            subtract=True, **common, **page_t(W // 2)))
     show(f"leaf level depth={depth}", grow.level_step.lower(
         *head, None, None, depth=depth, last_level=True, subtract=False,
         **common))

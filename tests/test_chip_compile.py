@@ -69,6 +69,9 @@ def device_paths(monkeypatch):
     the backend sniffs of ops/ would trace the host's FFI kernels."""
     monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
     monkeypatch.setenv("XTB_NO_NATIVE_SPLIT", "1")
+    monkeypatch.setattr("xgboost_tpu.ops.histogram._on_tpu", lambda: True)
+    monkeypatch.setattr("xgboost_tpu.ops.hist_pallas._resolve_interpret",
+                        lambda interpret: bool(interpret))
 
 
 def _shape(shape, dtype, sharding):
@@ -84,17 +87,19 @@ LEVELS = {"root": dict(node0=0, n_nodes=1, stride=1),
 @pytest.mark.parametrize("level", sorted(LEVELS))
 @pytest.mark.parametrize("bins_dtype", [jnp.uint8, jnp.int16],
                          ids=["uint8", "int16"])
-@pytest.mark.parametrize("form", ["float32", "int8limb"])
+@pytest.mark.parametrize("form", ["onepass", "int8limb"])
 def test_fused_hist_kernel_compiles_for_v5e(one_chip, form, bins_dtype,
                                             level):
-    """Both fused kernels, compiled and not interpreted, at the tiles
-    choose_tiles picks.  uint8 is the page of max_bin <= 254; int16 is what
-    the grower really holds at max_bin=256 (257 symbols with the sentinel)."""
+    """Both forms of the fused kernel (three bfloat16 terms with float32
+    sums: what a round runs; int8 limbs with int32 sums: unwired), compiled
+    and not interpreted, at the tiles choose_tiles picks.  uint8 is the page
+    of max_bin <= 254; int16 is what the grower really holds at max_bin=256
+    (257 symbols with the sentinel)."""
     from xgboost_tpu.ops.hist_pallas import (build_histogram_pallas,
                                              build_histogram_pallas_q)
 
     kernel, vals = {
-        "float32": (build_histogram_pallas,
+        "onepass": (build_histogram_pallas,
                     _shape((ROWS, 2), jnp.float32, one_chip)),
         "int8limb": (build_histogram_pallas_q,
                      _shape((ROWS, 2, 3), jnp.int8, one_chip)),
@@ -175,6 +180,42 @@ def test_padded_level_program_compiles_for_v5e(one_chip, device_paths, depth):
     assert "custom_call_target=\"xtb_" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("program", ["root", "width32", "width128"])
+def test_level_programs_hold_the_onepass_kernel_where_the_rule_says(
+        one_chip, device_paths, program):
+    """The level programs as ``HistTreeGrower.grow(resident=True)`` runs
+    them on a chip, each handed the transposed page: ``level_step`` at depth
+    0 (6 operand rows) and ``level_step_padded`` at 32 slots (96) hold the
+    Mosaic kernel and no one-hot convolution; at 128 slots (384 rows: bound
+    by the multiply-add already) ``level_histogram`` keeps the XLA form
+    whatever it is handed.  The best-first pass (192 rows) is never handed a
+    transposed page: ``test_bestfirst_pass_compiles_for_v5e`` holds it to
+    its convolutions under the same fixture."""
+    from xgboost_tpu.tree.grow import level_step, level_step_padded
+
+    page_t = (_shape((F, ROWS), jnp.int16, one_chip),)
+    if program == "root":
+        def step(page_t, *args):  # a fresh function: a fresh trace
+            return level_step.__wrapped__(
+                *args, None, None, depth=0, params=_split_params(),
+                last_level=False, subtract=False, bins_t=page_t)
+        args = _level_args(one_chip, one_chip)
+    else:
+        W = int(program[5:])
+
+        def step(page_t, *args):
+            return level_step_padded.__wrapped__(
+                *args, width=W, params=_split_params(), subtract=True,
+                bins_t=page_t)
+        args = _level_args(one_chip, one_chip, 8) + (
+            _shape((W, F, B, 2), jnp.float32, one_chip),
+            _shape((), jnp.int32, one_chip))
+    text = jax.jit(step).lower(page_t, *args).compile().as_text()
+    assert ("tpu_custom_call" in text) == (program != "width128")
+    assert (" convolution(" in text) == (program == "width128")
+    assert "custom_call_target=\"xtb_" not in text
+
+
 def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
     """``level_step_bestfirst`` at 28 columns, 256 bins and the cell's budget
     of 255 leaves: 32 pairs a pass, the one-hot matmul for the 32 built
@@ -214,6 +255,7 @@ def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
         _shape((F,), bool, one_chip)).compile()
     text = compiled.as_text()
     assert "custom_call_target=\"xtb_" not in text
+    assert "tpu_custom_call" not in text  # 32 pairs: 192 operand rows
     moved = re.findall(r"= \w+\[(\d+)[\],][^\n]* (?:gather|scatter)\(", text)
     assert moved and max(int(n) for n in moved) <= max(grower._grow_slots,
                                                        2048), moved
@@ -243,6 +285,7 @@ def test_sharded_level_program_allreduces_on_four_chips(topo, device_paths,
         _shape((W, F, B, 2), jnp.float32, rep), _shape((), jnp.int32, rep))
     compiled = grower._interior_fns[W].lower(*args).compile()
     assert "all-reduce" in compiled.as_text()
+    assert "tpu_custom_call" not in compiled.as_text()  # a mesh: the XLA form
 
 
 def test_ranking_gradient_compiles_for_v5e(one_chip):

@@ -200,9 +200,10 @@ def test_pallas_bench_shape_tiles_interpret():
     from xgboost_tpu.ops.histogram import build_histogram
 
     B = 257
-    for F, n_nodes, stride in ((28, 16, 2), (28, 32, 1), (54, 64, 2)):
+    for F, n_nodes, stride, tile in ((28, 16, 2, 2048), (28, 32, 1, 2048),
+                                     (54, 64, 2, 512)):
         T, FG = choose_tiles(F, B, n_nodes, 1)
-        assert (T, FG) == (1024, min(F, 32)), (F, n_nodes, T, FG)
+        assert (T, FG) == (tile, min(F, 32)), (F, n_nodes, T, FG)
         rng = np.random.default_rng(F)
         R = 2 * T + 517  # two full tiles + ragged remainder
         bins = jnp.asarray(rng.integers(0, B + 1, size=(R, F)), jnp.int32)
@@ -230,7 +231,7 @@ def test_pallas_quantised_bench_shape_tiles_interpret():
 
     B, F, n_nodes = 257, 28, 16
     T, FG = choose_tiles(F, B, n_nodes, 1, out_ch=6)
-    assert (T, FG) == (1024, 28)
+    assert (T, FG) == (2048, 28)
     rng = np.random.default_rng(3)
     R = 2 * T + 301
     bins = jnp.asarray(rng.integers(0, B + 1, size=(R, F)), jnp.int32)
@@ -587,3 +588,234 @@ def test_bin_tiers_signature_is_steady_near_a_boundary():
     assert len(edge) == 3 and (a != b).sum() >= 3
     assert ta.widths == tb.widths
     assert not np.array_equal(np.asarray(ta.order), np.asarray(tb.order))
+
+
+# ---- the one-pass kernel: three exact bfloat16 terms against a bfloat16
+# ---- one-hot (ops/hist_pallas.py), interpreted here ------------------------
+
+_SPLIT_CASES = {
+    "random": lambda rng: rng.normal(size=4096),
+    "negative": lambda rng: -np.abs(rng.normal(size=4096)) * 7.3,
+    "tiny": lambda rng: rng.normal(size=4096) * 1e-25,
+    "huge": lambda rng: rng.normal(size=4096) * 1e37,
+    "mixed": lambda rng: np.concatenate([
+        [0.0, -0.0, 1.0, -1.0, 3.0e38, -3.0e38, 2.0 ** -100, 1 / 3],
+        np.exp(rng.uniform(-60, 80, size=4096)) * rng.choice([-1, 1], 4096)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SPLIT_CASES))
+def test_split3_is_exact(case):
+    """``hi + mid + lo == g`` bit for bit, each term a bfloat16, for every
+    float32 from 2**-102 to bfloat16's largest."""
+    from xgboost_tpu.ops.hist_pallas import split3
+
+    g = _SPLIT_CASES[case](np.random.default_rng(len(case))).astype(
+        np.float32)
+    terms = jax.jit(split3)(jnp.asarray(g))
+    assert all(t.dtype == jnp.bfloat16 for t in terms)
+    hi, mid, lo = (np.asarray(t.astype(jnp.float32)) for t in terms)
+    np.testing.assert_array_equal((hi + mid) + lo, g)
+    assert np.all(np.abs(mid) <= np.abs(hi) * 2.0 ** -8 + 1e-45)
+    assert np.all(np.abs(lo) <= np.abs(hi) * 2.0 ** -16 + 1e-45)
+
+
+def test_split3_drops_only_what_float32_cannot_hold_as_a_third_term():
+    """Under 2**-102 the third term falls under bfloat16's normal range and
+    is flushed: what is lost is under 1.2e-38 an element, never more."""
+    from xgboost_tpu.ops.hist_pallas import split3
+
+    g = (np.random.default_rng(1).normal(size=4096) * 1e-33).astype(
+        np.float32)
+    hi, mid, lo = (np.asarray(t.astype(jnp.float32))
+                   for t in jax.jit(split3)(jnp.asarray(g)))
+    assert np.max(np.abs(((hi + mid) + lo) - g)) < 1.2e-38
+
+
+_ONEPASS = {
+    # name: (F, n_bin, dtype, n_nodes, node0, stride)
+    "root": (28, 256, np.int16, 1, 0, 1),
+    "16-nodes": (28, 256, np.int16, 16, 31, 2),
+    "uint8": (28, 255, np.uint8, 16, 31, 2),
+    "wide-page": (54, 256, np.int16, 4, 7, 2),
+    "all-sentinel": (5, 64, np.int16, 2, 1, 1),
+}
+
+
+@pytest.mark.parametrize("node0_kind", ["static", "traced"])
+@pytest.mark.parametrize("case", sorted(_ONEPASS))
+def test_onepass_histogram_is_the_float32_sum(monkeypatch, case, node0_kind):
+    """The one-pass kernel against a float64 sum of the same rows, within
+    float32's rounding of a bin's magnitudes, and against the XLA form:
+    root, 16 built nodes at stride 2, ``node0`` a constant or a scalar the
+    kernel reads from SMEM, uint8 and int16 pages, the sentinel, two row
+    tiles and a ragged third, a feature group and a ragged second."""
+    from xgboost_tpu.ops.hist_pallas import onepass_histogram
+    from xgboost_tpu.ops.histogram import _hist_accumulate
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    F, n_bin, dtype, N, node0, stride = _ONEPASS[case]
+    T = 256
+    R = 2 * T + 77
+    rng = np.random.default_rng(F + N)
+    bins = rng.integers(0, n_bin + 1, size=(R, F)).astype(dtype)
+    if case == "all-sentinel":
+        bins[:] = n_bin
+    gpair = (rng.normal(size=(R, 2))
+             * np.exp(rng.uniform(-8, 8, size=(R, 1)))).astype(np.float32)
+    pos = rng.integers(node0 - 1, node0 + stride * N + 1,
+                       size=R).astype(np.int32)
+    node = node0 if node0_kind == "static" else jnp.int32(node0)
+    got = np.asarray(jax.jit(lambda bt, g, p, n0: onepass_histogram(
+        bt, g, p, n0, n_nodes=N, n_bin=n_bin, stride=stride, interpret=True,
+        row_tile=T))(jnp.asarray(bins.T), jnp.asarray(gpair),
+                     jnp.asarray(pos), node))
+    assert got.shape == (N, F, n_bin, 2) and got.dtype == np.float32
+    want = np.zeros((N, F, n_bin, 2))
+    mags = np.zeros((N, F, n_bin, 2))
+    for n in range(N):
+        rows = np.nonzero(pos == node0 + stride * n)[0]
+        for f in range(F):
+            ok = rows[bins[rows, f] < n_bin]
+            np.add.at(want[n, f], bins[ok, f], gpair[ok].astype(np.float64))
+            np.add.at(mags[n, f], bins[ok, f], np.abs(gpair[ok]))
+    assert (mags.sum() > 0) == (case != "all-sentinel")
+    assert np.all(np.abs(got - want) <= 4 * 2.0 ** -24 * mags + 1e-30)
+    xla = np.asarray(jax.jit(lambda b, g, p: _hist_accumulate(
+        b, g, p, node0, N, n_bin, T, stride))(
+            jnp.asarray(bins), jnp.asarray(gpair), jnp.asarray(pos)))
+    assert np.all(np.abs(got - xla) <= 8 * 2.0 ** -24 * mags + 1e-30)
+
+
+@pytest.mark.parametrize("signature", ["32+256", "all-four", "uint8"])
+def test_onepass_histogram_of_a_page_with_tiers(monkeypatch, signature):
+    """Through ``level_histogram``, told that the programs are a chip's: a
+    kernel call a tier over ``transposed_page``'s arrays, each at its
+    tier's height, comes back as the (N, F, B, C) histogram in column order
+    that the XLA form gives; exact on gradients whose sums are exact."""
+    from xgboost_tpu.ops import histogram as H
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    widths = SIGNATURES[signature]
+    n_bin = widths[-1][0]
+    dtype = np.uint8 if signature == "uint8" else np.int16
+    rng = np.random.default_rng(len(signature))
+    n_bins = _n_bins_for(widths, n_bin, rng)
+    n_bins[int(np.argmin(n_bins))] = 0  # a column with no value at all
+    tiers = H.bin_tiers(n_bins, n_bin)
+    assert tiers.widths == widths
+    N, node0, stride = 3, 7, 2
+    bins, gpair, pos = _tier_case(n_bins, n_bin, dtype, 2 * 256 + 77, N,
+                                  node0, stride, seed=3)
+    want = H.level_histogram(bins, gpair, pos, jnp.int32(node0), n_nodes=N,
+                             n_bin=n_bin, stride=stride, tiers=tiers)
+    pages = H.transposed_page(bins, tiers)
+    assert [p.shape for p in pages] == [(n, bins.shape[0]) for _, n in widths]
+    monkeypatch.setattr(H, "_on_tpu", lambda: True)
+    got = jax.jit(lambda b, g, p, n0, t, bt: H.level_histogram(
+        b, g, p, n0, n_nodes=N, n_bin=n_bin, stride=stride, tiers=t,
+        bins_t=bt))(bins, gpair, pos, jnp.int32(node0), tiers, pages)
+    assert got.shape == want.shape and float(jnp.abs(want).sum()) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+_RULE = {
+    # name: (n_nodes, extra, on a chip?, host impl, the form)
+    "root-6-rows": (1, {}, True, "matmul", "onepass"),
+    "32-slots-96-rows": (16, {}, True, "matmul", "onepass"),
+    "21-nodes-126-rows": (21, {}, True, "matmul", "onepass"),
+    "22-nodes-132-rows": (22, {}, True, "matmul", "xla"),
+    "bestfirst-192-rows": (32, {}, True, "matmul", "xla"),
+    "128-slots-384-rows": (64, {}, True, "matmul", "xla"),
+    "int8-limbs": (16, {"quantised": True}, True, "matmul", "xla"),
+    "row-list": (16, {"listed": True}, True, "matmul", "xla"),
+    "mesh": (16, {"sharded": True}, True, "matmul", "xla"),
+    "four-channels": (16, {"channels": 4}, True, "matmul", "xla"),
+    "cpu-matmul": (1, {}, False, "matmul", "xla"),
+    "row-pass-scatter": (1, {}, True, "scatter", "xla"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE))
+def test_hist_form_rule(monkeypatch, case):
+    """``hist_form``: the one-pass kernel where the gradient operand's
+    three terms fit one 128-wide tile, on a chip's one-hot matmul over the
+    whole page with float32 sums; the XLA form everywhere else.  And
+    ``level_histogram`` follows it: the kernel is traced exactly where the
+    rule says ``onepass`` and the transposed page was handed in."""
+    from xgboost_tpu.ops import histogram as H
+
+    n_nodes, extra, on_tpu, impl, form = _RULE[case]
+    monkeypatch.setenv("XTB_HIST_IMPL", impl)
+    monkeypatch.setattr(H, "_on_tpu", lambda: on_tpu)
+    assert H.hist_form(n_nodes, **extra) == form
+    if set(extra) - {"sharded"}:
+        return
+    from xgboost_tpu.ops import hist_pallas
+
+    calls = []
+    kernel = hist_pallas.onepass_histogram
+    monkeypatch.setattr(hist_pallas, "onepass_histogram",
+                        lambda *a, **kw: calls.append(kw) or kernel(*a, **kw))
+    R, F, B = 512, 4, 32
+    args = (jnp.zeros((R, F), jnp.int16), jnp.zeros((R, 2), jnp.float32),
+            jnp.zeros((R,), jnp.int32), jnp.int32(0))
+    for handed, expect in ((True, form == "onepass"), (False, False)):
+        del calls[:]
+        out = jax.eval_shape(lambda b, g, p, n0, bt: H.level_histogram(
+            b, g, p, n0, n_nodes=n_nodes, n_bin=B, bins_t=bt, **extra),
+            *args, (jnp.zeros((F, R), jnp.int16),) if handed else None)
+        assert out.shape == (n_nodes, F, B, 2)
+        assert bool(calls) == expect
+
+
+def test_grower_hands_the_transposed_page_where_the_rule_says(monkeypatch):
+    """``HistTreeGrower.grow(resident=True)`` told that its programs are a
+    chip's: the root and the 32-slot levels say ``hist_form=onepass`` on
+    their span and read one kept copy of the page, the round counts them,
+    and the tree is the XLA form's; without ``resident`` every level says
+    ``xla`` and no copy is made."""
+    from xgboost_tpu.data.ellpack import build_ellpack
+    from xgboost_tpu.data.quantile import sketch_dense
+    from xgboost_tpu.ops import histogram as H
+    from xgboost_tpu.ops.split import SplitParams
+    from xgboost_tpu.telemetry import spans
+    from xgboost_tpu.tree.grow import HistTreeGrower
+
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    rng = np.random.default_rng(3)
+    R, F = 1500, 6
+    X = rng.normal(size=(R, F)).astype(np.float32)
+    X[rng.random((R, F)) < 0.2] = np.nan
+    y = np.nan_to_num(X[:, 0] * X[:, 1]) + np.nan_to_num(X[:, 2]) > 0
+    ell = build_ellpack(X, sketch_dense(X, 32, use_device=False),
+                        row_align=64)
+    gp = np.zeros((ell.n_padded, 2), np.float32)
+    gp[:R] = np.stack([0.5 - y, np.full(R, 0.25)], 1)
+    valid = jnp.arange(ell.n_padded) < R
+    params = SplitParams(eta=0.3, gamma=0.0, min_child_weight=1.0,
+                         lambda_=1.0, alpha=0.0, max_delta_step=0.0)
+    trees, forms, growers = {}, {}, {}
+    for name, on_tpu, resident in (("xla", True, False),
+                                   ("onepass", True, True),
+                                   ("cpu", False, True)):
+        monkeypatch.setattr(H, "_on_tpu", lambda on_tpu=on_tpu: on_tpu)
+        jax.clear_caches()
+        g = growers[name] = HistTreeGrower(3, params, padded_levels=True)
+        for _ in range(2):  # the second tree reads the first one's copy
+            trees[name] = HistTreeGrower.to_host(g.grow(
+                ell.bins, jnp.asarray(gp), valid, ell.cuts_pad, ell.n_bins,
+                resident=resident))
+        forms[name] = [r["hist_form"] for r in spans.recent(
+            "grow.build_hist+eval_split")[-4:]]
+    assert forms["onepass"] == ["onepass"] * 3 + ["none"]
+    assert forms["xla"] == forms["cpu"] == ["xla"] * 3 + ["none"]
+    assert growers["xla"]._page_t is None and growers["cpu"]._page_t is None
+    kept = growers["onepass"]._page_t
+    assert kept[0] is ell.bins and kept[2][0].shape == ell.bins.shape[::-1]
+    for name in ("onepass", "cpu"):
+        np.testing.assert_array_equal(trees[name].feat, trees["xla"].feat)
+        np.testing.assert_array_equal(trees[name].sbin, trees["xla"].sbin)
+        np.testing.assert_allclose(trees[name].leaf_val,
+                                   trees["xla"].leaf_val, rtol=1e-5,
+                                   atol=1e-6)

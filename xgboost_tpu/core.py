@@ -1469,10 +1469,16 @@ class Booster:
         # the one-hot a column's bins tall (ops/histogram.py bin_tiers): one
         # chip's float32 matmul over the resident page; a mesh, processes,
         # the int8 limbs and a page binned anew each round build one tier
-        tiers_use = (ell.tiers if mesh is None and not proc_par and not det
-                     and self.tree_method != "approx"
-                     and not hist_is_row_pass() else None)
+        resident = (mesh is None and not proc_par and not det
+                    and self.tree_method != "approx"
+                    and not hist_is_row_pass())
+        tiers_use = ell.tiers if resident else None
         tiers_arg = {} if tiers_use is None else {"tiers": tiers_use}
+        if resident and not best_first:
+            # the same page tree after tree: the depth-wise grower keeps a
+            # transposed copy of it for the levels that the one-pass kernel
+            # builds (ops/histogram.py hist_form)
+            tiers_arg["resident"] = True
         if self.tree_method == "approx":
             # grow_histmaker (updater_approx.cc): fresh hessian-weighted
             # sketch every iteration, then the same hist machinery; cut

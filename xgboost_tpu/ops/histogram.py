@@ -63,11 +63,22 @@ float32 or int8-limb sums by ``quantised``, the static or the traced entry
 point by what ``node0`` is, the listed scan where it is handed a list of
 rows (the best-first pass alone hands one), and the one-hot
 matmul, the XLA scatter or the native row-pass kernel by ``_host_impl``: on
-the CPU backend neither matmul runs by default.  The fused Pallas kernels
-(ops/hist_pallas.py: the one-hot built in VMEM by hand) are compiled and
-pinned by their tests and have no caller in the library; the PR that puts
-them in a round wires them behind ``level_histogram``, chosen from what the
-code observes (ROADMAP D1).
+the CPU backend neither matmul runs by default.
+
+**One pass where the operand fits a tile.**  Up to 64 output columns the XLA
+form costs 2.05 ps an element of the one-hot whatever is multiplied into it
+(three bfloat16 passes for ``HIGHEST`` over an operand built through
+float32).  Where ``hist_form`` says ``onepass`` (a TPU's matmul, float32
+sums, the whole page on one chip, ``3 * C * n_nodes <= 128``: the root's 6
+operand rows, a 32-slot level's 96) and the caller hands the transposed page
+(``transposed_page``: the depth-wise grower keeps one), the level is the
+fused kernel of ops/hist_pallas.py, a call a tier: the gradient pair split
+into three exact bfloat16 terms, a bfloat16 one-hot built in VMEM, one MXU
+pass, the same float32 sums (PERF.md §6, PR 37: a level 0.2006 -> 0.077 s at
+10.5M x 28 x 256).  Wider levels run at 89% of the three-pass peak already
+and keep the XLA form, as does everything that is not handed the transposed
+page.  The kernel's int8-limb form is compiled and pinned by its tests and
+has no caller yet (ROADMAP D1).
 
 Determinism: float32 accumulation in a fixed sequential chunk order — within
 one topology, the role played by fixed-point gradient quantisation in the
@@ -551,10 +562,54 @@ def build_histogram_listed(bins, gpair, pos, node0, rows: RowList, *,
     return _untier(lax.fori_loop(0, in_list, from_list, acc), tiers, n_bin)
 
 
+# The widest gradient operand the one-pass kernel takes: one 128-wide MXU
+# tile holds the three bfloat16 terms of every channel of every built node.
+_ONEPASS_ROWS = 128
+
+
+def _on_tpu() -> bool:
+    """Whether programs traced now are the chip's (the fused kernel compiles
+    for a TPU and nothing else runs it in a round)."""
+    return jax.default_backend() == "tpu"
+
+
+def hist_form(n_nodes: int, *, channels: int = 2, quantised: bool = False,
+              listed: bool = False, sharded: bool = False) -> str:
+    """``"onepass"`` or ``"xla"``: how a level that builds ``n_nodes`` nodes
+    gets its histogram, from what the code sees and nothing else.  The fused
+    one-pass kernel (ops/hist_pallas.py) where the chip's one-hot matmul
+    would run, the sums are float32, the page is scanned whole on one chip,
+    and the gradient operand fits one MXU tile: ``3 * channels * n_nodes <=
+    128``, the root's 6 rows and a 32-slot level's 96.  Wider levels are
+    bound by the multiply-add, not by the one-hot (PERF.md §5), and keep the
+    XLA form, as do the int8-limb sums, a list of rows, a mesh, processes
+    and the CPU backend's row-pass kernels."""
+    fits = 3 * channels * n_nodes <= _ONEPASS_ROWS
+    if (fits and _on_tpu() and _host_impl() == "matmul" and not quantised
+            and not listed and not sharded):
+        return "onepass"
+    return "xla"
+
+
+@jax.jit
+def transposed_page(bins, tiers: Optional[BinTiers] = None):
+    """The page as the one-pass kernel reads it: one ``(F_w, R)`` array a
+    tier, rows in the lanes, a tier's columns in tier order (one array where
+    there are no tiers, or one).  Made once a page by whoever keeps the page
+    (tree/grow.py), not a level: at 10.5M x 28 the transpose costs 2.7 ms,
+    a twentieth of the root's level (PERF.md §6, PR 37)."""
+    if tiers is None or len(tiers.widths) == 1:
+        return (bins.T,)
+    ends = list(itertools.accumulate(n for _, n in tiers.widths))
+    return tuple(bins.T[tiers.order[hi - n:hi]]
+                 for (_, n), hi in zip(tiers.widths, ends))
+
+
 def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
                     stride: int = 1, quantised: bool = False,
                     rows: Optional[RowList] = None,
-                    tiers: Optional[BinTiers] = None):
+                    tiers: Optional[BinTiers] = None,
+                    bins_t: Optional[tuple] = None, sharded: bool = False):
     """A level's histogram for nodes ``node0 + stride*[0, n_nodes)``: the
     one way in for the level body and the page step, who branch on nothing.
 
@@ -570,9 +625,24 @@ def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
     float32 one-hot (the int8-limb sums take none yet; the row-pass kernels
     have no one-hot to shorten).  One tier is none: the program of a page
     whose every column needs ``n_bin`` bins is the program without tiers,
-    text and cache key."""
+    text and cache key.  ``bins_t``: ``transposed_page`` of the same page and
+    tiers, from a caller that keeps one; with it, where ``hist_form`` says
+    ``onepass`` (``sharded``: the caller runs under a mesh), the level is the
+    one-pass kernel, a call a tier; without it, the XLA form whatever the
+    rule says, text and cache key."""
     if tiers is not None and len(tiers.widths) == 1:
         tiers = None
+    if bins_t is not None and hist_form(
+            n_nodes, channels=gpair.shape[1], quantised=quantised,
+            listed=rows is not None, sharded=sharded) == "onepass":
+        from .hist_pallas import onepass_histogram
+
+        widths = tier_widths(tiers, n_bin, bins.shape[1])
+        assert [t.shape[0] for t in bins_t] == [n for _, n in widths]
+        return _untier(_by_tier([
+            onepass_histogram(page, gpair, pos, node0, n_nodes=n_nodes,
+                              n_bin=w, stride=stride)
+            for page, (w, _) in zip(bins_t, widths)], tiers), tiers, n_bin)
     if rows is not None:
         assert not quantised and not hist_is_row_pass()
         return build_histogram_listed(bins, gpair, pos, node0, rows,

@@ -38,6 +38,8 @@ class ShardedHistTreeGrower(HistTreeGrower):
     """Drop-in replacement for HistTreeGrower over a 1-D mesh: its loop and
     its width rule, each level program wrapped in ``shard_map``."""
 
+    sharded = True
+
     def __init__(self, max_depth: int, params: SplitParams, mesh, *,
                  interaction_sets=None, max_leaves: int = 0,
                  lossguide: bool = False, quantised: bool = False) -> None:
@@ -110,8 +112,9 @@ class ShardedHistTreeGrower(HistTreeGrower):
         return self._init_fn(gpair, valid)
 
     def _run_level(self, d: int, width, state, page, fm, setmat, cm,
-                   hist_prev, rho, has_cat: bool, tiers=None):
-        assert tiers is None  # a mesh's levels build one tier (core.py)
+                   hist_prev, rho, has_cat: bool, tiers=None, bins_t=None):
+        # a mesh's levels build one tier (core.py) in the XLA form
+        assert tiers is None and bins_t is None
         rho_args = () if rho is None else (rho,)
         if width is not None:
             return self._interior_fns[width](

@@ -55,11 +55,18 @@ ENV_SIZE = "XGBOOST_TPU_FLIGHT_SIZE"
 ENV_SPILL = "XGBOOST_TPU_FLIGHT_SPILL_S"
 
 
+# Whole rounds of a ten-second window have to fit the ring for the
+# benchmark's program_span metrics to read (telemetry/spans.py recent): at 22
+# records a round, 512 held 23 rounds, and a depth-6 round is 0.52 s since
+# PR 37 (18 untraced rounds of a traced window: 77% of 512).
+_DEFAULT_SIZE = 1024
+
+
 def _ring_size() -> int:
     try:
-        return max(16, int(os.environ.get(ENV_SIZE, "512")))
+        return max(16, int(os.environ.get(ENV_SIZE, _DEFAULT_SIZE)))
     except ValueError:
-        return 512
+        return _DEFAULT_SIZE
 
 
 _lock = threading.Lock()

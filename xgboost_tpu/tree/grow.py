@@ -35,8 +35,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.histogram import (BinTiers, combine_sibling_hists,
-                             hist_is_row_pass, level_histogram, node_sums,
-                             onehot_rows)
+                             hist_form, hist_is_row_pass, level_histogram,
+                             node_sums, onehot_rows, transposed_page)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 from ..telemetry.spans import count_in_round, wait_span
@@ -372,7 +372,7 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
            set_matrix, cat_mask, hist_prev, rho, node0, N: int, *,
            params: SplitParams, last_level: bool, axis_name: Optional[str],
            lossguide: bool, has_cat: bool, subtract: bool, quantised: bool,
-           tiers: Optional[BinTiers] = None):
+           tiers: Optional[BinTiers] = None, bins_t: Optional[tuple] = None):
     """One level, the only place it is written: histogram -> decide -> route
     over the ``N`` heap slots from ``node0``, a Python int (``level_step``: a
     program a depth) or a traced scalar (``level_step_padded``: one program
@@ -390,9 +390,12 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
     (multi-chip) the psum payload.  ``hist`` is None on the last level.
     ``tiers`` (ops/histogram.py ``bin_tiers``) shorten the one-hot inside
     ``level_histogram`` and nothing else: what comes back is the (N, F, B, C)
-    histogram in column order.
+    histogram in column order.  ``bins_t`` (``transposed_page``: the grower's
+    own copy) is what the one-pass kernel reads where ``level_histogram``
+    picks it; None, or under a mesh, is the XLA form.
     """
     B = cuts_pad.shape[1]
+    sharded = axis_name is not None
 
     def hist_of(alive_lvl):
         # quantised: gpair is the (R, C, 3) int8 limb array: integer builds
@@ -405,7 +408,8 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
                 # parent j of the previous level maps to offsets (2j, 2j+1)
                 left = level_histogram(bins, gpair, state.pos, node0,
                                        n_nodes=half, n_bin=B, stride=2,
-                                       quantised=quantised, tiers=tiers)
+                                       quantised=quantised, tiers=tiers,
+                                       bins_t=bins_t, sharded=sharded)
                 if axis_name is not None:
                     left = lax.psum(left, axis_name)
                 # a parent level handed over at this level's width has its
@@ -414,7 +418,8 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
             else:
                 hist = level_histogram(bins, gpair, state.pos, node0,
                                        n_nodes=N, n_bin=B, quantised=quantised,
-                                       tiers=tiers)
+                                       tiers=tiers, bins_t=bins_t,
+                                       sharded=sharded)
                 if axis_name is not None:
                     # the distributed cost (SURVEY §3.1)
                     hist = lax.psum(hist, axis_name)
@@ -462,6 +467,7 @@ def level_step(
     subtract: bool = False,
     quantised: bool = False,
     tiers: Optional[BinTiers] = None,
+    bins_t: Optional[tuple] = None,
 ):
     """Expand every alive node at ``depth`` (``_level``): a program a depth,
     ``node0`` and the width ``2**depth`` its constants."""
@@ -469,7 +475,8 @@ def level_step(
                   set_matrix, cat_mask, hist_prev, rho, (1 << depth) - 1,
                   1 << depth, params=params, last_level=last_level,
                   axis_name=axis_name, lossguide=lossguide, has_cat=has_cat,
-                  subtract=subtract, quantised=quantised, tiers=tiers)
+                  subtract=subtract, quantised=quantised, tiers=tiers,
+                  bins_t=bins_t)
 
 
 @functools.partial(
@@ -498,6 +505,7 @@ def level_step_padded(
     subtract: bool = True,
     quantised: bool = False,
     tiers: Optional[BinTiers] = None,
+    bins_t: Optional[tuple] = None,
 ):
     """``_level`` with the node dimension PADDED to a fixed ``width`` and
     a TRACED ``node0`` — ONE compiled program serves every interior depth
@@ -506,9 +514,9 @@ def level_step_padded(
 
     ``width`` >= 2**depth is ``level_width``'s to choose, and the reasons
     are there.  What the padding costs on the chip: a level builds
-    ``width // 2`` left children whatever its depth (ops/histogram.py:
-    one-hot matmul at HIGHEST, the one-hot built feature-major inside the
-    matmul's fusion), and that fusion is nearly all of a level's time.  On
+    ``width // 2`` left children whatever its depth (ops/histogram.py: the
+    one-pass kernel over ``bins_t`` at 32 slots, the one-hot matmul at
+    HIGHEST beyond), and that is nearly all of a level's time.  On
     the CPU the row-pass kernels add only where a row's node matches, and
     the padding costs the wider output block alone.
 
@@ -531,7 +539,7 @@ def level_step_padded(
                   jnp.asarray(node0, jnp.int32), width, params=params,
                   last_level=False, axis_name=axis_name, lossguide=lossguide,
                   has_cat=has_cat, subtract=subtract, quantised=quantised,
-                  tiers=tiers)
+                  tiers=tiers, bins_t=bins_t)
 
 
 @jax.jit
@@ -562,12 +570,13 @@ class GrownTree(NamedTuple):
 
 
 # The narrowest width a shared interior program is padded to: 32 slots = 16
-# built left children = 32 output columns of the histogram's matmul.  Up to
-# there the chip's cost of a level is flat (10.5M x 28: the matmul 0.154 s a
-# level for one built node, 0.168 s for sixteen; 0.165 and 0.173 s at 136
-# columns); beyond it the matmul is paid for: a whole level of a depth-8
-# tree takes 0.2006 s at 32 slots, 0.213 s at 64 and 0.3756 s at 128, 89% of
-# the three-pass bfloat16 peak (PERF.md §5, §7).
+# built left children = 32 output columns of the histogram's matmul, and 96
+# rows of the one-pass kernel's three-term operand: what fits its one MXU
+# tile (ops/histogram.py hist_form).  On the chip a whole level at 10.5M x 28
+# takes 0.0514 s at the root and 0.0770 s at 32 slots on the kernel (0.1769
+# and 0.2006 s in the XLA form, where the cost is flat up to 32 slots);
+# beyond, the XLA form's matmul is paid for: 0.213 s at 64 slots and 0.3756 s
+# at 128, 89% of the three-pass bfloat16 peak (PERF.md §5, §7).
 _WIDTH_FLOOR = 32
 
 
@@ -613,6 +622,9 @@ class HistTreeGrower:
     """Host driver looping jitted level steps (reference: GPUHistMaker::Update,
     src/tree/updater_gpu_hist.cu:703)."""
 
+    # whether the level programs run under a mesh (parallel/grower.py)
+    sharded = False
+
     def __init__(
         self,
         max_depth: int,
@@ -643,6 +655,28 @@ class HistTreeGrower:
             padded_levels = default_padded_levels(max_depth)
         self.padded_levels = padded_levels
         self.max_nodes = max_nodes_for_depth(max_depth)
+        # (page, tiers, transposed_page of them): the copy the one-pass
+        # kernel reads, kept while the same page comes back
+        self._page_t = None
+
+    def _transposed(self, bins, tiers: Optional[BinTiers]) -> tuple:
+        """The page transposed, a tier at a time (``transposed_page``): made
+        at a page's first tree and kept in the grower's own state, not in
+        the page (one more copy of it on the device: 588 MB at 10.5M x 28,
+        1.83 GB at 946,997 x 968)."""
+        kept = self._page_t
+        if kept is None or kept[0] is not bins or kept[1] is not tiers:
+            self._page_t = kept = (bins, tiers, transposed_page(bins, tiers))
+        return kept[2]
+
+    def _built(self, d: int, width: Optional[int]) -> int:
+        """Nodes whose histogram level ``d`` builds from the rows, dispatched
+        at ``width`` slots (None: at its own ``2**d``); none at the leaf
+        level."""
+        if d == self.max_depth:
+            return 0
+        slots = width or (1 << d)
+        return slots // 2 if self.subtract and d > 0 else slots
 
     def _init_state(self, gpair, valid, setmat, cuts_pad,
                     has_cat: bool) -> TreeState:
@@ -654,15 +688,19 @@ class HistTreeGrower:
 
     def _run_level(self, d: int, width: Optional[int], state, page, fm,
                    setmat, cm, hist_prev, rho, has_cat: bool,
-                   tiers: Optional[BinTiers] = None):
+                   tiers: Optional[BinTiers] = None,
+                   bins_t: Optional[tuple] = None):
         """Dispatch depth ``d``'s program: ``(state, hist)``.  With a
         ``width``, the shared padded interior program of that width with a
         traced node0: root, leaf finalize and one program a tier of
         ``level_width`` (one up to depth 6, two up to depth 8), however
-        deep the tree.  With None, a program a depth."""
+        deep the tree.  With None, a program a depth.  ``bins_t``: the
+        transposed page, for a level that the one-pass kernel builds."""
         md = self.max_depth
         common = dict(params=self.params, lossguide=self.lossguide,
                       has_cat=has_cat, quantised=self.quantised, tiers=tiers)
+        if bins_t is not None:  # None: the program as it always lowered
+            common["bins_t"] = bins_t
         if width is not None:
             return level_step_padded(
                 state, *page, fm, setmat, cm, hist_prev, (1 << d) - 1, rho,
@@ -673,12 +711,17 @@ class HistTreeGrower:
             **common)
 
     def grow(self, bins, gpair, valid, cuts_pad, n_bins, feature_masks=None,
-             cat_mask=None, tiers: Optional[BinTiers] = None) -> TreeState:
+             cat_mask=None, tiers: Optional[BinTiers] = None,
+             resident: bool = False) -> TreeState:
         """feature_masks: None, or callable (depth, n_nodes) -> (1|N, F) bool mask
         (the ColumnSampler hook: bytree/bylevel/bynode, src/common/random.h).
         cat_mask: optional (F,) bool marking categorical features.
         tiers: the page's ``bin_tiers`` (``EllpackPage.tiers``), for the
-        float32 one-hot on one chip; None is one tier of ``B`` bins."""
+        float32 one-hot on one chip; None is one tier of ``B`` bins.
+        resident: ``bins`` is the same page tree after tree (core.py: not
+        ``approx``, which bins anew every round), so a transposed copy of it
+        pays: the levels that ``hist_form`` gives the one-pass kernel read
+        it; without, every level keeps the XLA form."""
         F = bins.shape[1]
         with span("grow.setup"):  # the tree's state and masks, before a level
             ones = jnp.ones((1, F), dtype=bool)
@@ -695,6 +738,7 @@ class HistTreeGrower:
         page = (bins, gpair, cuts_pad, n_bins)
         tall = onehot_rows(tiers, cuts_pad.shape[1], F)
         hist = None
+        onepass = 0
         for d in range(md + 1):
             # the root and the leaf level have programs of their own; the
             # levels between are 2**d slots wide, or padded to the width of
@@ -706,11 +750,19 @@ class HistTreeGrower:
             # covers all three — the name keeps the reference phase vocabulary
             # greppable in traces (bestfirst.py's pass has a span of its own);
             # width = the slots the level was dispatched at, onehot_rows
-            # the height of its chunks' one-hot operand.  The level's inputs
+            # the height of its chunks' one-hot operand, hist_form how its
+            # histogram is built (ops/histogram.py hist_form; "none" at the
+            # leaf level, which builds none).  The level's inputs
             # are made inside it (eager programs of their own, a millisecond
             # a tree): its column mask and its parent's histogram
+            built = self._built(d, width)
+            form = "none" if not built else "xla" if not resident else \
+                hist_form(built, channels=gpair.shape[1],
+                          quantised=self.quantised, sharded=self.sharded)
+            onepass += form == "onepass"
             with span("grow.build_hist+eval_split", depth=d,
-                      width=width or (1 << d), onehot_rows=tall):
+                      width=width or (1 << d), onehot_rows=tall,
+                      hist_form=form):
                 fm = (ones if feature_masks is None
                       else feature_masks(d, 1 << d))
                 if width is not None:
@@ -724,7 +776,10 @@ class HistTreeGrower:
                             hist.dtype).at[:hist.shape[0]].set(hist)
                 state, hist = self._run_level(
                     d, width, state, page, fm, setmat, cm,
-                    None if d == md else hist, rho, has_cat, tiers)
+                    None if d == md else hist, rho, has_cat, tiers,
+                    self._transposed(bins, tiers) if form == "onepass"
+                    else None)
+        count_in_round(**{"hist.onepass_levels": onepass})
         return state
 
     @staticmethod
