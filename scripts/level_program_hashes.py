@@ -10,15 +10,22 @@ default  what HistTreeGrower.grow really dispatches, each program hashed at
          number formats, 8,192 rows, lowered for the CPU under the device's
          branches (XTB_HIST_IMPL=matmul, XTB_NO_NATIVE_SPLIT=1).
 --mesh   the same for ShardedHistTreeGrower on four forced host devices.
---v5e    root, shared interior (one a width tier) and leaf program of each
-         cell at its real shape, lowered for a described v5e (nothing
-         compiles, nothing runs): the text the chip's cache key is made of,
-         the levels that ``ops/histogram.py:hist_form`` gives the one-pass
-         kernel handed the transposed page, as the grower hands it.
+--v5e    root, shared interior (one a width) and leaf program of each cell
+         at its real shape, lowered for a described v5e (nothing compiles,
+         nothing runs): the text the chip's cache key is made of, the levels
+         that ``ops/histogram.py:hist_form`` gives the one-pass kernel
+         handed the transposed page, as the grower hands it, at the widths
+         the checkout's grower dispatches on a chip over a resident page.
 
 In every mode the best-first pass of the cell higgs-leafwise-255.train
 (tree/bestfirst.py ``level_step_bestfirst``) comes last, where the checkout
 has one.
+
+The one-pass kernel's Mosaic body would carry the file and line of every
+frame that traced it; they are lowered as unknown locations here, so that a
+line moved in a source file, or another checkout's path, does not read as a
+moved program (the chip's key does move with them: a kernel program
+compiles cold in seconds).
 """
 import argparse
 import hashlib
@@ -40,6 +47,11 @@ sys.path.insert(0, os.path.abspath(args.root))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax._src.interpreters import mlir  # noqa: E402
+from jax._src.lib.mlir import ir  # noqa: E402
+
+# no source locations in a Mosaic body (the module's docstring)
+mlir._traceback_to_location = lambda ctx, tb: ir.Location.unknown()
 
 from xgboost_tpu.ops.split import SplitParams  # noqa: E402
 from xgboost_tpu.tree import grow  # noqa: E402
@@ -100,8 +112,17 @@ def dispatched(F, depth, shared, quantised):
     bins, gpair, valid, cuts, nb = data(F)
     g._build(F, B)
     g._init_fn = Recorder(g._init_fn, "init", seen)
-    for w, fn in g._interior_fns.items():
-        g._interior_fns[w] = Recorder(fn, f"interior width={w}", seen)
+
+    class Recorded(dict):
+        """Interior programs, each hashed at its first call, whether
+        ``_build`` makes them all or the loop one at a time."""
+
+        def __setitem__(self, w, fn):
+            super().__setitem__(w, Recorder(fn, f"interior width={w}", seen))
+
+    interior, g._interior_fns = g._interior_fns, Recorded()
+    for w, fn in interior.items():
+        g._interior_fns[w] = fn
     for d in g._level_fns:
         g._level_fns[d] = Recorder(g._level_fns[d], f"level[{d}]", seen)
     bins, gpair, valid = shard_rows(mesh, bins, gpair, valid)
@@ -130,25 +151,32 @@ def for_v5e(F, depth, rows):
     common = dict(params=PARAMS, axis_name=None, lossguide=False,
                   has_cat=False, quantised=False)
 
-    def page_t(built):
-        """The transposed page, where the checkout's grower would hand it to
-        a level of ``built`` nodes (PR 37: the one-pass kernel; the backend
-        here is the CPU, so the rule is told that the programs are a
-        chip's)."""
-        from xgboost_tpu.ops import hist_pallas, histogram
+    from xgboost_tpu.ops import hist_pallas, histogram
 
-        if not hasattr(histogram, "hist_form"):
-            return {}
+    onepass = hasattr(histogram, "hist_form")  # PR 37: the one-pass kernel
+    if onepass:
+        # the backend here is the CPU: tell the rule the programs are a chip's
         histogram._on_tpu = lambda: True
         hist_pallas._resolve_interpret = bool  # None: compiled, as on a chip
+
+    def page_t(built):
+        """The transposed page, where the checkout's grower would hand it to
+        a level of ``built`` nodes."""
         return ({"bins_t": (shape((F, rows), jnp.int16),)}
-                if histogram.hist_form(built) == "onepass" else {})
+                if onepass and histogram.hist_form(built) == "onepass" else {})
+
+    grower = grow.HistTreeGrower(depth, PARAMS, padded_levels=True)
+    if hasattr(grower, "_width"):  # PR 40: the ladder follows the kernel
+        widths = {grower._width(d, True, 2, ((B, F),))
+                  for d in range(1, depth)}
+    else:
+        widths = {grow.level_width(d, depth) for d in range(1, depth)}
 
     show("root", grow.level_step.lower(
         *head, None, None, depth=0, last_level=False, subtract=False,
         **common, **page_t(1)))
     # node0 as HistTreeGrower.grow passes it: a Python int, weakly typed
-    for W in sorted({grow.level_width(d, depth) for d in range(1, depth)}):
+    for W in sorted(widths):
         show(f"shared interior width={W}", grow.level_step_padded.lower(
             *head, shape((W, F, B, 2), jnp.float32), 1, None, width=W,
             subtract=True, **common, **page_t(W // 2)))

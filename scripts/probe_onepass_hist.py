@@ -8,7 +8,10 @@ probe that PR 37's go/no-go rests on (PERF.md §5 "A level by its width",
 
 A page of ``--rows`` x ``--cols`` int16 bins under ``--bins`` bins, the root
 (one node) and the left children of a level of 16 built nodes (stride 2, a
-traced ``node0``, as ``level_step_padded`` asks):
+traced ``node0``, as ``level_step_padded`` asks).  ``--tiers 32:480,64:128,...``
+makes the page of bin-width tiers instead (``ops/histogram.py:bin_tiers``: a
+column of a ``w``-bin tier holds bins under ``w``; ``--cols`` is their sum), in
+tier order, built a tier at a time by both forms as a level builds it:
 
 xla     the float32 one-hot matmul at ``HIGHEST`` as it stands
         (``ops/histogram.py:_hist_chunk``), its 2,048-row chunks sliced from
@@ -20,8 +23,9 @@ jnp3    the same chunk with the exact three-term split written in ``jnp``:
         a bfloat16 one-hot, a ``(T, 3*2N)`` bfloat16 operand, one dot at
         default precision: whether XLA alone moves.
 kernel  ``ops/hist_pallas.py:onepass_histogram`` over the transposed page,
-        at the row tiles of ``--tiles``; ``+T`` with the page's transpose
-        inside the timed program, ``transpose`` the transpose alone.
+        at the row tiles of ``--tiles`` (0: ``choose_tiles``' plan, the
+        tile a round runs); ``+T`` with the page's transpose inside the
+        timed program, ``transpose`` the transpose alone.
 
 Times are host clock around a drained call, the least of ``--reps``; every
 form is held against ``xla`` (largest gap over the largest sum).
@@ -35,6 +39,7 @@ ap = argparse.ArgumentParser()
 ap.add_argument("--rows", type=int, default=10_500_000)
 ap.add_argument("--cols", type=int, default=28)
 ap.add_argument("--bins", type=int, default=256)
+ap.add_argument("--tiers", default="")
 ap.add_argument("--nodes", default="1,16")
 ap.add_argument("--tiles", default="512,1024,2048")
 ap.add_argument("--only", default="xla,jnp3,kernel,kernel+T,transpose")
@@ -57,6 +62,12 @@ from xgboost_tpu.ops import hist_pallas as HP  # noqa: E402
 from xgboost_tpu.ops import histogram as H  # noqa: E402
 
 R, F, B, T = args.rows, args.cols, args.bins, 2048
+WIDTHS = (tuple(tuple(int(v) for v in t.split(":"))
+                for t in args.tiers.split(",")) if args.tiers else ((B, F),))
+F = sum(n for _, n in WIDTHS)
+# the page's columns already stand in tier order
+TIERS = (H.BinTiers(WIDTHS, jnp.arange(F, dtype=jnp.int32))
+         if len(WIDTHS) > 1 else None)
 ONLY = args.only.split(",")
 INTERPRET = None if not args.describe else False
 
@@ -69,19 +80,22 @@ def page_loop(chunk_sum, bins, gpair, pos, acc):
     def body(i, acc):
         start = jnp.minimum(i * T, R - T)
         p = lax.dynamic_slice(pos, (start,), (T,))
-        return acc + chunk_sum(
+        return H._add(acc, chunk_sum(
             lax.dynamic_slice(bins, (start, 0), (T, F)),
             lax.dynamic_slice(gpair, (start, 0), (T, 2)),
-            jnp.where(start + lane >= i * T, p, -1))
+            jnp.where(start + lane >= i * T, p, -1)))
 
     # a traced trip count: a dynamic loop, which compiles in seconds
     return lax.fori_loop(0, jnp.int32(-(-R // T)) + pos[0] * 0, body, acc)
 
 
 def xla_form(bins, gpair, pos, node0, *, n_nodes, stride):
-    return page_loop(
-        lambda b, g, p: H._hist_chunk(b, g, p, node0, n_nodes, B, stride),
-        bins, gpair, pos, jnp.zeros((n_nodes, F, B, 2), jnp.float32))
+    acc = H._by_tier([jnp.zeros((n_nodes, n, w, 2), jnp.float32)
+                      for w, n in WIDTHS], TIERS)
+    return H._untier(page_loop(
+        lambda b, g, p: H._hist_chunk(b, g, p, node0, n_nodes, B, stride,
+                                      TIERS),
+        bins, gpair, pos, acc), TIERS, B)
 
 
 def jnp3_form(bins, gpair, pos, node0, *, n_nodes, stride):
@@ -108,25 +122,31 @@ def programs(n_nodes, stride):
     if args.scan:
         out["xla-scan"] = (lambda b, bt, g, p, n0: H.build_histogram_at.
                            __wrapped__(b, g, p, n0, n_bin=B, **kw))
-    if "jnp3" in ONLY:
+    if "jnp3" in ONLY and TIERS is None:
         out["jnp3"] = (lambda b, bt, g, p, n0: jnp3_form(b, g, p, n0, **kw))
     for tile in (int(t) for t in args.tiles.split(",")):
         def kernel(bt, g, p, n0, tile=tile):
-            return HP.onepass_histogram(bt, g, p, n0, n_bin=B, row_tile=tile,
-                                        interpret=INTERPRET, **kw)
+            # a call a tier, as ops/histogram.py:level_histogram makes them
+            return H._untier(H._by_tier([
+                HP.onepass_histogram(page, g, p, n0, n_bin=w, row_tile=tile,
+                                     interpret=INTERPRET, **kw)
+                for page, (w, _) in zip(bt, WIDTHS)], TIERS), TIERS, B)
+        name = f"T={tile or 'plan'}"
         if "kernel" in ONLY:
-            out[f"kernel T={tile}"] = (
+            out[f"kernel {name}"] = (
                 lambda b, bt, g, p, n0, k=kernel: k(bt, g, p, n0))
         if "kernel+T" in ONLY:
-            out[f"kernel+T T={tile}"] = (
-                lambda b, bt, g, p, n0, k=kernel: k(b.T, g, p, n0))
+            out[f"kernel+T {name}"] = (
+                lambda b, bt, g, p, n0, k=kernel: k(
+                    H.transposed_page(b, TIERS), g, p, n0))
     if "transpose" in ONLY:
-        out["transpose"] = lambda b, bt, g, p, n0: b.T
+        out["transpose"] = lambda b, bt, g, p, n0: H.transposed_page(b, TIERS)
     return {k: jax.jit(v) for k, v in out.items()}
 
 
 def main():
-    print(f"{R} x {F}, B {B}: one-hot {F * B} rows a chunk", flush=True)
+    print(f"{R} x {F}, B {B}, tiers {WIDTHS}: one-hot "
+          f"{H.onehot_rows(TIERS, B, F)} rows a chunk", flush=True)
     if args.describe:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -137,7 +157,8 @@ def main():
         def shape(s, d):
             return jax.ShapeDtypeStruct(s, d, sharding=chip)
 
-        operands = (shape((R, F), jnp.int16), shape((F, R), jnp.int16),
+        operands = (shape((R, F), jnp.int16),
+                    tuple(shape((n, R), jnp.int16) for _, n in WIDTHS),
                     shape((R, 2), jnp.float32), shape((R,), jnp.int32),
                     shape((), jnp.int32))
     else:
@@ -146,14 +167,19 @@ def main():
         if platform != "tpu" and not args.allow_cpu:
             sys.exit("no TPU: this probe times the chip (--allow-cpu rehearses)")
         ks = jax.random.split(jax.random.key(0), 3)
-        bins = jax.jit(lambda: jax.random.randint(
-            ks[0], (R, F), 0, B, jnp.int32).astype(jnp.int16))()
-        bins_t = jax.block_until_ready(jax.jit(lambda b: b.T)(bins))
+        bins = jax.jit(lambda: jnp.concatenate([jax.random.randint(
+            jax.random.fold_in(ks[0], i), (R, n), 0, w, jnp.int32).astype(
+                jnp.int16) for i, (w, n) in enumerate(WIDTHS)], axis=1))()
+        bins_t = jax.block_until_ready(
+            jax.jit(H.transposed_page)(bins, TIERS))
         gpair = jax.random.normal(ks[1], (R, 2), jnp.float32)
     for n_nodes in (int(v) for v in args.nodes.split(",")):
         # the root, or the left children of the level of 2*n_nodes slots
         stride = 1 if n_nodes == 1 else 2
         node0 = 0 if n_nodes == 1 else 2 * n_nodes - 1
+        print(f"nodes {n_nodes:>3} plan (row tile, feature group) a tier: "
+              + str([HP.choose_tiles(n, w, n_nodes, 2) for w, n in WIDTHS]),
+              flush=True)
         if not args.describe:
             pos = jax.random.randint(ks[2], (R,), node0,
                                      node0 + stride * n_nodes, jnp.int32)

@@ -111,14 +111,14 @@ def test_fused_hist_kernel_compiles_for_v5e(one_chip, form, bins_dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _level_args(sharding_rows, sharding_rep, depth=DEPTH):
+def _level_args(sharding_rows, sharding_rep, depth=DEPTH, n_bin=B):
     """Shapes of one level step's operands at ROWS x 28 x 256, depth 6
     unless told another."""
     from xgboost_tpu.tree.grow import init_tree_state, max_nodes_for_depth
 
     state = jax.eval_shape(
         lambda g, v: init_tree_state(
-            g, v, max_nodes=max_nodes_for_depth(depth), n_bin=B),
+            g, v, max_nodes=max_nodes_for_depth(depth), n_bin=n_bin),
         jax.ShapeDtypeStruct((ROWS, 2), jnp.float32),
         jax.ShapeDtypeStruct((ROWS,), bool))
     state = type(state)(*(
@@ -128,7 +128,7 @@ def _level_args(sharding_rows, sharding_rep, depth=DEPTH):
     return (state,
             _shape((ROWS, F), jnp.int16, sharding_rows),  # bins
             _shape((ROWS, 2), jnp.float32, sharding_rows),  # gpair
-            _shape((F, B), jnp.float32, sharding_rep),    # cuts_pad
+            _shape((F, n_bin), jnp.float32, sharding_rep),  # cuts_pad
             _shape((F,), jnp.int32, sharding_rep),        # n_bins
             _shape((1, F), bool, sharding_rep),           # feature_mask
             _shape((1, F), bool, sharding_rep),           # set_matrix
@@ -180,20 +180,24 @@ def test_padded_level_program_compiles_for_v5e(one_chip, device_paths, depth):
     assert "custom_call_target=\"xtb_" not in compiled.as_text()
 
 
-@pytest.mark.parametrize("program", ["root", "width32", "width128"])
+@pytest.mark.parametrize("program", ["root", "width32", "width64",
+                                     "width128", "width128-1024-bins"])
 def test_level_programs_hold_the_onepass_kernel_where_the_rule_says(
         one_chip, device_paths, program):
     """The level programs as ``HistTreeGrower.grow(resident=True)`` runs
     them on a chip, each handed the transposed page: ``level_step`` at depth
-    0 (6 operand rows) and ``level_step_padded`` at 32 slots (96) hold the
-    Mosaic kernel and no one-hot convolution; at 128 slots (384 rows: bound
-    by the multiply-add already) ``level_histogram`` keeps the XLA form
-    whatever it is handed.  The best-first pass (192 rows) is never handed a
-    transposed page: ``test_bestfirst_pass_compiles_for_v5e`` holds it to
-    its convolutions under the same fixture."""
+    0 (6 operand rows) and ``level_step_padded`` at 32, 64 and 128 slots
+    (96, 192 and 384 rows: depth 8's ladder) hold the Mosaic kernel and no
+    one-hot convolution.  At 1,024 bins the kernel's block of sums at 384
+    rows would outgrow VMEM, and the 128-slot level compiles in the XLA
+    form.  The best-first pass (192 rows) is never handed a transposed
+    page: ``test_bestfirst_pass_compiles_for_v5e`` holds it to its
+    convolutions under the same fixture, and a mesh keeps the XLA form
+    (``test_sharded_level_program_allreduces_on_four_chips``)."""
     from xgboost_tpu.tree.grow import level_step, level_step_padded
 
     page_t = (_shape((F, ROWS), jnp.int16, one_chip),)
+    n_bin = 1024 if program.endswith("-1024-bins") else B
     if program == "root":
         def step(page_t, *args):  # a fresh function: a fresh trace
             return level_step.__wrapped__(
@@ -201,18 +205,18 @@ def test_level_programs_hold_the_onepass_kernel_where_the_rule_says(
                 last_level=False, subtract=False, bins_t=page_t)
         args = _level_args(one_chip, one_chip)
     else:
-        W = int(program[5:])
+        W = int(program[5:].split("-")[0])
 
         def step(page_t, *args):
             return level_step_padded.__wrapped__(
                 *args, width=W, params=_split_params(), subtract=True,
                 bins_t=page_t)
-        args = _level_args(one_chip, one_chip, 8) + (
-            _shape((W, F, B, 2), jnp.float32, one_chip),
+        args = _level_args(one_chip, one_chip, 8, n_bin) + (
+            _shape((W, F, n_bin, 2), jnp.float32, one_chip),
             _shape((), jnp.int32, one_chip))
     text = jax.jit(step).lower(page_t, *args).compile().as_text()
-    assert ("tpu_custom_call" in text) == (program != "width128")
-    assert (" convolution(" in text) == (program == "width128")
+    assert ("tpu_custom_call" in text) == (n_bin == B)
+    assert (" convolution(" in text) == (n_bin != B)
     assert "custom_call_target=\"xtb_" not in text
 
 
@@ -283,7 +287,7 @@ def test_sharded_level_program_allreduces_on_four_chips(topo, device_paths,
     W = 1 << (DEPTH - 1)
     args = _level_args(rows, rep) + (
         _shape((W, F, B, 2), jnp.float32, rep), _shape((), jnp.int32, rep))
-    compiled = grower._interior_fns[W].lower(*args).compile()
+    compiled = grower._interior_fn(W).lower(*args).compile()
     assert "all-reduce" in compiled.as_text()
     assert "tpu_custom_call" not in compiled.as_text()  # a mesh: the XLA form
 

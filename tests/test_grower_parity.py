@@ -189,3 +189,73 @@ def test_level_width_holds_the_level_under_the_cap(max_depth):
         assert set(widths) <= {cap}
     if max_depth == 8:
         assert widths == [32] * 5 + [128] * 2
+
+
+# the page's (bins, columns): HIGGS's, and bosch's bin-width tiers
+_HIGGS, _BOSCH = ((256, 28),), ((32, 480), (64, 128), (128, 176), (256, 184))
+
+_LADDER = {
+    # name: (max_depth, on a chip?, the grower, resident page?, the page's
+    # tiers, the widths)
+    "kernel-depth8": (8, True, "one-chip", True, _HIGGS,
+                      [32] * 5 + [64, 128]),
+    "kernel-depth6": (6, True, "one-chip", True, _HIGGS, [32] * 5),
+    "kernel-depth7": (7, True, "one-chip", True, _HIGGS, [32] * 5 + [64]),
+    "kernel-depth9": (9, True, "one-chip", True, _HIGGS,
+                      [32] * 5 + [64, 128, 256]),
+    "kernel-depth10": (10, True, "one-chip", True, _HIGGS,
+                       [32] * 5 + [64, 128, 512, 512]),
+    "kernel-bosch-tiers": (8, True, "one-chip", True, _BOSCH,
+                           [32] * 5 + [64, 128]),
+    # the block of sums outgrows VMEM: 50 MB at 192 rows, 100 MB at 384
+    "kernel-1024-bins": (8, True, "one-chip", True, ((1024, 32),),
+                         [32] * 5 + [128, 128]),
+    "kernel-512-bins": (8, True, "one-chip", True, ((512, 32),),
+                        [32] * 5 + [64, 128]),
+    "mesh": (8, True, "mesh", True, _HIGGS, [32] * 5 + [128, 128]),
+    "int8-limbs": (8, True, "int8-limbs", True, _HIGGS,
+                   [32] * 5 + [128, 128]),
+    "not-resident": (8, True, "one-chip", False, _HIGGS,
+                     [32] * 5 + [128, 128]),
+    "cpu-backend": (8, False, "one-chip", True, _HIGGS,
+                    [32] * 5 + [128, 128]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LADDER))
+def test_level_width_ladder_follows_the_kernel(monkeypatch, case):
+    """The widths ``HistTreeGrower.grow`` dispatches the interior depths at:
+    past the 32-slot floor a width doubles where the level it holds is the
+    one-pass kernel's (one chip's matmul over a resident page, float32
+    sums: 64 and 128 at depth 8, then x4 past the kernel's 384 operand
+    rows, and where the kernel's block of sums outgrows VMEM: 1,024 bins at
+    192 rows), and steps x4 as ``level_width`` alone does everywhere else
+    (a mesh, the int8 limbs, a page that is not kept, the CPU backend)."""
+    import jax
+    from jax.sharding import Mesh
+
+    from xgboost_tpu.ops import histogram as H
+    from xgboost_tpu.parallel.grower import ShardedHistTreeGrower
+    from xgboost_tpu.parallel.mesh import DATA_AXIS
+    from xgboost_tpu.tree.grow import level_width
+
+    max_depth, on_tpu, grower, resident, page, want = _LADDER[case]
+    monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
+    monkeypatch.setattr(H, "_on_tpu", lambda: on_tpu)
+    params = SplitParams(eta=0.3, gamma=0.0, min_child_weight=1.0,
+                         lambda_=1.0, alpha=0.0, max_delta_step=0.0)
+    if grower == "mesh":
+        g = ShardedHistTreeGrower(
+            max_depth, params, Mesh(np.asarray(jax.devices()[:1]),
+                                    (DATA_AXIS,)))
+        g.padded_levels = True
+    else:
+        g = HistTreeGrower(max_depth, params, padded_levels=True,
+                           quantised=grower == "int8-limbs")
+    widths = [g._width(d, resident, 2, page) for d in range(1, max_depth)]
+    assert widths == want
+    assert g._width(0, resident, 2, page) is None
+    assert g._width(max_depth, resident, 2, page) is None
+    if want[-1] == 128 and want[-2] == 128:  # the x4 ladder, unchanged
+        assert widths == [level_width(d, max_depth)
+                          for d in range(1, max_depth)]

@@ -200,8 +200,10 @@ def test_pallas_bench_shape_tiles_interpret():
     from xgboost_tpu.ops.histogram import build_histogram
 
     B = 257
+    # 54 columns, 64 nodes: 37.7 MB of double-buffered sums leave room for
+    # the operand's six (384, T) arrays at 256 rows a tile
     for F, n_nodes, stride, tile in ((28, 16, 2, 2048), (28, 32, 1, 2048),
-                                     (54, 64, 2, 512)):
+                                     (54, 64, 2, 256)):
         T, FG = choose_tiles(F, B, n_nodes, 1)
         assert (T, FG) == (tile, min(F, 32)), (F, n_nodes, T, FG)
         rng = np.random.default_rng(F)
@@ -639,6 +641,10 @@ _ONEPASS = {
     "uint8": (28, 255, np.uint8, 16, 31, 2),
     "wide-page": (54, 256, np.int16, 4, 7, 2),
     "all-sentinel": (5, 64, np.int16, 2, 1, 1),
+    # a 64-slot level's 192 operand rows and a 128-slot level's 384: two
+    # and three 128-row tiles of the operand past one one-hot
+    "32-nodes": (28, 256, np.int16, 32, 63, 2),
+    "64-nodes": (28, 256, np.int16, 64, 127, 2),
 }
 
 
@@ -647,9 +653,10 @@ _ONEPASS = {
 def test_onepass_histogram_is_the_float32_sum(monkeypatch, case, node0_kind):
     """The one-pass kernel against a float64 sum of the same rows, within
     float32's rounding of a bin's magnitudes, and against the XLA form:
-    root, 16 built nodes at stride 2, ``node0`` a constant or a scalar the
-    kernel reads from SMEM, uint8 and int16 pages, the sentinel, two row
-    tiles and a ragged third, a feature group and a ragged second."""
+    root, 16, 32 and 64 built nodes at stride 2 (one, two and three 128-row
+    tiles of the operand), ``node0`` a constant or a scalar the kernel reads
+    from SMEM, uint8 and int16 pages, the sentinel, two row tiles and a
+    ragged third, a feature group and a ragged second."""
     from xgboost_tpu.ops.hist_pallas import onepass_histogram
     from xgboost_tpu.ops.histogram import _hist_accumulate
 
@@ -687,12 +694,15 @@ def test_onepass_histogram_is_the_float32_sum(monkeypatch, case, node0_kind):
     assert np.all(np.abs(got - xla) <= 8 * 2.0 ** -24 * mags + 1e-30)
 
 
-@pytest.mark.parametrize("signature", ["32+256", "all-four", "uint8"])
-def test_onepass_histogram_of_a_page_with_tiers(monkeypatch, signature):
+@pytest.mark.parametrize("signature,N", [
+    ("32+256", 3), ("all-four", 3), ("uint8", 3),
+    ("all-four", 32), ("all-four", 64)])
+def test_onepass_histogram_of_a_page_with_tiers(monkeypatch, signature, N):
     """Through ``level_histogram``, told that the programs are a chip's: a
     kernel call a tier over ``transposed_page``'s arrays, each at its
     tier's height, comes back as the (N, F, B, C) histogram in column order
-    that the XLA form gives; exact on gradients whose sums are exact."""
+    that the XLA form gives; exact on gradients whose sums are exact; three
+    built nodes, and the 32 and 64 of a 64- and a 128-slot level."""
     from xgboost_tpu.ops import histogram as H
 
     monkeypatch.setenv("XTB_HIST_IMPL", "matmul")
@@ -704,7 +714,7 @@ def test_onepass_histogram_of_a_page_with_tiers(monkeypatch, signature):
     n_bins[int(np.argmin(n_bins))] = 0  # a column with no value at all
     tiers = H.bin_tiers(n_bins, n_bin)
     assert tiers.widths == widths
-    N, node0, stride = 3, 7, 2
+    node0, stride = 2 * N + 1, 2
     bins, gpair, pos = _tier_case(n_bins, n_bin, dtype, 2 * 256 + 77, N,
                                   node0, stride, seed=3)
     want = H.level_histogram(bins, gpair, pos, jnp.int32(node0), n_nodes=N,
@@ -724,13 +734,26 @@ _RULE = {
     "root-6-rows": (1, {}, True, "matmul", "onepass"),
     "32-slots-96-rows": (16, {}, True, "matmul", "onepass"),
     "21-nodes-126-rows": (21, {}, True, "matmul", "onepass"),
-    "22-nodes-132-rows": (22, {}, True, "matmul", "xla"),
-    "bestfirst-192-rows": (32, {}, True, "matmul", "xla"),
-    "128-slots-384-rows": (64, {}, True, "matmul", "xla"),
+    "22-nodes-132-rows": (22, {}, True, "matmul", "onepass"),
+    "64-slots-192-rows": (32, {}, True, "matmul", "onepass"),
+    "128-slots-384-rows": (64, {}, True, "matmul", "onepass"),
+    "64-nodes-384-rows": (64, {}, True, "matmul", "onepass"),
+    "65-nodes-390-rows": (65, {}, True, "matmul", "xla"),
+    # the kernel's block of sums, double-buffered, at a feature group of 32
+    # columns of 1,024 bins: 25 MB at 96 rows, 50 at 192, 100 at 384
+    "1024-bins-16-nodes": (16, {"widths": ((1024, 32),)}, True, "matmul",
+                           "onepass"),
+    "1024-bins-32-nodes": (32, {"widths": ((1024, 32),)}, True, "matmul",
+                           "xla"),
+    "1024-bins-64-nodes": (64, {"widths": ((1024, 32),)}, True, "matmul",
+                           "xla"),
+    "bosch-tiers-64-nodes": (64, {"widths": ((32, 480), (64, 128),
+                                             (128, 176), (256, 184))},
+                             True, "matmul", "onepass"),
     "int8-limbs": (16, {"quantised": True}, True, "matmul", "xla"),
     "row-list": (16, {"listed": True}, True, "matmul", "xla"),
     "mesh": (16, {"sharded": True}, True, "matmul", "xla"),
-    "four-channels": (16, {"channels": 4}, True, "matmul", "xla"),
+    "four-channels": (33, {"channels": 4}, True, "matmul", "xla"),
     "cpu-matmul": (1, {}, False, "matmul", "xla"),
     "row-pass-scatter": (1, {}, True, "scatter", "xla"),
 }
@@ -739,17 +762,22 @@ _RULE = {
 @pytest.mark.parametrize("case", sorted(_RULE))
 def test_hist_form_rule(monkeypatch, case):
     """``hist_form``: the one-pass kernel where the gradient operand's
-    three terms fit one 128-wide tile, on a chip's one-hot matmul over the
-    whole page with float32 sums; the XLA form everywhere else.  And
-    ``level_histogram`` follows it: the kernel is traced exactly where the
-    rule says ``onepass`` and the transposed page was handed in."""
+    three terms fit three 128-row tiles (a 128-slot level's 64 built nodes)
+    and the kernel's working set fits VMEM at every tier of the page, on a
+    chip's one-hot matmul over the whole page with float32 sums; the XLA
+    form everywhere else (the best-first pass, 32 nodes, keeps it by its
+    list of rows).  And ``level_histogram`` follows it: the kernel is traced
+    exactly where the rule says ``onepass`` and the transposed page was
+    handed in."""
     from xgboost_tpu.ops import histogram as H
 
     n_nodes, extra, on_tpu, impl, form = _RULE[case]
     monkeypatch.setenv("XTB_HIST_IMPL", impl)
     monkeypatch.setattr(H, "_on_tpu", lambda: on_tpu)
     assert H.hist_form(n_nodes, **extra) == form
-    if set(extra) - {"sharded"}:
+    extra = dict(extra)
+    widths = extra.pop("widths", ((32, 4),))
+    if set(extra) - {"sharded"} or len(widths) > 1:
         return
     from xgboost_tpu.ops import hist_pallas
 
@@ -757,7 +785,7 @@ def test_hist_form_rule(monkeypatch, case):
     kernel = hist_pallas.onepass_histogram
     monkeypatch.setattr(hist_pallas, "onepass_histogram",
                         lambda *a, **kw: calls.append(kw) or kernel(*a, **kw))
-    R, F, B = 512, 4, 32
+    R, ((B, F),) = 512, widths
     args = (jnp.zeros((R, F), jnp.int16), jnp.zeros((R, 2), jnp.float32),
             jnp.zeros((R,), jnp.int32), jnp.int32(0))
     for handed, expect in ((True, form == "onepass"), (False, False)):
@@ -769,12 +797,15 @@ def test_hist_form_rule(monkeypatch, case):
         assert bool(calls) == expect
 
 
-def test_grower_hands_the_transposed_page_where_the_rule_says(monkeypatch):
+@pytest.mark.parametrize("max_depth", [3, 8])
+def test_grower_hands_the_transposed_page_where_the_rule_says(monkeypatch,
+                                                              max_depth):
     """``HistTreeGrower.grow(resident=True)`` told that its programs are a
-    chip's: the root and the 32-slot levels say ``hist_form=onepass`` on
+    chip's: the root and every interior level say ``hist_form=onepass`` on
     their span and read one kept copy of the page, the round counts them,
     and the tree is the XLA form's; without ``resident`` every level says
-    ``xla`` and no copy is made."""
+    ``xla`` and no copy is made.  At depth 8 the kernel's ladder dispatches
+    level 6 at 64 slots and level 7 at 128, the XLA form both at 128."""
     from xgboost_tpu.data.ellpack import build_ellpack
     from xgboost_tpu.data.quantile import sketch_dense
     from xgboost_tpu.ops import histogram as H
@@ -795,21 +826,27 @@ def test_grower_hands_the_transposed_page_where_the_rule_says(monkeypatch):
     valid = jnp.arange(ell.n_padded) < R
     params = SplitParams(eta=0.3, gamma=0.0, min_child_weight=1.0,
                          lambda_=1.0, alpha=0.0, max_delta_step=0.0)
-    trees, forms, growers = {}, {}, {}
+    trees, forms, widths, growers = {}, {}, {}, {}
     for name, on_tpu, resident in (("xla", True, False),
                                    ("onepass", True, True),
                                    ("cpu", False, True)):
         monkeypatch.setattr(H, "_on_tpu", lambda on_tpu=on_tpu: on_tpu)
         jax.clear_caches()
-        g = growers[name] = HistTreeGrower(3, params, padded_levels=True)
+        g = growers[name] = HistTreeGrower(max_depth, params,
+                                           padded_levels=True)
         for _ in range(2):  # the second tree reads the first one's copy
             trees[name] = HistTreeGrower.to_host(g.grow(
                 ell.bins, jnp.asarray(gp), valid, ell.cuts_pad, ell.n_bins,
                 resident=resident))
-        forms[name] = [r["hist_form"] for r in spans.recent(
-            "grow.build_hist+eval_split")[-4:]]
-    assert forms["onepass"] == ["onepass"] * 3 + ["none"]
-    assert forms["xla"] == forms["cpu"] == ["xla"] * 3 + ["none"]
+        levels = spans.recent("grow.build_hist+eval_split")[-max_depth - 1:]
+        forms[name] = [r["hist_form"] for r in levels]
+        widths[name] = [r["width"] for r in levels]
+    assert forms["onepass"] == ["onepass"] * max_depth + ["none"]
+    assert forms["xla"] == forms["cpu"] == ["xla"] * max_depth + ["none"]
+    if max_depth == 8:
+        assert widths["onepass"] == [1] + [32] * 5 + [64, 128, 256]
+        assert widths["xla"] == widths["cpu"] == [1] + [32] * 5 + [128] * 2 \
+            + [256]
     assert growers["xla"]._page_t is None and growers["cpu"]._page_t is None
     kept = growers["onepass"]._page_t
     assert kept[0] is ell.bins and kept[2][0].shape == ell.bins.shape[::-1]

@@ -55,9 +55,9 @@ def test_sharded_tree_identical_to_single(problem, eight_devices, monkeypatch,
     valid_s = jax.device_put(valid, row1d)
 
     multi = ShardedHistTreeGrower(max_depth, params, mesh)
-    multi._build(ell.bins.shape[1], ell.cuts_pad.shape[1])
-    assert len(multi._interior_fns) == (2 if max_depth == 7 else 1)
     s8 = multi.grow(bins_s, gp_s, valid_s, ell.cuts_pad, ell.n_bins)
+    assert len(multi._interior_fns) == (
+        (2 if max_depth == 7 else 1) if shared_width else 0)
 
     np.testing.assert_array_equal(np.asarray(s1.feat), np.asarray(s8.feat))
     np.testing.assert_array_equal(np.asarray(s1.sbin), np.asarray(s8.sbin))

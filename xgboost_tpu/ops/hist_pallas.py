@@ -7,8 +7,8 @@ one-hot operand out of HBM in the feature-major form written there, but pays
 2.05 ps an element of the one-hot whatever is multiplied into it: three
 bfloat16 passes for ``HIGHEST`` over an operand it builds through float32.
 This kernel builds the one-hot in VMEM by hand, in the matmul's own narrow
-type, so a level whose gradient operand fits one 128-wide MXU tile is one
-pass (PERF.md §5, §6 PR 37).  HBM sees only: the transposed page read once
+type, so a level is one pass for each 128-row tile of its gradient operand
+(PERF.md §5, §6 PR 37, PR 40).  HBM sees only: the transposed page read once
 (R*F*itemsize bytes), the gradient operand read once per feature group, the
 sums written once.
 
@@ -34,11 +34,11 @@ lane-dense and no value is ever sliced off the lane axis:
   feature group (index_map ignores the row index) and accumulates
       hist[:, f*B:(f+1)*B] += gm @ onehot(bins_t[f]).T   # (Cv*N, T) x (B, T)^T
   where gm[c*N + n] = vals_t[c] masked to the rows sitting in node n.
-The one-hot is the operand the MXU holds and the ``Cv*N <= 128`` operand rows
-stream past it: on a v5e at 10.5M x 28 x 256 that reads 0.051 s at the root
-(6 rows) and 0.076 s at sixteen built nodes (96), where the other way round
-(the one-hot streamed past the operand's tile) reads 0.100 s whatever the
-operand holds, and XLA's three passes 0.163 and 0.186 s (PERF.md §6, PR 37).
+The one-hot is the operand the MXU holds and the ``Cv*N`` operand rows stream
+past it: on a v5e at 10.5M x 28 x 256 that reads 0.051 s at the root (6 rows),
+0.076 s at sixteen built nodes (96), 0.149 and 0.297 s at 192 and 384, where
+the one-hot streamed past the operand reads 0.100 s a 128-row tile of it, and
+XLA's three passes 0.163, 0.186, 0.195 and 0.360 s (PERF.md §6, PR 37).
 A column costs what its bins are tall: the columns of a tier under 128 bins
 share a dot, eight of 32 bins or four of 64 side by side on its 256 lanes
 (``_DOT_LANES``), so a narrow tier is never padded to 128 lanes a column.  The
@@ -110,22 +110,46 @@ def choose_tiles(n_features: int, n_bin: int, n_nodes: int,
         (out_ch = 6 either way: three terms of (g,h), or (g,h) x 3 limbs)
       - double-buffered inputs: 2 * T * (FG*itemsize + 16*2 + 8*4)
       - scratch: widened bins FG*T*4, the one-hot of a dot and its int32
-        compare 6 * _DOT_LANES * T, masked operand and its iota temporaries
-        3 * roundup(out_ch*N, 8) * T * 4
-    Always returns something; the compiler refuses what does not fit.
+        compare 6 * _DOT_LANES * T, the masked operand's iota, channel, node,
+        value, compare and result: 6 * roundup(out_ch*N, 8) * T * 4
+    Always returns something; the compiler refuses what does not fit
+    (``tiles_fit`` says whether anything does).
     """
     lanes = _bin_lanes(n_bin)
+    fg = _feat_group(n_features, lanes)
+    m = _round_up(out_ch * n_nodes, 8)
+    for t in _ROW_TILES:
+        if _working_set(t, fg, lanes, m, bin_itemsize) <= vmem_budget:
+            return t, fg
+    return _ROW_TILES[-1], fg
+
+
+def _feat_group(n_features: int, lanes: int) -> int:
     fg = max(n_features, 1)
     if fg > _FEAT_GROUP or fg % max(1, _DOT_LANES // lanes):
         fg = _FEAT_GROUP
-    m = _round_up(out_ch * n_nodes, 8)
+    return fg
+
+
+def _working_set(t: int, fg: int, lanes: int, m: int,
+                 bin_itemsize: int) -> int:
+    """VMEM bytes of the kernel at row tile ``t`` (choose_tiles' terms)."""
     out_b = 2 * m * fg * lanes * 4
-    for t in _ROW_TILES:
-        in_b = 2 * t * (fg * bin_itemsize + 64)
-        scratch = fg * t * 4 + 6 * max(_DOT_LANES, lanes) * t + 3 * m * t * 4
-        if out_b + in_b + scratch <= vmem_budget:
-            return t, fg
-    return _ROW_TILES[-1], fg
+    in_b = 2 * t * (fg * bin_itemsize + 64)
+    scratch = fg * t * 4 + 6 * max(_DOT_LANES, lanes) * t + 6 * m * t * 4
+    return out_b + in_b + scratch
+
+
+def tiles_fit(n_features: int, n_bin: int, n_nodes: int,
+              out_ch: int = 6) -> bool:
+    """Whether the smallest row tile's working set fits the VMEM budget, for
+    bins of any integer type.  The double-buffered block of sums does not
+    shrink with the row tile: at 384 operand rows a feature group of 32
+    columns of 1,024 bins is 100 MB of it, and such a level keeps the XLA
+    form (``ops/histogram.py:hist_form``)."""
+    lanes = _bin_lanes(n_bin)
+    return _working_set(_ROW_TILES[-1], _feat_group(n_features, lanes), lanes,
+                        _round_up(out_ch * n_nodes, 8), 4) <= _VMEM_BUDGET
 
 
 def _resolve_interpret(interpret):
@@ -279,9 +303,9 @@ def onepass_histogram(bins_t, gpair, pos, node0, *, n_nodes: int, n_bin: int,
 
     bins_t (F, R) int — the page transposed (``ops/histogram.py
     transposed_page``), sentinel >= n_bin for missing; gpair (R, C) f32; pos
-    (R,) int32; ``node0`` an int or a traced scalar.  ``3 * C * n_nodes``
-    should not pass 128 (``hist_form``): beyond one tile of the operand the
-    XLA form's three passes cost the same.  ``row_tile``/``feat_group`` of 0
+    (R,) int32; ``node0`` an int or a traced scalar.  Every 128 rows of the
+    ``3*C*n_nodes`` operand is one more pass over the one-hot: ``hist_form``
+    stops at 384, a 128-slot level.  ``row_tile``/``feat_group`` of 0
     select the VMEM-budget plan (choose_tiles)."""
     C = gpair.shape[1]
     out = _fused_hist(bins_t, jnp.concatenate(split3(gpair.T), axis=0), pos,

@@ -65,20 +65,20 @@ rows (the best-first pass alone hands one), and the one-hot
 matmul, the XLA scatter or the native row-pass kernel by ``_host_impl``: on
 the CPU backend neither matmul runs by default.
 
-**One pass where the operand fits a tile.**  Up to 64 output columns the XLA
-form costs 2.05 ps an element of the one-hot whatever is multiplied into it
-(three bfloat16 passes for ``HIGHEST`` over an operand built through
-float32).  Where ``hist_form`` says ``onepass`` (a TPU's matmul, float32
-sums, the whole page on one chip, ``3 * C * n_nodes <= 128``: the root's 6
-operand rows, a 32-slot level's 96) and the caller hands the transposed page
+**One pass a tile of the operand.**  The XLA form costs 2.05 ps an element
+of the one-hot up to 64 output columns (three bfloat16 passes for ``HIGHEST``
+over an operand built through float32) and runs at 89% of that peak at 128.
+Where ``hist_form`` says ``onepass`` (a TPU's matmul, float32 sums, the
+whole page on one chip, ``3 * C * n_nodes <= 384``: from the root's 6 operand
+rows to a 128-slot level's 384) and the caller hands the transposed page
 (``transposed_page``: the depth-wise grower keeps one), the level is the
 fused kernel of ops/hist_pallas.py, a call a tier: the gradient pair split
-into three exact bfloat16 terms, a bfloat16 one-hot built in VMEM, one MXU
-pass, the same float32 sums (PERF.md §6, PR 37: a level 0.2006 -> 0.077 s at
-10.5M x 28 x 256).  Wider levels run at 89% of the three-pass peak already
-and keep the XLA form, as does everything that is not handed the transposed
-page.  The kernel's int8-limb form is compiled and pinned by its tests and
-has no caller yet (ROADMAP D1).
+into three exact bfloat16 terms, a bfloat16 one-hot built in VMEM and held
+in the MXU while the operand streams past it a 128-row tile at a time, the
+same float32 sums (PERF.md §6, PR 37 and PR 40).  The grower's ladder of
+widths follows the rule (tree/grow.py ``level_width``); whatever is not
+handed the transposed page keeps the XLA form.  The kernel's int8-limb form
+is compiled and pinned by its tests and has no caller yet (ROADMAP D1).
 
 Determinism: float32 accumulation in a fixed sequential chunk order — within
 one topology, the role played by fixed-point gradient quantisation in the
@@ -562,9 +562,9 @@ def build_histogram_listed(bins, gpair, pos, node0, rows: RowList, *,
     return _untier(lax.fori_loop(0, in_list, from_list, acc), tiers, n_bin)
 
 
-# The widest gradient operand the one-pass kernel takes: one 128-wide MXU
-# tile holds the three bfloat16 terms of every channel of every built node.
-_ONEPASS_ROWS = 128
+# The widest gradient operand the one-pass kernel takes: three bfloat16 terms
+# of two channels of 64 built nodes, a 128-slot level (768 never measured).
+_ONEPASS_ROWS = 384
 
 
 def _on_tpu() -> bool:
@@ -574,21 +574,33 @@ def _on_tpu() -> bool:
 
 
 def hist_form(n_nodes: int, *, channels: int = 2, quantised: bool = False,
-              listed: bool = False, sharded: bool = False) -> str:
+              listed: bool = False, sharded: bool = False,
+              widths: tuple = ()) -> str:
     """``"onepass"`` or ``"xla"``: how a level that builds ``n_nodes`` nodes
     gets its histogram, from what the code sees and nothing else.  The fused
     one-pass kernel (ops/hist_pallas.py) where the chip's one-hot matmul
     would run, the sums are float32, the page is scanned whole on one chip,
-    and the gradient operand fits one MXU tile: ``3 * channels * n_nodes <=
-    128``, the root's 6 rows and a 32-slot level's 96.  Wider levels are
-    bound by the multiply-add, not by the one-hot (PERF.md §5), and keep the
-    XLA form, as do the int8-limb sums, a list of rows, a mesh, processes
-    and the CPU backend's row-pass kernels."""
+    the operand is at most three 128-row tiles (``3 * channels * n_nodes
+    <= 384``: the root's 6 rows to a 128-slot level's 384; the grower's
+    ladder of widths follows it), and the kernel's working set fits VMEM
+    for each of ``widths``, the ((bins, columns), ...) of its calls
+    (``tier_widths``; 384 rows of 1,024 bins do not).  Wider levels, the
+    int8-limb sums, a list of rows, a mesh, processes and the CPU's
+    row-pass kernels keep the XLA form; each further tile is one more pass
+    over the one-hot (PERF.md §6)."""
     fits = 3 * channels * n_nodes <= _ONEPASS_ROWS
     if (fits and _on_tpu() and _host_impl() == "matmul" and not quantised
-            and not listed and not sharded):
+            and not listed and not sharded
+            and _kernel_fits(n_nodes, channels, widths)):
         return "onepass"
     return "xla"
+
+
+def _kernel_fits(n_nodes: int, channels: int, widths: tuple) -> bool:
+    from .hist_pallas import tiles_fit
+
+    return all(tiles_fit(n, w, n_nodes, out_ch=3 * channels)
+               for w, n in widths)
 
 
 @jax.jit
@@ -632,12 +644,13 @@ def level_histogram(bins, gpair, pos, node0, *, n_nodes: int, n_bin: int,
     rule says, text and cache key."""
     if tiers is not None and len(tiers.widths) == 1:
         tiers = None
+    widths = tier_widths(tiers, n_bin, bins.shape[1])
     if bins_t is not None and hist_form(
             n_nodes, channels=gpair.shape[1], quantised=quantised,
-            listed=rows is not None, sharded=sharded) == "onepass":
+            listed=rows is not None, sharded=sharded,
+            widths=widths) == "onepass":
         from .hist_pallas import onepass_histogram
 
-        widths = tier_widths(tiers, n_bin, bins.shape[1])
         assert [t.shape[0] for t in bins_t] == [n for _, n in widths]
         return _untier(_by_tier([
             onepass_histogram(page, gpair, pos, node0, n_nodes=n_nodes,

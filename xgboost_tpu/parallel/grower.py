@@ -18,8 +18,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.split import SplitParams
 from ..tree.grow import (HistTreeGrower, TreeState, init_tree_state,
-                         level_step, level_step_padded, level_width,
-                         make_set_matrix, max_nodes_for_depth)
+                         level_step, level_step_padded, make_set_matrix,
+                         max_nodes_for_depth)
 from .mesh import DATA_AXIS
 
 
@@ -96,10 +96,10 @@ class ShardedHistTreeGrower(HistTreeGrower):
                 out_specs=(sspec, P())))
 
         md = self.max_depth
-        # a shared interior program a width tier, as the loop asks for them
-        self._interior_fns = {
-            w: program(level_step_padded, 2, width=w, subtract=True)
-            for w in sorted({level_width(d, md) for d in range(1, md)})}
+        self._program = program
+        # a shared interior program a width, made when the loop first asks
+        # for it (``_interior_fn``): the inherited ``_width`` decides them
+        self._interior_fns = {}
         self._level_fns = {
             d: program(level_step, 1, depth=d, last_level=(d == md),
                        subtract=(0 < d < md))
@@ -117,11 +117,19 @@ class ShardedHistTreeGrower(HistTreeGrower):
         assert tiers is None and bins_t is None
         rho_args = () if rho is None else (rho,)
         if width is not None:
-            return self._interior_fns[width](
+            return self._interior_fn(width)(
                 state, *page, fm, setmat, cm, hist_prev,
                 jnp.int32((1 << d) - 1), *rho_args)
         return self._level_fns[d](state, *page, fm, setmat, cm, hist_prev,
                                   *rho_args)
+
+    def _interior_fn(self, width: int):
+        """The shared interior program of ``width`` slots, made at its first
+        call (``_build`` must have run)."""
+        if width not in self._interior_fns:
+            self._interior_fns[width] = self._program(
+                level_step_padded, 2, width=width, subtract=True)
+        return self._interior_fns[width]
 
 
 class ShardedMultiTargetGrower:

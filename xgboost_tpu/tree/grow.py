@@ -28,7 +28,7 @@ carry an axis of their own through every line and stay apart.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +36,8 @@ from jax import lax
 
 from ..ops.histogram import (BinTiers, combine_sibling_hists,
                              hist_form, hist_is_row_pass, level_histogram,
-                             node_sums, onehot_rows, transposed_page)
+                             node_sums, onehot_rows, tier_widths,
+                             transposed_page)
 from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
 from ..telemetry import span
 from ..telemetry.spans import count_in_round, wait_span
@@ -515,8 +516,8 @@ def level_step_padded(
     ``width`` >= 2**depth is ``level_width``'s to choose, and the reasons
     are there.  What the padding costs on the chip: a level builds
     ``width // 2`` left children whatever its depth (ops/histogram.py: the
-    one-pass kernel over ``bins_t`` at 32 slots, the one-hot matmul at
-    HIGHEST beyond), and that is nearly all of a level's time.  On
+    one-pass kernel over ``bins_t`` where ``hist_form`` says so, the
+    one-hot matmul at HIGHEST elsewhere), nearly all of a level's time.  On
     the CPU the row-pass kernels add only where a row's node matches, and
     the padding costs the wider output block alone.
 
@@ -575,23 +576,31 @@ class GrownTree(NamedTuple):
 # tile (ops/histogram.py hist_form).  On the chip a whole level at 10.5M x 28
 # takes 0.0514 s at the root and 0.0770 s at 32 slots on the kernel (0.1769
 # and 0.2006 s in the XLA form, where the cost is flat up to 32 slots);
-# beyond, the XLA form's matmul is paid for: 0.213 s at 64 slots and 0.3756 s
-# at 128, 89% of the three-pass bfloat16 peak (PERF.md §5, §7).
+# beyond, a level costs what its operand is tall: each further 128-row tile
+# is one more pass on the kernel, and the XLA form reads 0.213 s at 64 slots
+# and 0.3756 s at 128, 89% of the three-pass bfloat16 peak (PERF.md §5, §7).
 _WIDTH_FLOOR = 32
 
 
-def level_width(d: int, max_depth: int) -> int:
+def level_width(d: int, max_depth: int,
+                onepass: Optional[Callable[[int], bool]] = None) -> int:
     """Slots the shared program of interior depth ``d`` is padded to; the
-    only place that is decided.  The smallest of 32, 128, 512, ... that holds
+    only place that is decided.  The smallest width of the ladder that holds
     the level's ``2**d`` nodes, and never more than the widest interior
-    level, ``2**(max_depth-1)``: up to depth 6 one width, as it always was.
+    level, ``2**(max_depth-1)``: up to depth 6 one width, 32, as it always
+    was.  ``onepass(width)``: whether a level dispatched at ``width`` slots
+    is the one-pass kernel's (``HistTreeGrower._width`` asks ``hist_form``).
 
-    Steps of x4 and not x2: every tier is one more program with a scan over
-    all the rows in it, and at 10.5M rows such a program compiles cold for
-    about five minutes (ROADMAP S12)."""
+    Past the floor a width doubles where the level it holds is the kernel's
+    (32, 64, 128 at depth 8 on one chip): such a program has no scan and
+    compiles in seconds, and a level costs what its operand is tall, so
+    padding 64 nodes to 128 slots would pay twice for them.  Elsewhere it
+    steps x4 (32, 128, 512, ...): every width is one more program with a
+    scan over all the rows, and at 10.5M rows such a program compiles cold
+    for about five minutes (ROADMAP S12)."""
     width = _WIDTH_FLOOR
     while width < (1 << d):
-        width *= 4
+        width *= 2 if onepass is not None and onepass(2 * width) else 4
     return min(1 << (max_depth - 1), width)
 
 
@@ -678,6 +687,30 @@ class HistTreeGrower:
         slots = width or (1 << d)
         return slots // 2 if self.subtract and d > 0 else slots
 
+    def _form(self, built: int, resident: bool, channels: int,
+              widths: tuple) -> str:
+        """How a level that builds ``built`` nodes gets its histogram:
+        ``hist_form``'s answer for a ``resident`` page of ``widths``
+        (``tier_widths``), ``xla`` for any other page, ``none`` where it
+        builds none."""
+        if not built:
+            return "none"
+        if not resident:
+            return "xla"
+        return hist_form(built, channels=channels, quantised=self.quantised,
+                         sharded=self.sharded, widths=widths)
+
+    def _width(self, d: int, resident: bool, channels: int,
+               widths: tuple) -> Optional[int]:
+        """The width depth ``d`` is dispatched at: ``level_width``'s, with
+        the ladder this grower's levels would take on the kernel, for the
+        interior depths of the shared programs; None for a program of its
+        own."""
+        if not (self.padded_levels and 0 < d < self.max_depth):
+            return None
+        return level_width(d, self.max_depth, lambda w: self._form(
+            self._built(d, w), resident, channels, widths) == "onepass")
+
     def _init_state(self, gpair, valid, setmat, cuts_pad,
                     has_cat: bool) -> TreeState:
         return init_tree_state(
@@ -692,10 +725,11 @@ class HistTreeGrower:
                    bins_t: Optional[tuple] = None):
         """Dispatch depth ``d``'s program: ``(state, hist)``.  With a
         ``width``, the shared padded interior program of that width with a
-        traced node0: root, leaf finalize and one program a tier of
-        ``level_width`` (one up to depth 6, two up to depth 8), however
-        deep the tree.  With None, a program a depth.  ``bins_t``: the
-        transposed page, for a level that the one-pass kernel builds."""
+        traced node0: root, leaf finalize and one program a width of
+        ``level_width`` (one up to depth 6; at depth 8 three on one chip's
+        kernel, two elsewhere), however deep the tree.  With None, a
+        program a depth.  ``bins_t``: the transposed page, for a level that
+        the one-pass kernel builds."""
         md = self.max_depth
         common = dict(params=self.params, lossguide=self.lossguide,
                       has_cat=has_cat, quantised=self.quantised, tiers=tiers)
@@ -737,14 +771,14 @@ class HistTreeGrower:
         md = self.max_depth
         page = (bins, gpair, cuts_pad, n_bins)
         tall = onehot_rows(tiers, cuts_pad.shape[1], F)
+        widths = tier_widths(tiers, cuts_pad.shape[1], F)
         hist = None
         onepass = 0
         for d in range(md + 1):
             # the root and the leaf level have programs of their own; the
             # levels between are 2**d slots wide, or padded to the width of
             # their tier
-            width = (level_width(d, md)
-                     if self.padded_levels and 0 < d < md else None)
+            width = self._width(d, resident, gpair.shape[1], widths)
             # one span per level: the compiled program fuses build_hist +
             # eval_split + the position rewrite, so the bracket necessarily
             # covers all three — the name keeps the reference phase vocabulary
@@ -755,10 +789,8 @@ class HistTreeGrower:
             # leaf level, which builds none).  The level's inputs
             # are made inside it (eager programs of their own, a millisecond
             # a tree): its column mask and its parent's histogram
-            built = self._built(d, width)
-            form = "none" if not built else "xla" if not resident else \
-                hist_form(built, channels=gpair.shape[1],
-                          quantised=self.quantised, sharded=self.sharded)
+            form = self._form(self._built(d, width), resident,
+                              gpair.shape[1], widths)
             onepass += form == "onepass"
             with span("grow.build_hist+eval_split", depth=d,
                       width=width or (1 << d), onehot_rows=tall,
