@@ -29,6 +29,7 @@ compiles cold in seconds).
 """
 import argparse
 import hashlib
+import inspect
 import os
 import sys
 
@@ -60,6 +61,15 @@ assert os.path.abspath(grow.__file__).startswith(os.path.abspath(args.root))
 B = 256
 PARAMS = SplitParams(eta=0.1, gamma=0.0, min_child_weight=1.0, lambda_=1.0,
                      alpha=0.0, max_delta_step=0.0)
+
+
+def cat_mask(program, arg) -> tuple:
+    """``(arg,)`` where ``program`` still takes the traced categorical mask
+    between the set matrix and the rest (a checkout from before the
+    categorical columns became static), else ``()``.  It can go once no
+    checkout that old is hashed."""
+    fn = getattr(program, "__wrapped__", program)
+    return (arg,) if "cat_mask" in inspect.signature(fn).parameters else ()
 
 
 def show(label, lowered):
@@ -147,7 +157,8 @@ def for_v5e(F, depth, rows):
     head = (type(state)(*(shape(s.shape, s.dtype) for s in state)),
             shape((rows, F), jnp.int16), shape((rows, 2), jnp.float32),
             shape((F, B), jnp.float32), shape((F,), jnp.int32),
-            shape((1, F), bool), shape((1, F), bool), shape((F,), bool))
+            shape((1, F), bool), shape((1, F), bool)) + cat_mask(
+                grow.level_step, shape((F,), bool))
     common = dict(params=PARAMS, axis_name=None, lossguide=False,
                   has_cat=False, quantised=False)
 
@@ -208,7 +219,9 @@ def bestfirst_pass(F, rows, leaves, sharding=None):
         type(state)(*(shape(x.shape, x.dtype) for x in state)),
         shape((rows, F), jnp.int16), shape((rows, 2), jnp.float32),
         shape((F,), jnp.int32), shape((1, F), bool), shape((1, 2, F), bool),
-        shape((1, F), bool), shape((F,), bool), pairs=g.pairs,
+        shape((1, F), bool),
+        *cat_mask(bestfirst.level_step_bestfirst, shape((F,), bool)),
+        pairs=g.pairs,
         max_leaves=leaves, max_depth=0, gamma_eps=1e-6, params=params,
         has_cat=False, monotone=False,
         # one chip's matmul scans a list of rows where they are few (PR 33)

@@ -111,7 +111,7 @@ def test_fused_hist_kernel_compiles_for_v5e(one_chip, form, bins_dtype,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _level_args(sharding_rows, sharding_rep, depth=DEPTH, n_bin=B):
+def _level_args(sharding_rows, sharding_rep, depth=DEPTH, n_bin=B, F=F):
     """Shapes of one level step's operands at ROWS x 28 x 256, depth 6
     unless told another."""
     from xgboost_tpu.tree.grow import init_tree_state, max_nodes_for_depth
@@ -131,8 +131,7 @@ def _level_args(sharding_rows, sharding_rep, depth=DEPTH, n_bin=B):
             _shape((F, n_bin), jnp.float32, sharding_rep),  # cuts_pad
             _shape((F,), jnp.int32, sharding_rep),        # n_bins
             _shape((1, F), bool, sharding_rep),           # feature_mask
-            _shape((1, F), bool, sharding_rep),           # set_matrix
-            _shape((F,), bool, sharding_rep))             # cat_mask
+            _shape((1, F), bool, sharding_rep))           # set_matrix
 
 
 def _split_params():
@@ -220,6 +219,45 @@ def test_level_programs_hold_the_onepass_kernel_where_the_rule_says(
     assert "custom_call_target=\"xtb_" not in text
 
 
+@pytest.mark.parametrize("program", ["root", "width32"])
+def test_categorical_level_programs_compile_for_v5e(one_chip, device_paths,
+                                                    program):
+    """The airline cell's level programs: 8 columns, six of them categorical
+    with up to 300 categories (past max_bin, so ``B`` is 300 and the page
+    int16), the one-pass kernel over the transposed page, the categorical
+    scan and the membership route.  No gather or scatter may have a
+    row-sized output: the route brings each row its node's set by selects
+    (``_membership_dense``), not by a gather of the (N, B) sets."""
+    import re
+
+    from xgboost_tpu.tree.grow import level_step, level_step_padded
+
+    F_CAT, B_CAT, cats = 8, 300, (0, 1, 2, 4, 5, 6)
+    page_t = (_shape((F_CAT, ROWS), jnp.int16, one_chip),)
+    if program == "root":
+        def step(page_t, *args):  # a fresh function: a fresh trace
+            return level_step.__wrapped__(
+                *args, None, None, depth=0, params=_split_params(),
+                last_level=False, subtract=False, has_cat=cats,
+                bins_t=page_t)
+        args = _level_args(one_chip, one_chip, n_bin=B_CAT, F=F_CAT)
+    else:
+        def step(page_t, *args):
+            return level_step_padded.__wrapped__(
+                *args, width=32, params=_split_params(), subtract=True,
+                has_cat=cats, bins_t=page_t)
+        args = _level_args(one_chip, one_chip, n_bin=B_CAT, F=F_CAT) + (
+            _shape((32, F_CAT, B_CAT, 2), jnp.float32, one_chip),
+            _shape((), jnp.int32, one_chip))
+    compiled = jax.jit(step).lower(page_t, *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "custom_call_target=\"xtb_" not in text
+    moved = re.findall(r"= \w+\[(\d+)[\],][^\n]* (?:gather|scatter)\(", text)
+    assert not moved or max(int(n) for n in moved) < ROWS, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
+
+
 def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
     """``level_step_bestfirst`` at 28 columns, 256 bins and the cell's budget
     of 255 leaves: 32 pairs a pass, the one-hot matmul for the 32 built
@@ -255,8 +293,8 @@ def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
         state, _shape((ROWS, F), jnp.int16, one_chip),
         _shape((ROWS, 2), jnp.float32, one_chip),
         _shape((F,), jnp.int32, one_chip), _shape((1, F), bool, one_chip),
-        _shape((1, 2, F), bool, one_chip), _shape((1, F), bool, one_chip),
-        _shape((F,), bool, one_chip)).compile()
+        _shape((1, 2, F), bool, one_chip),
+        _shape((1, F), bool, one_chip)).compile()
     text = compiled.as_text()
     assert "custom_call_target=\"xtb_" not in text
     assert "tpu_custom_call" not in text  # 32 pairs: 192 operand rows
@@ -466,7 +504,7 @@ def test_one_tier_lowers_to_the_program_without_tiers(one_chip, device_paths,
                 _shape((F,), jnp.int32, one_chip),
                 _shape((1, F), bool, one_chip),
                 _shape((1, 2, F), bool, one_chip),
-                _shape((1, F), bool, one_chip), _shape((F,), bool, one_chip))
+                _shape((1, F), bool, one_chip))
         static = dict(pairs=grower.pairs, max_leaves=255, max_depth=0,
                       gamma_eps=1e-6, params=params, has_cat=False,
                       monotone=False,

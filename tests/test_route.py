@@ -16,7 +16,7 @@ from xgboost_tpu.tree import grow
 R, F, B = 5003, 7, 16  # a row count no chunk or tile divides
 
 
-def level(width, depth, seed, has_cat):
+def level(width, depth, seed, has_cat, B=B):
     """Rows and node tables of the level at ``depth``, padded to ``width``
     slots: whatever the slots past ``2**depth`` hold must reach no row."""
     rng = np.random.default_rng(seed)
@@ -45,7 +45,9 @@ def level(width, depth, seed, has_cat):
             jnp.asarray(can), node0)
 
 
-@pytest.mark.parametrize("has_cat", [False, True], ids=["num", "cat"])
+@pytest.mark.parametrize("has_cat,n_bin", [(False, B), (True, B),
+                                           (True, 300)],
+                         ids=["num", "cat", "cat-300-bins"])
 @pytest.mark.parametrize("width,depth,traced", [
     (1, 0, False),                     # the root's program
     (32, 5, False), (32, 5, True),     # a full level, as level_step has it
@@ -53,12 +55,16 @@ def level(width, depth, seed, has_cat):
     (128, 7, False), (128, 7, True),
     (128, 4, True),
 ])
-def test_dense_form_equals_gather_form(width, depth, traced, has_cat):
-    bins, pos, best, can, node0 = level(width, depth, width + depth, has_cat)
+def test_dense_form_equals_gather_form(width, depth, traced, has_cat, n_bin):
+    """Categorical sets of 300 bins (a column past ``max_bin``: the page's
+    width and sentinel follow it) and of more than 32 nodes (the membership
+    in several words a bin) route alike in both forms."""
+    bins, pos, best, can, node0 = level(width, depth, width + depth, has_cat,
+                                        n_bin)
 
     def run(form):
         def call(node0):
-            return form(bins, pos, best, can, node0, width, B, has_cat)
+            return form(bins, pos, best, can, node0, width, n_bin, has_cat)
         return np.asarray(jax.jit(call)(jnp.int32(node0)) if traced
                           else call(node0))
 
@@ -76,7 +82,7 @@ def test_dense_form_equals_gather_form(width, depth, traced, has_cat):
     assert not moved[~on].any() and (p == -1).any()
     assert set(np.unique(want[moved] - 2 * p[moved])) == {1, 2}
     feat = np.clip(np.asarray(best.feature), 0, F - 1)[np.clip(p - node0, 0, width - 1)]
-    miss = moved & (b[np.arange(R), feat] == B)
+    miss = moved & (b[np.arange(R), feat] == n_bin)
     assert set(np.unique(want[miss] - 2 * p[miss])) == {1, 2}  # default both ways
 
 

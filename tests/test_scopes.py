@@ -64,7 +64,7 @@ def toy(tmp_path_factory):
         cuts=jnp.asarray(np.sort(rng.normal(size=(F, B)), axis=1)
                          .astype(np.float32)),
         nb=jnp.full(F, B, jnp.int32), ones=jnp.ones((1, F), bool),
-        setm=jnp.ones((1, F), bool), cm=jnp.zeros(F, bool),
+        setm=jnp.ones((1, F), bool),
         params=bst._split_params,
         state=grow.init_tree_state(gpair, jnp.ones(R, bool),
                                    max_nodes=grow.max_nodes_for_depth(DEPTH),
@@ -76,7 +76,7 @@ def toy(tmp_path_factory):
 def level_hlo(toy, depth, last, subtract, padded) -> str:
     a = toy
     head = (a["state"], a["bins"], a["gpair"], a["cuts"], a["nb"], a["ones"],
-            a["setm"], a["cm"])
+            a["setm"])
     if padded:
         width = 1 << (DEPTH - 1)
         prev = jnp.zeros((width, F, B, 2), jnp.float32)
@@ -175,7 +175,7 @@ def bestfirst_hlo(toy, finish=False) -> str:
             state, n_slots=grower.n_slots).compile().as_text()
     return bestfirst.level_step_bestfirst.lower(
         state, a["bins"], a["gpair"], a["nb"], a["ones"],
-        jnp.ones((1, 2, F), bool), a["setm"], a["cm"], pairs=grower.pairs,
+        jnp.ones((1, 2, F), bool), a["setm"], pairs=grower.pairs,
         max_leaves=12, max_depth=0, gamma_eps=1e-6, params=a["params"],
         has_cat=False, monotone=False).compile().as_text()
 

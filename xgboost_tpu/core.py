@@ -32,8 +32,8 @@ from .ops.predict import predict_leaf_ids
 from .ops.split import SplitParams
 from .params import TrainParam, canonicalize, split_unknown
 from .telemetry import span
-from .telemetry.spans import wait_span
-from .tree.grow import HistTreeGrower, leaf_margin_delta
+from .telemetry.spans import count_in_round, wait_span
+from .tree.grow import HistTreeGrower, count_categorical, leaf_margin_delta
 
 __all__ = ["Booster"]
 
@@ -267,6 +267,7 @@ class Booster:
             max_delta_step=float(self.tparam.max_delta_step),
             monotone=self.tparam.monotone_constraints,
             max_cat_to_onehot=int(self.tparam.max_cat_to_onehot),
+            max_cat_threshold=int(self.tparam.max_cat_threshold),
         )
         self._configured = True
 
@@ -1466,6 +1467,13 @@ class Booster:
                                             grower, cat_mask_np)
         bins_use, cuts_use, nbins_use = cache.bins, ell.cuts_pad, ell.n_bins
         cuts_token_use = ell.cuts.token
+        if cat_mask_np is not None:
+            # the widest categorical column, once a round; which columns
+            # take the one-hot branch (ops/split.py), for the tree counts
+            cat_bins = ell.cuts.n_bins_array()[cat_mask_np]
+            count_in_round(**{"cat.bins_max": int(cat_bins.max())})
+            onehot_cols = cat_mask_np & (ell.cuts.n_bins_array()
+                                         < self.tparam.max_cat_to_onehot)
         # the one-hot a column's bins tall (ops/histogram.py bin_tiers): one
         # chip's float32 matmul over the resident page; a mesh, processes,
         # the int8 limbs and a page binned anew each round build one tier
@@ -1512,7 +1520,9 @@ class Booster:
             if ell_iter.n_padded != cache.bins.shape[0]:
                 raise AssertionError("approx page padding mismatch")
             bins_use = jnp.asarray(ell_iter.bins)
-            cuts_use = jnp.asarray(cuts.padded(self.tparam.max_bin))
+            # (a categorical column's bins are its categories, past max_bin)
+            cuts_use = jnp.asarray(cuts.padded(
+                max(self.tparam.max_bin, cuts.max_n_bins)))
             nbins_use = jnp.asarray(cuts.n_bins_array())
             # these trees' split_bins index the per-iteration sketch, NOT the
             # resident ellpack: stamping the ellpack's token would falsely
@@ -1638,6 +1648,8 @@ class Booster:
                     with span("tree.from_grown"):
                         tree = RegTree.from_grown(grown)
                 tree.cuts_token = cuts_token_use
+                if cat_mask_np is not None:
+                    count_categorical(tree, onehot_cols)
                 self.trees.append(tree)
                 self.tree_info.append(k)
                 self.tree_weights.append(1.0)

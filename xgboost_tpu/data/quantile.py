@@ -131,20 +131,18 @@ def categorical_cuts(n_cats: int) -> np.ndarray:
     return np.arange(1, max(n_cats, 1) + 1, dtype=np.float32)
 
 
-def _assemble_cuts(F: int, max_bin: int, cat_n_cats, num_seg) -> HistogramCuts:
+def _assemble_cuts(F: int, cat_n_cats, num_seg) -> HistogramCuts:
     """Stitch per-feature cut segments: identity cuts for categorical
     features (cat_n_cats: {feature -> n_cats}), ``num_seg(f) -> (seg, min)``
-    for numeric ones.  Shared by every sketch flavour."""
+    for numeric ones.  Shared by every sketch flavour.  A categorical
+    column's bins are its categories, however many: ``max_bin`` bounds the
+    numeric columns alone (upstream's HistogramCuts, where a category is a
+    cut), and the page's common width follows the widest column."""
     ptrs, values = [0], []
     mins = np.zeros(F, np.float32)
     for f in range(F):
         if f in cat_n_cats:
-            n_cats = cat_n_cats[f]
-            if n_cats > max_bin:
-                raise ValueError(
-                    f"categorical feature {f} has {n_cats} categories; "
-                    f"raise max_bin (currently {max_bin})")
-            seg = categorical_cuts(n_cats)
+            seg = categorical_cuts(cat_n_cats[f])
             mins[f] = -1e-5
         else:
             seg, mins[f] = num_seg(f)
@@ -191,7 +189,7 @@ def sketch_dense(
             cat_n_cats[int(f)] = int(col.max()) + 1 if len(col) else 1
         num_pos = {int(f): i for i, f in enumerate(num_idx)}
         return _assemble_cuts(
-            F, max_bin, cat_n_cats,
+            F, cat_n_cats,
             lambda f: (base.feature_cuts(num_pos[f]), base.min_vals[num_pos[f]]))
 
     if weights is not None:
@@ -504,7 +502,7 @@ class StreamingSketch:
         cat_n_cats = {int(f): (int(cat_max[f]) + 1 if cat_max[f] >= 0 else 1)
                       for f in np.nonzero(self.cat_mask)[0]}
         return _assemble_cuts(
-            F, self.max_bin, cat_n_cats,
+            F, cat_n_cats,
             lambda f: (base.feature_cuts(f), base.min_vals[f]))
 
 
@@ -602,5 +600,5 @@ def sketch_csr(indptr, indices, values, n_features: int, max_bin: int,
     cat_n_cats = {int(f): (int(cat_local_max[f]) + 1 if cat_local_max[f] >= 0 else 1)
                   for f in np.nonzero(is_cat)[0]}
     return _assemble_cuts(
-        n_features, max_bin, cat_n_cats,
+        n_features, cat_n_cats,
         lambda f: (base.feature_cuts(f), base.min_vals[f]))

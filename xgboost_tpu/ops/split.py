@@ -20,6 +20,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _EPS = 1e-6  # kRtEps (include/xgboost/base.h)
 
@@ -36,8 +37,12 @@ class SplitParams(NamedTuple):
     # monotone_constraints: per-feature {-1,0,+1} (src/tree/constraints.cc);
     # None disables the constrained evaluation path entirely
     monotone: "object" = None
-    # categorical split config (reference: src/tree/param.h max_cat_to_onehot)
+    # categorical split config (reference: src/tree/param.h): a column of
+    # fewer categories than max_cat_to_onehot is split one category against
+    # the rest; a wider one by a partition of its categories sorted by
+    # weight, at most max_cat_threshold of them on the side a scan gathers
     max_cat_to_onehot: int = 4
+    max_cat_threshold: int = 64
 
 
 class BestSplit(NamedTuple):
@@ -249,10 +254,66 @@ def _evaluate_splits_native(hist, totals, n_bins, params: SplitParams,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("params",))
+def cat_columns(cat_mask) -> tuple:
+    """The categorical columns of ``cat_mask`` as the static tuple the level
+    programs take (``has_cat``): ``()`` for None or a mask with none."""
+    if cat_mask is None:
+        return ()
+    return tuple(int(f) for f in np.flatnonzero(np.asarray(cat_mask)))
+
+
+def _categorical_scan(hist, feat_sum, n_bins, params: SplitParams,
+                      cat_cols: tuple):
+    """Upstream's two categorical enumerations over the columns ``cat_cols``
+    (src/tree/hist/evaluate_splits.h EnumerateOneHot, EnumeratePart; the
+    whole of it, and what departs, in docs/categorical.md).
+
+    Returns, each (N, C, B) over the C categorical columns: the present rows'
+    left sums ``GL``, ``HL`` of candidate ``j`` (the missing rows are added
+    by the caller for the other direction), whether ``j`` is a candidate with
+    the missing rows right (``ok_r``) and left (``ok_l``), and each bin's
+    rank in the column's order (``rank``); and the (C,) one-hot columns.
+
+    One-hot (fewer than ``max_cat_to_onehot`` categories): candidate ``j`` is
+    category ``j`` alone on the right.  Partition: the categories sorted by
+    their own weight (``CalcWeightCat``: ``calc_weight`` with 0 under
+    ``min_child_weight``), empty ones last; candidate ``j`` sends ranks
+    ``0..j`` right.  With the missing rows left that is the forward scan, the
+    right side at most ``n - 1`` categories; with them right the backward
+    scan, the left side at most ``n - 1``; ``n = min(max_cat_threshold,
+    categories)``."""
+    N, _, B, _ = hist.shape
+    cols = np.asarray(cat_cols)
+    hc = hist[:, cols]  # (N, C, B, 2)
+    nb = n_bins[cols][None, :, None]  # (1, C, 1)
+    onehot = n_bins[cols] < params.max_cat_to_onehot  # (C,)
+    j = jnp.arange(B, dtype=jnp.int32)[None, None, :]
+    with jax.named_scope("split.cat"):
+        G, H = hc[..., 0], hc[..., 1]
+        weight = jnp.where(H < params.min_child_weight, 0.0,
+                           calc_weight(G, H, params))
+        key = jnp.where(H > 0.0, weight, jnp.inf)  # empty categories last
+        order = jnp.argsort(key, axis=2, stable=True)
+        rank = jnp.argsort(order, axis=2).astype(jnp.int32)
+        right = jnp.cumsum(jnp.take_along_axis(hc, order[..., None], axis=2),
+                           axis=2)  # ranks 0..j
+        n = jnp.minimum(params.max_cat_threshold, nb)
+        part_l = (j <= n - 2)
+        part_r = (j >= nb - n) & (j <= nb - 2)
+    oh = onehot[None, :, None]
+    total = feat_sum[:, cols][:, :, None, :]  # (N, C, 1, 2)
+    GL = total[..., 0] - jnp.where(oh, G, right[..., 0])
+    HL = total[..., 1] - jnp.where(oh, H, right[..., 1])
+    single = j < nb
+    ok_r = jnp.where(oh, single, part_r)
+    ok_l = jnp.where(oh, single, part_l)
+    return GL, HL, ok_r, ok_l, rank, onehot
+
+
+@functools.partial(jax.jit, static_argnames=("params", "cat_cols"))
 def evaluate_splits(
     hist, totals, n_bins, params: SplitParams, feature_mask=None, node_bounds=None,
-    cat_mask=None,
+    cat_cols: tuple = (),
 ) -> BestSplit:
     """Pick the best split per node.
 
@@ -262,40 +323,25 @@ def evaluate_splits(
     feature_mask : optional (F,) or (N, F) bool — column sampling / interaction
                    constraints (per-node allowed features)
     node_bounds  : optional (N, 2) f32 [lower, upper] monotone weight bounds
+    cat_cols     : the categorical columns (``cat_columns``), static: their
+                   candidates are ``_categorical_scan``'s
     """
     N, F, B, _ = hist.shape
-    has_cat = cat_mask is not None
-    if not has_cat and _native_split_ok(params):
+    if not cat_cols and _native_split_ok(params):
         return _evaluate_splits_native(hist, totals, n_bins, params,
                                        feature_mask)
 
-    if has_cat:
-        # Categorical features (reference: evaluate_splits.cu one-hot pass +
-        # sorted-partition pass, max_cat_to_onehot switch in param.h):
-        #  - partition: permute bins by grad/hess ratio, then the ordinary
-        #    prefix scan below IS the optimal-partition scan;
-        #  - one-hot (few categories): left = everything-but-c, expressed by
-        #    overriding the prefix sums with feat_sum - hist[c].
-        onehot_f = cat_mask & (n_bins < params.max_cat_to_onehot)  # (F,)
-        ratio = hist[..., 0] / (hist[..., 1] + _EPS)  # (N,F,B)
-        ratio = jnp.where(hist[..., 1] > 0, ratio, jnp.inf)  # empty cats last
-        bin_iota = jnp.arange(B, dtype=jnp.float32)
-        sort_key = jnp.where(cat_mask[None, :, None], ratio, bin_iota[None, None, :])
-        order = jnp.argsort(sort_key, axis=2)  # identity for numeric features
-        inv_order = jnp.argsort(order, axis=2).astype(jnp.int32)
-        hist_eval = jnp.take_along_axis(hist, order[..., None], axis=2)
-    else:
-        hist_eval = hist
-
-    cum = jnp.cumsum(hist_eval, axis=2)  # (N,F,B,2): left sums, missing->right
+    cum = jnp.cumsum(hist, axis=2)  # (N,F,B,2): left sums, missing->right
     feat_sum = cum[:, :, -1, :]  # (N,F,2) — uses all bins incl. top
     miss = totals[:, None, :] - feat_sum  # (N,F,2) missing-value stats
 
     GL_r, HL_r = cum[..., 0], cum[..., 1]  # missing -> right
-    if has_cat:
-        oh = onehot_f[None, :, None]
-        GL_r = jnp.where(oh, feat_sum[:, :, None, 0] - hist[..., 0], GL_r)
-        HL_r = jnp.where(oh, feat_sum[:, :, None, 1] - hist[..., 1], HL_r)
+    if cat_cols:
+        cols = np.asarray(cat_cols)
+        GL_c, HL_c, ok_r_c, ok_l_c, rank_c, onehot_c = _categorical_scan(
+            hist, feat_sum, n_bins, params, cat_cols)
+        GL_r = GL_r.at[:, cols].set(GL_c)
+        HL_r = HL_r.at[:, cols].set(HL_c)
     GL_l, HL_l = GL_r + miss[:, :, None, 0], HL_r + miss[:, :, None, 1]  # missing -> left
 
     monotone = params.monotone is not None and any(c != 0 for c in params.monotone)
@@ -350,15 +396,16 @@ def evaluate_splits(
         jnp.abs(miss[:, :, 1:2]) > _EPS
     ).reshape(N, F, 1)
     ok = bin_ok[None, :, :] | top_ok
-    if has_cat:
-        # one-hot: every non-empty category is a valid candidate
-        ok = jnp.where(onehot_f[None, :, None],
-                       (bin_idx[None, None, :] < n_bins[None, :, None]), ok)
     if feature_mask is not None:
         fm = feature_mask if feature_mask.ndim == 2 else feature_mask[None, :]
         ok = ok & fm[:, :, None]
+    ok_l = ok
+    if cat_cols:  # the two directions differ in a partition's bound
+        allowed = True if feature_mask is None else fm[:, cols, None]
+        ok_l = ok.at[:, cols].set(ok_l_c & allowed)
+        ok = ok.at[:, cols].set(ok_r_c & allowed)
     gain_r = jnp.where(ok, gain_r, -jnp.inf)
-    gain_l = jnp.where(ok, gain_l, -jnp.inf)
+    gain_l = jnp.where(ok_l, gain_l, -jnp.inf)
 
     # prefer missing->left on ties? reference default dir comes from scan order;
     # pick strictly-better direction, defaulting left like DeviceSplitCandidate.
@@ -387,19 +434,17 @@ def evaluate_splits(
         lw = calc_weight(GL, HL, params)
         rw = calc_weight(GR, HR, params)
 
-    if has_cat:
-        is_cat = cat_mask[best_f]  # (N,)
-        chosen_oh = onehot_f[best_f]
+    if cat_cols:
         # categories routed RIGHT (common/categorical.h: in-set -> right):
-        #  one-hot: the single chosen category; partition: the sorted suffix
-        inv_at = jnp.take_along_axis(
-            inv_order, best_f[:, None, None], axis=1
-        )[:, 0, :]  # (N, B) rank of each bin in the sorted order
+        # one-hot the chosen category, partition ranks 0..j of its order
+        is_cat = jnp.zeros(F, bool).at[cols].set(True)[best_f]  # (N,)
+        at = jnp.zeros(F, jnp.int32).at[cols].set(
+            jnp.arange(len(cols), dtype=jnp.int32))[best_f]  # its place in cols
+        rank_at = jnp.take_along_axis(rank_c, at[:, None, None], axis=1)[:, 0]
         bb = jnp.arange(B, dtype=jnp.int32)[None, :]
-        in_range = bb < n_bins[best_f][:, None]
-        set_oh = (bb == best_b[:, None])
-        set_part = inv_at > best_b[:, None]
-        cat_set = jnp.where(chosen_oh[:, None], set_oh, set_part) & in_range & is_cat[:, None]
+        in_set = jnp.where(onehot_c[at][:, None], bb == best_b[:, None],
+                           rank_at <= best_b[:, None])
+        cat_set = in_set & (bb < n_bins[best_f][:, None]) & is_cat[:, None]
     else:
         is_cat = jnp.zeros(N, bool)
         cat_set = jnp.zeros((N, B), bool)

@@ -54,7 +54,7 @@ class ShardedHistTreeGrower(HistTreeGrower):
         self.mesh = mesh
         self._built_for = None
 
-    def _build(self, n_features: int, n_bin: int = 1, has_cat: bool = False) -> None:
+    def _build(self, n_features: int, n_bin: int = 1, has_cat: tuple = ()) -> None:
         if self._built_for == (n_features, n_bin, has_cat):
             return
         ax = DATA_AXIS
@@ -78,7 +78,7 @@ class ShardedHistTreeGrower(HistTreeGrower):
         # quantised: the gpair slot carries (R, C, 3) int8 limbs and every
         # level fn takes a trailing replicated rho (per-channel scale)
         gspec = P(ax, None, None) if q else P(ax, None)
-        row_specs = (sspec, P(ax, None), gspec, P(), P(), P(), P(), P())
+        row_specs = (sspec, P(ax, None), gspec, P(), P(), P(), P())
         rho_specs = (P(),) if q else ()
 
         def program(step, n_more: int, **static):
@@ -107,20 +107,20 @@ class ShardedHistTreeGrower(HistTreeGrower):
         self._built_for = (n_features, n_bin, has_cat)
 
     def _init_state(self, gpair, valid, setmat, cuts_pad,
-                    has_cat: bool) -> TreeState:
+                    has_cat: tuple) -> TreeState:
         self._build(setmat.shape[1], cuts_pad.shape[1], has_cat)
         return self._init_fn(gpair, valid)
 
-    def _run_level(self, d: int, width, state, page, fm, setmat, cm,
-                   hist_prev, rho, has_cat: bool, tiers=None, bins_t=None):
+    def _run_level(self, d: int, width, state, page, fm, setmat,
+                   hist_prev, rho, has_cat: tuple, tiers=None, bins_t=None):
         # a mesh's levels build one tier (core.py) in the XLA form
         assert tiers is None and bins_t is None
         rho_args = () if rho is None else (rho,)
         if width is not None:
             return self._interior_fn(width)(
-                state, *page, fm, setmat, cm, hist_prev,
+                state, *page, fm, setmat, hist_prev,
                 jnp.int32((1 << d) - 1), *rho_args)
-        return self._level_fns[d](state, *page, fm, setmat, cm, hist_prev,
+        return self._level_fns[d](state, *page, fm, setmat, hist_prev,
                                   *rho_args)
 
     def _interior_fn(self, width: int):

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from .. import collective
 from ..ops.histogram import combine_sibling_hists
 from ..reliability.faults import maybe_inject
-from ..ops.split import SplitParams
+from ..ops.split import SplitParams, cat_columns
 from ..tree.grow import (TreeState, init_tree_state, make_set_matrix,
                          max_nodes_for_depth)
 from ..tree.stream import _decide_level, _page_step
@@ -70,8 +70,7 @@ class ProcessHistTreeGrower:
              cat_mask=None) -> TreeState:
         F = bins.shape[1]
         B = cuts_pad.shape[1]
-        has_cat = cat_mask is not None
-        cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(0, bool)
+        has_cat = cat_columns(cat_mask)
         setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
         ones = jnp.ones((1, F), dtype=bool)
         state = init_tree_state(
@@ -142,9 +141,9 @@ class ProcessHistTreeGrower:
                 hist_f = jnp.zeros((N, F, B, 2), jnp.float32)
             fm = ones if feature_masks is None else feature_masks(d, N)
             state, best, can = _decide_level(
-                state, hist_f, n_bins, cuts_pad, fm, setmat, cm,
+                state, hist_f, n_bins, cuts_pad, fm, setmat,
                 depth=d, params=self.params, lossguide=self.lossguide,
-                last_level=(d == self.max_depth),
+                last_level=(d == self.max_depth), has_cat=has_cat,
             )
             prev_best, prev_can, prev_d = best, can, d
         return state

@@ -60,7 +60,8 @@ from ..models.tree import RegTree
 from ..ops.histogram import (BinTiers, combine_sibling_hists,
                              hist_is_row_pass, level_histogram, node_sums,
                              row_list, row_list_fits, rows_scanned)
-from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
+from ..ops.split import (BestSplit, SplitParams, calc_weight, cat_columns,
+                         evaluate_splits)
 from ..telemetry import span
 from ..telemetry.spans import count_in_round, wait_span
 from .grow import _update_positions, make_set_matrix
@@ -214,7 +215,7 @@ def _init_state(pos, root_totals, *, S: int, F: int, B: int, n_sets: int):
 
 
 def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
-            gamma_eps: float, has_cat: bool, list_rows: Optional[int],
+            gamma_eps: float, has_cat: tuple, list_rows: Optional[int],
             tiers: Optional[BinTiers] = None):
     """First half of a pass: choose the parents, route their rows, build the
     pass's histograms from the rows.  Returns ``(state, picked, built)`` with
@@ -288,9 +289,9 @@ def _expand(state: BFState, bins, gpair, *, pairs: int, max_leaves: int,
 
 
 def _settle(state: BFState, picked: Picked, built, n_bins, root_mask,
-            pair_masks, set_matrix, cat_mask, *, pairs: int, max_leaves: int,
+            pair_masks, set_matrix, *, pairs: int, max_leaves: int,
             max_depth: int, gamma_eps: float, params: SplitParams,
-            has_cat: bool, monotone: bool):
+            has_cat: tuple, monotone: bool):
     """Second half of a pass: the siblings by subtraction, every child's
     best split, the block of 2*pairs slots written, the driver replayed."""
     k = pairs
@@ -351,7 +352,7 @@ def _settle(state: BFState, picked: Picked, built, n_bins, root_mask,
                              set_matrix.astype(jnp.float32)) > 0.0
         best = evaluate_splits(block, totals, n_bins, params, allowed & draw,
                                jnp.stack([lower, upper], axis=1),
-                               cat_mask=cat_mask if has_cat else None)
+                               cat_cols=has_cat)
         gain = jnp.where(valid, best.gain, -jnp.inf)
         if max_depth > 0:
             gain = jnp.where(depth < max_depth, gain, -jnp.inf)
@@ -438,9 +439,9 @@ _STATIC = _SETTLE_STATIC + ("list_rows",)  # of the whole pass
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def level_step_bestfirst(state: BFState, bins, gpair, n_bins, root_mask,
-                         pair_masks, set_matrix, cat_mask, *, pairs: int,
+                         pair_masks, set_matrix, *, pairs: int,
                          max_leaves: int, max_depth: int, gamma_eps: float,
-                         params: SplitParams, has_cat: bool, monotone: bool,
+                         params: SplitParams, has_cat: tuple, monotone: bool,
                          list_rows: Optional[int] = None,
                          tiers: Optional[BinTiers] = None):
     """One pass (module docstring), the whole of it one program: a tree is
@@ -450,7 +451,7 @@ def level_step_bestfirst(state: BFState, bins, gpair, n_bins, root_mask,
         gamma_eps=gamma_eps, has_cat=has_cat, list_rows=list_rows,
         tiers=tiers)
     return _settle(state, picked, built, n_bins, root_mask, pair_masks,
-                   set_matrix, cat_mask, pairs=pairs, max_leaves=max_leaves,
+                   set_matrix, pairs=pairs, max_leaves=max_leaves,
                    max_depth=max_depth, gamma_eps=gamma_eps, params=params,
                    has_cat=has_cat, monotone=monotone)
 
@@ -564,9 +565,8 @@ class BestFirstGrower:
                                      or self.mesh is not None)
         F = bins.shape[1]
         B = cuts_pad.shape[1]
-        has_cat = cat_mask is not None
+        has_cat = cat_columns(cat_mask)
         with span("grow.setup"):  # the tree's state and masks, before a pass
-            cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
             setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
             root_mask, pair_masks = self._masks(feature_masks, F)
 
@@ -602,14 +602,14 @@ class BestFirstGrower:
             if not self.distributed:
                 return level_step_bestfirst(
                     state, bins, gpair, n_bins, root_mask, pair_masks,
-                    setmat, cm, tiers=tiers, **static)
+                    setmat, tiers=tiers, **static)
             state, picked, built = _expand_alone(
                 state, bins, gpair,
                 **{name: static[name] for name in _EXPAND_STATIC})
             built = jnp.asarray(collective.allreduce(np.asarray(built)))
             return _settle_alone(
                 state, picked, built, n_bins, root_mask, pair_masks, setmat,
-                cm, **{name: static[name] for name in _SETTLE_STATIC})
+                **{name: static[name] for name in _SETTLE_STATIC})
 
         sent = passes = n_alloc = n_splits = 0
         told, scanned = collections.deque(), []

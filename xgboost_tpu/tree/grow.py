@@ -38,7 +38,8 @@ from ..ops.histogram import (BinTiers, combine_sibling_hists,
                              hist_form, hist_is_row_pass, level_histogram,
                              node_sums, onehot_rows, tier_widths,
                              transposed_page)
-from ..ops.split import BestSplit, SplitParams, calc_weight, evaluate_splits
+from ..ops.split import (BestSplit, SplitParams, calc_weight, cat_columns,
+                         evaluate_splits)
 from ..telemetry import span
 from ..telemetry.spans import count_in_round, wait_span
 
@@ -256,7 +257,8 @@ def _update_positions_dense(bins, pos, best, can_split, node0, N: int,
     a select over the N nodes; the split feature's bin by a select over the
     F columns the histogram pass reads anyway.  Both are reduces that XLA
     fuses with their compare, so neither the (N, R) nor the (R, F) int32
-    operand exists in memory.  Work a level: R*(N + F) selects."""
+    operand exists in memory.  Work a level: R*(N + F) selects, and where a
+    column is categorical R*B*ceil(N/32) more (``_membership_dense``)."""
     F = bins.shape[1]
     i32 = jnp.int32
     # bit 0 can_split, 1 default_left, 2 is_cat, then bin + 1 in [0, B] (a
@@ -282,18 +284,38 @@ def _update_positions_dense(bins, pos, best, can_split, node0, N: int,
                                bins.astype(i32), 0), axis=1)
     goleft = binval <= sb
     if has_cat:
-        # the membership lookup keeps its gather: no cell has categories
-        lc = jnp.clip(local, 0, N - 1)
-        member = best.cat_set.reshape(-1)[lc * B + jnp.clip(binval, 0, B - 1)]
+        member = _membership_dense(best.cat_set, jnp.clip(local, 0, N - 1),
+                                   jnp.clip(binval, 0, B - 1))
         goleft = jnp.where((row & 4) == 4, ~member, goleft)
     goleft = jnp.where(binval >= B, dl, goleft)  # sentinel B = missing
     child = 2 * pos + 1 + jnp.where(goleft, 0, 1)
     return jnp.where(can_r, child, pos)
 
 
+def _membership_dense(cat_set, lc, binval):
+    """``cat_set[lc, binval]`` a row with no gather of a row-sized output.
+    The level's (N, B) sets turned around: for each bin, which nodes hold
+    it, 32 nodes a word; a bin's words brought to the row by a select over
+    the B bins (a reduce XLA fuses with its compare, as the node table's),
+    then the bit of the row's own node."""
+    N, B = cat_set.shape
+    i32 = jnp.int32
+    words = -(-N // 32)
+    bits = jnp.zeros((words * 32, B), i32).at[:N].set(cat_set.astype(i32))
+    by_bin = jnp.sum(bits.reshape(words, 32, B)
+                     << jnp.arange(32, dtype=i32)[None, :, None],
+                     axis=1, dtype=i32)  # (words, B): node n at bit n % 32
+    at_bin = jnp.arange(B, dtype=i32)[:, None] == binval[None, :]
+    word = 0
+    for w in range(words):
+        mine = jnp.sum(jnp.where(at_bin, by_bin[w][:, None], 0), axis=0)
+        word = mine if words == 1 else word | jnp.where(lc >> 5 == w, mine, 0)
+    return ((word >> (lc & 31)) & 1) == 1
+
+
 def decide_level(state: TreeState, hist_of, cuts_pad, n_bins, feature_mask,
-                 set_matrix, cat_mask, node0, N: int, *, params: SplitParams,
-                 last_level: bool, lossguide: bool, has_cat: bool):
+                 set_matrix, node0, N: int, *, params: SplitParams,
+                 last_level: bool, lossguide: bool, has_cat: tuple):
     """A level less its rows: the level's slices of the tree arrays, then
     either the leaf level's writes or histogram -> best splits -> who may
     take them -> the tree arrays with that written in.
@@ -341,8 +363,7 @@ def decide_level(state: TreeState, hist_of, cuts_pad, n_bins, feature_mask,
 
         node_bounds = jnp.stack([lower_lvl, upper_lvl], axis=1)
         best = evaluate_splits(hist_eval, totals_lvl, n_bins, params, fmask,
-                               node_bounds,
-                               cat_mask=cat_mask if has_cat else None)
+                               node_bounds, cat_cols=has_cat)
 
         gamma_eps = max(params.gamma, _EPS)
         can_split = alive_lvl & (best.gain > gamma_eps)
@@ -370,9 +391,9 @@ def decide_level(state: TreeState, hist_of, cuts_pad, n_bins, feature_mask,
 
 
 def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
-           set_matrix, cat_mask, hist_prev, rho, node0, N: int, *,
+           set_matrix, hist_prev, rho, node0, N: int, *,
            params: SplitParams, last_level: bool, axis_name: Optional[str],
-           lossguide: bool, has_cat: bool, subtract: bool, quantised: bool,
+           lossguide: bool, has_cat: tuple, subtract: bool, quantised: bool,
            tiers: Optional[BinTiers] = None, bins_t: Optional[tuple] = None):
     """One level, the only place it is written: histogram -> decide -> route
     over the ``N`` heap slots from ``node0``, a Python int (``level_step``: a
@@ -431,7 +452,7 @@ def _level(state: TreeState, bins, gpair, cuts_pad, n_bins, feature_mask,
             return hist, dequantise(hist, rho)  # the ONE rounding step
 
     st, best, can_split, hist = decide_level(
-        state, hist_of, cuts_pad, n_bins, feature_mask, set_matrix, cat_mask,
+        state, hist_of, cuts_pad, n_bins, feature_mask, set_matrix,
         node0, N, params=params, last_level=last_level, lossguide=lossguide,
         has_cat=has_cat)
     if not last_level:
@@ -455,7 +476,6 @@ def level_step(
     n_bins,
     feature_mask,
     set_matrix,
-    cat_mask,
     hist_prev=None,
     rho=None,
     *,
@@ -464,7 +484,7 @@ def level_step(
     last_level: bool,
     axis_name: Optional[str] = None,
     lossguide: bool = False,
-    has_cat: bool = False,
+    has_cat: tuple = (),
     subtract: bool = False,
     quantised: bool = False,
     tiers: Optional[BinTiers] = None,
@@ -473,7 +493,7 @@ def level_step(
     """Expand every alive node at ``depth`` (``_level``): a program a depth,
     ``node0`` and the width ``2**depth`` its constants."""
     return _level(state, bins, gpair, cuts_pad, n_bins, feature_mask,
-                  set_matrix, cat_mask, hist_prev, rho, (1 << depth) - 1,
+                  set_matrix, hist_prev, rho, (1 << depth) - 1,
                   1 << depth, params=params, last_level=last_level,
                   axis_name=axis_name, lossguide=lossguide, has_cat=has_cat,
                   subtract=subtract, quantised=quantised, tiers=tiers,
@@ -493,7 +513,6 @@ def level_step_padded(
     n_bins,
     feature_mask,
     set_matrix,
-    cat_mask,
     hist_prev,
     node0,
     rho=None,
@@ -502,7 +521,7 @@ def level_step_padded(
     params: SplitParams,
     axis_name: Optional[str] = None,
     lossguide: bool = False,
-    has_cat: bool = False,
+    has_cat: tuple = (),
     subtract: bool = True,
     quantised: bool = False,
     tiers: Optional[BinTiers] = None,
@@ -536,7 +555,7 @@ def level_step_padded(
     (width, F, B, C); row j = heap node ``node0 + j``.
     """
     return _level(state, bins, gpair, cuts_pad, n_bins, feature_mask,
-                  set_matrix, cat_mask, hist_prev, rho,
+                  set_matrix, hist_prev, rho,
                   jnp.asarray(node0, jnp.int32), width, params=params,
                   last_level=False, axis_name=axis_name, lossguide=lossguide,
                   has_cat=has_cat, subtract=subtract, quantised=quantised,
@@ -568,6 +587,20 @@ class GrownTree(NamedTuple):
     base_weight: "object"
     sum_hess: "object"
     totals: "object"
+
+
+def count_categorical(tree, onehot_cols) -> None:
+    """The round span's categorical counters from a finished tree
+    (``models/tree.py`` RegTree): its splits on a categorical column,
+    ``splits.categorical``, and of them those on a column of ``onehot_cols``,
+    the one category against the rest (ops/split.py), ``splits.cat_onehot``."""
+    import numpy as np
+
+    cat = (tree.split_type == 1) & (tree.left_children >= 0)
+    count_in_round(**{
+        "splits.categorical": int(cat.sum()),
+        "splits.cat_onehot": int(np.sum(
+            onehot_cols[tree.split_indices[cat]]))})
 
 
 # The narrowest width a shared interior program is padded to: 32 slots = 16
@@ -712,7 +745,7 @@ class HistTreeGrower:
             self._built(d, w), resident, channels, widths) == "onepass")
 
     def _init_state(self, gpair, valid, setmat, cuts_pad,
-                    has_cat: bool) -> TreeState:
+                    has_cat: tuple) -> TreeState:
         return init_tree_state(
             gpair, valid, max_nodes=self.max_nodes, n_sets=setmat.shape[0],
             n_bin=cuts_pad.shape[1],
@@ -720,7 +753,7 @@ class HistTreeGrower:
         )
 
     def _run_level(self, d: int, width: Optional[int], state, page, fm,
-                   setmat, cm, hist_prev, rho, has_cat: bool,
+                   setmat, hist_prev, rho, has_cat: tuple,
                    tiers: Optional[BinTiers] = None,
                    bins_t: Optional[tuple] = None):
         """Dispatch depth ``d``'s program: ``(state, hist)``.  With a
@@ -737,10 +770,10 @@ class HistTreeGrower:
             common["bins_t"] = bins_t
         if width is not None:
             return level_step_padded(
-                state, *page, fm, setmat, cm, hist_prev, (1 << d) - 1, rho,
+                state, *page, fm, setmat, hist_prev, (1 << d) - 1, rho,
                 width=width, subtract=self.subtract, **common)
         return level_step(
-            state, *page, fm, setmat, cm, hist_prev, rho, depth=d,
+            state, *page, fm, setmat, hist_prev, rho, depth=d,
             last_level=(d == md), subtract=(self.subtract and 0 < d < md),
             **common)
 
@@ -760,8 +793,7 @@ class HistTreeGrower:
         with span("grow.setup"):  # the tree's state and masks, before a level
             ones = jnp.ones((1, F), dtype=bool)
             setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
-            has_cat = cat_mask is not None
-            cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(F, bool)
+            has_cat = cat_columns(cat_mask)
             state = self._init_state(gpair, valid, setmat, cuts_pad, has_cat)
             rho = None
             if self.quantised:
@@ -807,7 +839,7 @@ class HistTreeGrower:
                             (width,) + hist.shape[1:],
                             hist.dtype).at[:hist.shape[0]].set(hist)
                 state, hist = self._run_level(
-                    d, width, state, page, fm, setmat, cm,
+                    d, width, state, page, fm, setmat,
                     None if d == md else hist, rho, has_cat, tiers,
                     self._transposed(bins, tiers) if form == "onepass"
                     else None)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.histogram import combine_sibling_hists, level_histogram
-from ..ops.split import SplitParams
+from ..ops.split import SplitParams, cat_columns
 from .grow import (TreeState, _update_positions, decide_level, init_tree_state,
                    make_set_matrix, max_nodes_for_depth)
 
@@ -48,7 +48,7 @@ def _sim_transfer_ms_per_mb() -> float:
 )
 def _page_step(page_bins, gpair_seg, pos_seg, prev_best, prev_can, *,
                node0_prev: int, n_prev: int, node0: int, n_nodes: int,
-               n_bin: int, has_prev: bool, has_cat: bool, build: bool = True,
+               n_bin: int, has_prev: bool, has_cat: tuple, build: bool = True,
                stride: int = 1, quantised: bool = False):
     """Route one page with the previous level's splits, then accumulate the
     current level's histogram over it (stride=2: left children only, for the
@@ -67,17 +67,17 @@ def _page_step(page_bins, gpair_seg, pos_seg, prev_best, prev_can, *,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("depth", "params", "lossguide", "last_level"),
+    jax.jit, static_argnames=("depth", "params", "lossguide", "last_level",
+                              "has_cat"),
 )
 def _decide_level(state: TreeState, hist, n_bins, cuts_pad, feature_mask,
-                  set_matrix, cat_mask, *, depth: int, params: SplitParams,
-                  lossguide: bool, last_level: bool):
+                  set_matrix, *, depth: int, params: SplitParams,
+                  lossguide: bool, last_level: bool, has_cat: tuple):
     """evaluate + record for one level (no position update — pages do that)."""
     return decide_level(
         state, lambda alive_lvl: (None, hist), cuts_pad, n_bins, feature_mask,
-        set_matrix, cat_mask, (1 << depth) - 1, 1 << depth, params=params,
-        last_level=last_level, lossguide=lossguide,
-        has_cat=bool(cat_mask.shape) and cat_mask.shape[0] > 0)[:3]
+        set_matrix, (1 << depth) - 1, 1 << depth, params=params,
+        last_level=last_level, lossguide=lossguide, has_cat=has_cat)[:3]
 
 
 class StreamingHistTreeGrower:
@@ -158,8 +158,7 @@ class StreamingHistTreeGrower:
              cuts_pad, n_bins, feature_masks=None, cat_mask=None) -> TreeState:
         F = pages[0].shape[1]
         B = cuts_pad.shape[1]
-        has_cat = cat_mask is not None
-        cm = jnp.asarray(cat_mask) if has_cat else jnp.zeros(0, bool)
+        has_cat = cat_columns(cat_mask)
         setmat = jnp.asarray(make_set_matrix(self.interaction_sets, F))
         ones = jnp.ones((1, F), dtype=bool)
         state = init_tree_state(
@@ -283,9 +282,9 @@ class StreamingHistTreeGrower:
             else:
                 hist_f = hist_acc
             state, best, can = _decide_level(
-                state, hist_f, n_bins, cuts_pad, fm, setmat, cm,
+                state, hist_f, n_bins, cuts_pad, fm, setmat,
                 depth=d, params=self.params, lossguide=self.lossguide,
-                last_level=(d == self.max_depth),
+                last_level=(d == self.max_depth), has_cat=has_cat,
             )
             if best is not None:
                 decisions.append((best, can, d))
