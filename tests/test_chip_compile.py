@@ -258,6 +258,25 @@ def test_categorical_level_programs_compile_for_v5e(one_chip, device_paths,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
 
 
+@pytest.mark.parametrize("slots", [127, 510, 511])
+def test_margin_update_selects_without_a_gather_for_v5e(one_chip, device_paths,
+                                                      slots):
+    """The margin update at the cells' tree widths (depth 6, a leaf budget
+    of 255, depth 8): the dense select, no gather with a row-sized output,
+    and the (S, R) operand of its compare never in memory."""
+    import re
+
+    from xgboost_tpu.tree.grow import leaf_margin_delta
+
+    compiled = jax.jit(lambda p, v: leaf_margin_delta.__wrapped__(p, v)).lower(
+        _shape((ROWS,), jnp.int32, one_chip),
+        _shape((slots,), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    moved = re.findall(r"= \w+\[(\d+)[\],][^\n]* gather\(", text)
+    assert not moved or max(int(n) for n in moved) < ROWS, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < slots * ROWS
+
+
 def test_bestfirst_pass_compiles_for_v5e(one_chip, device_paths):
     """``level_step_bestfirst`` at 28 columns, 256 bins and the cell's budget
     of 255 leaves: 32 pairs a pass, the one-hot matmul for the 32 built

@@ -202,10 +202,31 @@ def test_bestfirst_pass_names_its_work_and_gathers_nothing_row_sized(toy):
     assert not row_sized_gathers(finish, "route")
 
 
-def test_margin_update_is_scoped(toy):
+def margin_hlo(toy) -> str:
+    """The margin update's program as the environment picks its form, traced
+    afresh: the jitted entry point's cache, and JAX's own of the function it
+    wraps, may hold a trace of the other form."""
     st = toy["state"]
-    hlo = grow.leaf_margin_delta.lower(st.pos, st.leaf_val).compile().as_text()
+    return jax.jit(lambda p, v: grow.leaf_margin_delta.__wrapped__(p, v)).lower(
+        st.pos, st.leaf_val).compile().as_text()
+
+
+def test_margin_update_is_scoped(toy):
+    """Beside the matmul histogram the margin update is the dense select
+    (tree/grow.py ``margin_form``): wholly under ``margin``, no row-sized
+    gather (on the chip the gather was 16% of a depth-6 round: PERF.md)."""
+    assert grow.margin_form(toy["state"].leaf_val.shape[0]) == "dense"
+    hlo = margin_hlo(toy)
     assert set(scopes_of_row_sized(hlo)) == {"margin"}
+    assert not row_sized_gathers(hlo, "margin")
+
+
+def test_the_margin_update_gather_would_be_seen(toy, monkeypatch):
+    """Beside the row-pass histogram the same reading finds the gather."""
+    monkeypatch.setenv("XTB_HIST_IMPL", "scatter")
+    hlo = margin_hlo(toy)
+    assert set(scopes_of_row_sized(hlo)) == {"margin"}
+    assert len(row_sized_gathers(hlo, "margin")) == 1
 
 
 def test_ranking_gradient_is_scoped_and_gathers_only_on_the_way_back():

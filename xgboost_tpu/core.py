@@ -33,7 +33,8 @@ from .ops.split import SplitParams
 from .params import TrainParam, canonicalize, split_unknown
 from .telemetry import span
 from .telemetry.spans import count_in_round, wait_span
-from .tree.grow import HistTreeGrower, count_categorical, leaf_margin_delta
+from .tree.grow import (HistTreeGrower, count_categorical,
+                        leaf_margin_delta, margin_form)
 
 __all__ = ["Booster"]
 
@@ -1640,7 +1641,9 @@ class Booster:
                         tree.split_conditions[lm] = lv[lm]
                     else:
                         state = state._replace(leaf_val=leaf_val)
-                with span("grow.margin"):
+                slots = leaf_val.shape[0]
+                with span("grow.margin", **{"margin.form": margin_form(slots),
+                                            "margin.slots": slots}):
                     delta = leaf_margin_delta(pos, leaf_val)
                     new_margin = new_margin.at[:, k].add(delta)
                 if tree is None:

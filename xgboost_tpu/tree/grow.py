@@ -566,10 +566,55 @@ def level_step_padded(
 def leaf_margin_delta(pos, leaf_val):
     """Per-row margin update from the finished tree — the prediction-cache
     fast path (reference: TreeUpdater::UpdatePredictionCache,
-    include/xgboost/tree_updater.h:92): every row sits on its leaf already."""
+    include/xgboost/tree_updater.h:92): every row sits on its leaf already.
+
+    Two forms, bitwise equal on every ``pos`` in ``[-1, S)``
+    (tests/test_margin_delta.py), chosen by ``margin_form``."""
     with jax.named_scope("margin"):
-        safe = jnp.clip(pos, 0, leaf_val.shape[0] - 1)
-        return jnp.where(pos >= 0, leaf_val[safe], 0.0)
+        dense = margin_form(leaf_val.shape[0]) == "dense"
+        form = _margin_delta_dense if dense else _margin_delta_gather
+        return form(pos, leaf_val)
+
+
+# The most leaf slots whose margin update is the dense select: its work is
+# R*S compare-selects where the gather's is one index a row, so past some
+# width the gather is the cheaper form.  On the chip at 10.5M rows the
+# gather reads 83-86 ms whatever the tree, the same select in the route
+# 2.9 ms over 156 slots, 26.6 ms over 2,048 and 190 ms over 8,192 (PERF.md
+# §5, §6): 2,047 slots (depth 10) is the widest tree both readings put on
+# the select's side.
+_MARGIN_DENSE_SLOTS = 2047
+
+
+def margin_form(slots: int) -> str:
+    """``dense`` or ``gather``: the margin update's form for a tree of
+    ``slots`` slots.  Dense where the route is (not beside the CPU's row-pass
+    histogram, where a gather is the cheap form) and the tree is at most
+    ``_MARGIN_DENSE_SLOTS`` wide."""
+    if hist_is_row_pass() or slots > _MARGIN_DENSE_SLOTS:
+        return "gather"
+    return "dense"
+
+
+def _margin_delta_gather(pos, leaf_val):
+    """One gather a row: ``leaf_val[pos]``, 0 where ``pos`` is -1."""
+    safe = jnp.clip(pos, 0, leaf_val.shape[0] - 1)
+    return jnp.where(pos >= 0, leaf_val[safe], 0.0)
+
+
+def _margin_delta_dense(pos, leaf_val):
+    """No gather with a row-sized output: the leaf values' bits brought to
+    the row by a select over the S slots, a reduce that XLA fuses with its
+    compare, as the route's (``_update_positions_dense``), so the (S, R)
+    operand never exists in memory.  One slot matches a row on the tree, so
+    the sum is its bits unchanged (-0.0, NaN and the infinities included);
+    a padded row (``pos`` -1) matches none and reads +0.0."""
+    i32 = jnp.int32
+    bits = lax.bitcast_convert_type(leaf_val, i32)
+    row = jnp.sum(jnp.where(jnp.arange(leaf_val.shape[0], dtype=i32)[:, None]
+                            == pos[None, :], bits[:, None], 0),
+                  axis=0, dtype=i32)
+    return lax.bitcast_convert_type(row, jnp.float32)
 
 
 class GrownTree(NamedTuple):
